@@ -16,26 +16,6 @@ from typing import Callable
 
 from repro.configs.paper_cnn import FLConfig
 
-CACHE_ENV_VAR = "REPRO_COMPILE_CACHE"
-
-
-def maybe_enable_compile_cache() -> str | None:
-    """Opt-in persistent XLA compilation cache (mitigates the LM compile
-    wall — BENCH_workloads records 24.2s compile vs 0.11s exec per grid).
-
-    Set ``REPRO_COMPILE_CACHE=<dir>`` to enable; returns the directory or
-    None.  Call BEFORE the first jit lowering (benchmarks.run does, and so
-    does each subprocess child — the env var propagates).  The thresholds
-    are zeroed so micro-benchmark programs cache too; scripts/run_tier1.sh
-    honours the same variable via JAX's env-var config."""
-    cache_dir = os.environ.get(CACHE_ENV_VAR)
-    if cache_dir:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    return cache_dir or None
-
 FAST_FL = FLConfig(num_clients=16, clients_per_round=6, global_epochs=5,
                    local_epochs=2, batch_size=16, lr=1e-3)
 FULL_FL = FLConfig()  # the paper's §VI constants

@@ -3,13 +3,12 @@
     PYTHONPATH=src python -m benchmarks.run [--full] [--only NAME]
 
 Prints ``name,us_per_call,derived`` CSV lines per the contract.
-``--full`` restores the paper's protocol sizes (hours on this 1-core CPU
-container; the default fast mode keeps every structural element).
+``--full`` restores the paper's protocol sizes (hours on a CPU; the
+default fast mode keeps every structural element).
 
-Set ``REPRO_COMPILE_CACHE=<dir>`` to enable JAX's persistent compilation
-cache for every suite (and their subprocess children — the env var
-propagates): repeat runs skip the compile wall (BENCH_workloads records the
-LM grid at 24.2s compile vs 0.11s exec), and every BENCH_*.json records
+JAX's persistent compilation cache is on for every suite
+(``repro.compile_cache``): in ``JAX_COMPILATION_CACHE_DIR`` when that is
+set, else in ``<checkout>/.jax_cache``.  Every BENCH_*.json records
 ``compile_s`` so cached and cold runs are distinguishable."""
 from __future__ import annotations
 
@@ -91,8 +90,8 @@ def main(argv=None) -> int:
         ap.error(f"unknown suite {args.only!r}; have "
                  f"{sorted(n for n, _ in SUITES)}")
 
-    from .common import maybe_enable_compile_cache
-    maybe_enable_compile_cache()   # before any suite's first jit lowering
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()   # before any suite's first jit lowering
 
     import importlib
     failures = []

@@ -21,6 +21,8 @@ device-count flag must be set before jax initializes); the child reports one
 JSON line that the parent collects into ``BENCH_sharded_round.json`` at the
 repo root plus the usual CSV lines.  Every variant records ``compile_s``
 (first-call wall minus a steady round — the jit happens on first call).
+It is a CPU-emulation suite: where a TPU is visible it refuses to run rather
+than start children that would contend for the chip.
 """
 from __future__ import annotations
 
@@ -53,8 +55,8 @@ VARIANTS = (
 
 def _child(devices: int, rounds: int) -> dict:
     """Runs inside the forced-device-count subprocess: time every variant."""
-    from benchmarks.common import maybe_enable_compile_cache
-    maybe_enable_compile_cache()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import jax
     import numpy as np
@@ -139,7 +141,17 @@ def _child(devices: int, rounds: int) -> dict:
 
 
 def main(fast: bool = True) -> dict:
+    import jax
+
     from .common import emit, write_report
+
+    if jax.default_backend() == "tpu":
+        # One process per chip: the children would contend for the TPU this
+        # process now holds.  The sharded engine's chip path is
+        # ``python chip_smoke.py --four-chips``.
+        raise RuntimeError(
+            "benchmarks.sharded_round emulates devices on the CPU in child "
+            "processes and refuses to run where a TPU is visible")
 
     rounds = TIMED_ROUNDS if fast else 4 * TIMED_ROUNDS
     results = []

@@ -6,18 +6,10 @@
 #   scripts/run_tier1.sh -m "slow or not slow"    # everything
 #   scripts/run_tier1.sh -m slow                  # slow tier only
 #
-# Opt-in persistent XLA compilation cache (mitigates the compile-bound
-# micro-CNN/LM engine tests -- BENCH_workloads records the LM grid at 24.2s
-# compile vs 0.11s exec): set REPRO_COMPILE_CACHE=<dir> and repeat runs
-# reuse compiled programs.  JAX reads these env-var configs at import, so
-# subprocess tests (sharded parity) inherit the cache too.
+# Persistent compilation cache: set JAX_COMPILATION_CACHE_DIR=<dir>; JAX
+# reads it at import, so subprocess tests (sharded parity) inherit it too.
 set -euo pipefail
 cd "$(dirname "$0")/.."
-if [[ -n "${REPRO_COMPILE_CACHE:-}" ]]; then
-  export JAX_COMPILATION_CACHE_DIR="$REPRO_COMPILE_CACHE"
-  export JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES=-1
-  export JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0
-fi
 # --durations=15 surfaces the slowest tests so compile-bound regressions in
 # the engine tiers are visible in every CI log, not just the weekly bench.
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" exec python -m pytest -x -q --durations=15 "$@"

@@ -77,20 +77,12 @@ from repro.kernels.dispatch import client_histograms, weighted_sum_tree
 Array = jax.Array
 PyTree = Any
 
-try:  # jax ≥ 0.8
-    from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        # check_vma=False: the replicated outputs (mask/scores) come from an
-        # all_gather whose replication the static checker cannot infer.
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    # check_vma=False: the replicated outputs (mask/scores) come from an
+    # all_gather whose replication the static checker cannot infer.
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def topn_mask_from_scores(scores: Array, n_select: int) -> Array:

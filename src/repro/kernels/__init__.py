@@ -1,9 +1,10 @@
 """Pallas TPU kernels for the framework's perf-critical compute (DESIGN.md §6).
 
 All kernels use explicit BlockSpec VMEM tiling and are validated against
-pure-jnp oracles (ref.py) with interpret=True on CPU; on a real TPU set
-interpret=False.  The FL round hot path (per-client histograms + masked
-weighted aggregation) reaches these kernels through the trace-time backend
+pure-jnp oracles (ref.py) with ``interpret=True`` on CPU.  Every entry
+point defaults to ``interpret=False`` (compile for the TPU): a caller that
+wants the interpreter says so.  The FL round hot path (per-client
+histograms + masked weighted aggregation) reaches these kernels through the trace-time backend
 switch in ``dispatch.py`` — TPU compiles the Pallas kernels, CPU/GPU fall
 back to the XLA references (the accumulator patterns are TPU-shaped; see
 dispatch's docstring), and every engine routes through that one switch.

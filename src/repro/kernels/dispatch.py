@@ -36,8 +36,10 @@ Backend override — for tests and measurement:
 * the ``REPRO_COMPUTE_BACKEND`` env var overrides ``"auto"`` resolution
   process-wide (read at trace time), which is how the interpret-mode
   bit-identity tests drive the Pallas path through a full engine on CPU;
-* forcing ``"pallas"`` off-TPU silently implies interpret mode (the
-  kernels cannot lower to CPU/GPU there — see the platform note above).
+* forcing ``"pallas"`` off-TPU raises: the kernels cannot lower to CPU/GPU
+  (see the platform note above), and running them in the interpreter
+  instead would hide that the accelerator is missing.  Ask for
+  ``"pallas_interpret"`` to run the kernel bodies on the CPU.
 
 Numerics contract (pinned by tests/test_compute_dispatch.py):
 
@@ -96,10 +98,19 @@ def compute_backend(backend: str = "auto") -> str:
 
 
 def _interpret(backend: str) -> bool:
-    """Pallas kernels must run in interpret mode off-accelerator: the TPU
-    custom-calls do not compile on the CPU backend."""
-    return (backend == "pallas_interpret"
-            or jax.default_backend() not in _PALLAS_PLATFORMS)
+    """``interpret=`` for a resolved Pallas backend: True only when the
+    caller asked for ``"pallas_interpret"``.  A forced ``"pallas"`` on a
+    platform the kernels do not compile for is an error, never a silent
+    switch to the interpreter."""
+    if backend == "pallas_interpret":
+        return True
+    platform = jax.default_backend()
+    if platform not in _PALLAS_PLATFORMS:
+        raise RuntimeError(
+            f"compute backend 'pallas' needs one of {_PALLAS_PLATFORMS}, but "
+            f"JAX's default backend is {platform!r}; use 'pallas_interpret' "
+            "to run the kernels in the interpreter")
+    return False
 
 
 # ---------------------------------------------------------------------------

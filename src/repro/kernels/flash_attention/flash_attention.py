@@ -82,7 +82,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q/k/v: (BH, S, D) → (BH, S, D).  window=0 → full causal."""
     bh, s, d = q.shape
     block_q = min(block_q, s)

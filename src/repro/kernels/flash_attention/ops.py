@@ -13,7 +13,7 @@ from .flash_attention import flash_attention
 @functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
 def gqa_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, window: int = 0,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool = False) -> jax.Array:
     """q: (B, S, H, D); k/v: (B, S, KV, D) → (B, S, H, D)."""
     b, s, h, d = q.shape
     kvh = k.shape[2]

@@ -36,7 +36,7 @@ def _hist_kernel(labels_ref, valid_ref, o_ref, *, num_classes, block_s):
                                     "interpret"))
 def label_hist_kernel(labels: jax.Array, valid: jax.Array, num_classes: int,
                       block_b: int = 8, block_s: int = 512,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """labels: (B, n) int32, valid: (B, n) bool → (B, C) f32."""
     b, n = labels.shape
     pad_b = (-b) % block_b

@@ -18,7 +18,7 @@ from .label_hist import label_hist_kernel
 
 @functools.partial(jax.jit, static_argnames=("num_classes", "interpret"))
 def client_statistics(labels: jax.Array, num_classes: int = 10,
-                      interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                      interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """(B, n) ragged labels (−1 pad) → (hists (B, C), σ²/n scores (B,))."""
     valid = labels >= 0
     safe = jnp.where(valid, labels, 0)

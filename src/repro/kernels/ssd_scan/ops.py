@@ -12,7 +12,7 @@ from .ssd_scan import ssd_scan
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_apply(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
-              C: jax.Array, chunk: int = 128, interpret: bool = True):
+              C: jax.Array, chunk: int = 128, interpret: bool = False):
     """x: (b, S, H, P); dt: (b, S, H); A: (H,); B/C: (b, S, G, N), G | H.
     Returns (y (b, S, H, P), final_state (b, H, P, N)) — matches
     repro.models.layers._ssd_chunked."""

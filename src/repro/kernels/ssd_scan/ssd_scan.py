@@ -69,7 +69,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, state_scr,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
-             C: jax.Array, chunk: int = 128, interpret: bool = True):
+             C: jax.Array, chunk: int = 128, interpret: bool = False):
     """x: (BH, S, P); dt: (BH, S); A: (BH,); B/C: (BH, S, N).
     → (y (BH, S, P) f32, final_state (BH, P, N) f32).  S % chunk == 0."""
     bh, s, p = x.shape

@@ -20,7 +20,7 @@ def normalized_scales(weights: jax.Array, mask: jax.Array) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def aggregate_params(stacked_params: PyTree, weights: jax.Array,
-                     mask: jax.Array, interpret: bool = True) -> PyTree:
+                     mask: jax.Array, interpret: bool = False) -> PyTree:
     """FedAvg over the leading client axis of every leaf, Pallas-fused."""
     scales = normalized_scales(weights, mask)
 

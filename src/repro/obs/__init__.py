@@ -37,6 +37,7 @@ from repro.obs.trace import (
     events,
     instant,
     memory_snapshots,
+    pallas_kernel_calls,
     profiler,
     record_duration,
     record_memory_analysis,
@@ -69,6 +70,7 @@ __all__ = [
     "events",
     "instant",
     "memory_snapshots",
+    "pallas_kernel_calls",
     "profiler",
     "record_duration",
     "record_memory_analysis",

@@ -19,6 +19,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import re
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -143,9 +144,10 @@ def write_trace(path: Optional[str] = None) -> Optional[str]:
 @contextlib.contextmanager
 def profiler(label: str):
     """Wrap engine execution in ``jax.profiler.trace`` when REPRO_TRACE_DIR
-    is set; a plain span otherwise.  Profiler failures (unsupported backend,
-    double-start) degrade to the span — observability must never take down
-    the run."""
+    is set; a plain span otherwise.  An exception from the engine body
+    propagates unchanged.  A profiler that cannot start (unsupported
+    backend, a trace already running) raises: the caller asked for a trace,
+    and a run without it would look traced when it was not."""
     d = trace_dir()
     with span(f"engine_execute:{label}"):
         if d is None:
@@ -155,16 +157,37 @@ def profiler(label: str):
         prof_dir = os.path.join(d, "jax")
         os.makedirs(prof_dir, exist_ok=True)
         try:
-            with jax.profiler.trace(prof_dir):
-                yield
-        except Exception:
+            jax.profiler.start_trace(prof_dir)
+        except Exception as e:
+            raise RuntimeError(f"{ENV_TRACE_DIR}={d}: jax.profiler could not "
+                               f"start a trace in {prof_dir}") from e
+        try:
             yield
+        finally:
+            jax.profiler.stop_trace()
+
+
+_KERNEL_CALL = re.compile(
+    r'custom_call_target="tpu_custom_call".*?jit\((\w+)\)/pallas_call')
+
+
+def pallas_kernel_calls(hlo_text: str) -> Dict[str, int]:
+    """Compiled Pallas TPU kernel calls in an optimized HLO module's text,
+    counted by kernel: each ``tpu_custom_call`` instruction is keyed by the
+    jitted kernel function its metadata names
+    (``…/jit(label_hist_kernel)/pallas_call``).  Empty for a program that
+    holds no kernel — a CPU compile, or a TPU compile of the references."""
+    out: Dict[str, int] = {}
+    for name in _KERNEL_CALL.findall(hlo_text):
+        out[name] = out.get(name, 0) + 1
+    return out
 
 
 def record_memory_analysis(label: str, compiled: Any) -> None:
     """Best-effort ``compiled.memory_analysis()`` snapshot for one AOT
-    compile.  Backends without the API (or donation-opaque executables)
-    are skipped silently."""
+    compile, plus ``pallas_kernels`` — :func:`pallas_kernel_calls` of the
+    compiled program — when it holds any.  Backends without the API (or
+    donation-opaque executables) are skipped silently."""
     try:
         ma = compiled.memory_analysis()
         if ma is None:
@@ -176,6 +199,9 @@ def record_memory_analysis(label: str, compiled: Any) -> None:
             v = getattr(ma, field, None)
             if v is not None:
                 snap[field] = int(v)
+        kernels = pallas_kernel_calls(compiled.as_text())
+        if kernels:
+            snap["pallas_kernels"] = kernels
         if len(snap) > 1:
             with _LOCK:
                 _MEMORY.append(snap)

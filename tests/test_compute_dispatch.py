@@ -97,6 +97,20 @@ class TestBackendResolution:
         with pytest.raises(ValueError, match="compute backend"):
             compute_backend("cuda")
 
+    @pytest.mark.parametrize("op", ["hist", "mean", "sum"])
+    def test_forced_pallas_off_tpu_raises(self, op):
+        # no silent switch to the interpreter: on the CPU a forced "pallas"
+        # is an error, "pallas_interpret" the explicit way to the kernels
+        labels = jnp.zeros((2, 4), jnp.int32)
+        tree = {"w": jnp.ones((2, 3))}
+        call = {"hist": lambda: client_histograms(labels, 3, backend="pallas"),
+                "mean": lambda: masked_weighted_mean(tree, jnp.ones(2),
+                                                     backend="pallas"),
+                "sum": lambda: weighted_sum_tree(tree, jnp.ones(2),
+                                                 backend="pallas")}[op]
+        with pytest.raises(RuntimeError, match="pallas_interpret"):
+            call()
+
 
 class TestPallasInterpretParity:
     """Interpret-mode Pallas ≡ reference, at the shapes engines call with."""

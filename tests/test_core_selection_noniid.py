@@ -152,13 +152,13 @@ class TestAggregation:
     def test_psum_aggregate_shard_map(self):
         """Masked psum over a 1-device 'pod' axis == identity on the one shard."""
         mesh = jax.make_mesh((1,), ("pod",))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def f(p, m):
             return psum_aggregate(p, m, "pod")
 
-        out = shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=P())(
+        out = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                            out_specs=P())(
             {"w": jnp.ones(4)}, jnp.ones(()))
         np.testing.assert_allclose(np.asarray(out["w"]), 1.0)
 

@@ -1,5 +1,8 @@
-"""Pallas kernel validation: shape/dtype sweeps vs pure-jnp oracles
-(interpret=True — kernel bodies execute in Python on CPU; TPU is the target)."""
+"""Pallas kernel validation: shape/dtype sweeps vs pure-jnp oracles.
+
+Every call passes ``interpret=True`` (the kernel bodies execute in Python on
+the CPU); the entry points default to compiling for the TPU.  The chip
+compiles of the main-path kernels live in tests/test_tpu_compile.py."""
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +36,8 @@ class TestWeightedAgg:
         mask = mask.at[0].set(1.0)  # at least one selected
         w = weights * mask
         scales = w / w.sum()
-        got = weighted_agg_kernel(stacked, scales, block_n=256)
+        got = weighted_agg_kernel(stacked, scales, block_n=256,
+                                  interpret=True)
         want = weighted_agg_ref(stacked, weights, mask)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), **tol(dtype))
@@ -43,7 +47,7 @@ class TestWeightedAgg:
                    "b": jax.random.normal(KEY, (5, 3))}
         weights = jnp.ones(5)
         mask = jnp.array([1.0, 1, 0, 0, 1])
-        got = aggregate_params(stacked, weights, mask)
+        got = aggregate_params(stacked, weights, mask, interpret=True)
         want = jax.tree_util.tree_map(
             lambda s: weighted_agg_ref(s.reshape(5, -1), weights, mask
                                        ).reshape(s.shape[1:]), stacked)
@@ -58,7 +62,8 @@ class TestLabelHist:
     def test_matches_ref(self, b, n, c):
         labels = jax.random.randint(KEY, (b, n), 0, c)
         valid = jax.random.uniform(jax.random.PRNGKey(1), (b, n)) > 0.2
-        got = label_hist_kernel(labels, valid, c, block_b=4, block_s=64)
+        got = label_hist_kernel(labels, valid, c, block_b=4, block_s=64,
+                                interpret=True)
         want = label_hist_ref(labels, c, valid)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -80,7 +85,8 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (2, s, d), jnp.float32).astype(dtype)
         k = jax.random.normal(ks[1], (2, s, d), jnp.float32).astype(dtype)
         v = jax.random.normal(ks[2], (2, s, d), jnp.float32).astype(dtype)
-        got = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+        got = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                              interpret=True)
         want = attention_ref(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), **tol(dtype))
@@ -90,7 +96,7 @@ class TestFlashAttention:
         ks = jax.random.split(KEY, 3)
         q, k, v = (jax.random.normal(kk, (1, 128, 32)) for kk in ks)
         got = flash_attention(q, k, v, causal=True, window=window,
-                              block_q=32, block_k=32)
+                              block_q=32, block_k=32, interpret=True)
         want = attention_ref(q, k, v, causal=True, window=window)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
@@ -102,7 +108,7 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (b, s, h, d))
         k = jax.random.normal(ks[1], (b, s, kv, d))
         v = jax.random.normal(ks[2], (b, s, kv, d))
-        got = gqa_flash_attention(q, k, v, causal=True)
+        got = gqa_flash_attention(q, k, v, causal=True, interpret=True)
         want = L._sdpa(q, k, v, L.causal_mask(s, s), kv)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
@@ -110,7 +116,8 @@ class TestFlashAttention:
     def test_unaligned_seq_padding(self):
         ks = jax.random.split(KEY, 3)
         q, k, v = (jax.random.normal(kk, (1, 50, 16)) for kk in ks)
-        got = flash_attention(q, k, v, causal=True, block_q=16, block_k=16)
+        got = flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
+                              interpret=True)
         want = attention_ref(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
@@ -127,7 +134,7 @@ class TestSSDScan:
         A = -jnp.exp(jax.random.normal(ks[2], (bh,)) * 0.3)
         B = jax.random.normal(ks[3], (bh, s, n)) * 0.5
         C = jax.random.normal(ks[4], (bh, s, n)) * 0.5
-        y, fin = ssd_scan(x, dt, A, B, C, chunk=chunk)
+        y, fin = ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=True)
         y_ref, fin_ref = ssd_ref(x, dt, A, B, C)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-4)
@@ -144,7 +151,7 @@ class TestSSDScan:
         A = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
         B = jax.random.normal(ks[3], (b, s, g, n)) * 0.5
         C = jax.random.normal(ks[4], (b, s, g, n)) * 0.5
-        y_k, fin_k = ssd_apply(x, dt, A, B, C, chunk=16)
+        y_k, fin_k = ssd_apply(x, dt, A, B, C, chunk=16, interpret=True)
         y_m, fin_m = _ssd_chunked(x, dt, A, B, C, chunk=16)
         np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_m),
                                    rtol=1e-4, atol=1e-4)
@@ -160,7 +167,7 @@ class TestSSDScan:
         A = -jnp.exp(jax.random.normal(ks[2], (bh,)) * 0.3)
         B = (jax.random.normal(ks[3], (bh, s, n)) * 0.5).astype(dtype)
         C = (jax.random.normal(ks[4], (bh, s, n)) * 0.5).astype(dtype)
-        y, _ = ssd_scan(x, dt, A, B, C, chunk=16)
+        y, _ = ssd_scan(x, dt, A, B, C, chunk=16, interpret=True)
         y_ref, _ = ssd_ref(x, dt, A, B, C)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=5e-2, atol=5e-2)

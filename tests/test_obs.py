@@ -261,6 +261,36 @@ class TestTrace:
         monkeypatch.delenv("REPRO_TRACE_DIR", raising=False)
         assert write_trace() is None
 
+    def test_engine_error_under_trace_dir_surfaces_as_itself(
+            self, tmp_path, monkeypatch):
+        # sim stacks scenarios into one grid, so plans of different n make
+        # the engine itself raise — inside the profiler's trace
+        monkeypatch.setenv("REPRO_TRACE_DIR", str(tmp_path))
+        spec = ExperimentSpec(
+            scenarios=(ScenarioSpec.from_case("iid", samples_per_client=8),
+                       ScenarioSpec.from_case("case1b", samples_per_client=9)),
+            strategies=("random",), seeds=(0,), fl=MICRO, engine="sim")
+        with pytest.raises(ValueError, match="must share"):
+            run(spec)
+        # the profiler was stopped on the way out: a new trace can start
+        with pytest.raises(ValueError, match="must share"):
+            run(spec)
+
+    def test_profiler_start_failure_raises(self, tmp_path, monkeypatch):
+        import jax
+
+        from repro.obs.trace import profiler
+
+        def refuse(*a, **k):
+            raise RuntimeError("profiler unavailable")
+
+        monkeypatch.setenv("REPRO_TRACE_DIR", str(tmp_path))
+        monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+        with pytest.raises(RuntimeError, match="could not start") as info:
+            with profiler("sim"):
+                pass
+        assert "profiler unavailable" in str(info.value.__cause__)
+
 
 # ---------------------------------------------------------------------------
 # Report + health flags
