@@ -27,8 +27,9 @@ def local_train(params: PyTree, opt, batches: Dict[str, Array],
     def one_batch(carry, batch):
         p, st = carry
         (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(p, batch)
-        ups, st = opt.update(grads, st, p)
-        p = apply_updates(p, ups)
+        with jax.named_scope("opt.update"):
+            ups, st = opt.update(grads, st, p)
+            p = apply_updates(p, ups)
         return (p, st), loss
 
     def one_epoch(carry, _):

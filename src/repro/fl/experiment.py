@@ -949,7 +949,7 @@ def _engine_sharded(spec: ExperimentSpec, lowered: Sequence[LoweredScenario],
     from jax.sharding import PartitionSpec as P
 
     from repro.data import client_batches
-    from repro.obs import (make_collector, resolve_metrics,
+    from repro.obs import (make_collector, phase, resolve_metrics,
                            resolve_telemetry_request)
     from repro.optim import get_optimizer
     from .client import local_gradient, local_train
@@ -973,17 +973,21 @@ def _engine_sharded(spec: ExperimentSpec, lowered: Sequence[LoweredScenario],
     opt = get_optimizer(cfg.optimizer, cfg.lr)
     eval_batch = wl.eval_set(ds, spec.eval_n_per_class)
     eval_fn = wl.make_eval(ds)
-    eval_jit = jax.jit(lambda p: eval_fn(p, eval_batch))
+    @jax.jit
+    def eval_jit(p):
+        with phase("eval"):
+            return eval_fn(p, eval_batch)
     if agg.clustered:
         # Per-cluster eval + the valid-population mixture, the same f32 jnp
         # ops as the other engines' clustered eval.
         @jax.jit
         def eval_mix_jit(p, w):
-            l_c, m_c = jax.vmap(lambda q: eval_fn(q, eval_batch))(p)
-            tot = jnp.maximum(w.sum(), 1.0)
-            return ((l_c * w).sum() / tot,
-                    (m_c["accuracy"] * w).sum() / tot,
-                    m_c["accuracy"], l_c)
+            with phase("eval"):
+                l_c, m_c = jax.vmap(lambda q: eval_fn(q, eval_batch))(p)
+                tot = jnp.maximum(w.sum(), 1.0)
+                return ((l_c * w).sum() / tot,
+                        (m_c["accuracy"] * w).sum() / tot,
+                        m_c["accuracy"], l_c)
     loss_fn = wl.make_loss(ds)
 
     if agg.base == "fedavg":
@@ -1173,8 +1177,8 @@ def run(spec: ExperimentSpec, *, ds=None) -> ExperimentResult:
     The old per-engine keys (``meta["sharded"]`` / ``meta["population"]`` /
     ``meta["clustered"]``) are kept as aliases of the envelope's
     ``engine_facts``."""
-    from repro.obs import (build_envelope, memory_snapshots, profiler,
-                           record_duration, span, span_summary, write_trace)
+    from repro.obs import (build_envelope, memory_snapshots, profiler, span,
+                           span_summary, write_trace)
     with span("validate", engine=spec.engine):
         spec.validate()
     with span("lower_scenarios", engine=spec.engine):
@@ -1186,11 +1190,6 @@ def run(spec: ExperimentSpec, *, ds=None) -> ExperimentResult:
         out = engine(spec, lowered, ds)
     acc, loss, nsel, wall_s, compile_s = out[:5]
     meta = dict(out[5]) if len(out) > 5 else {}
-    # The engines time their own compile/execute split internally (AOT
-    # lowering happens inside the engine); fold the totals into the span
-    # stream so the Chrome trace carries them.
-    record_duration(f"engine_compile:{spec.engine}", compile_s)
-    record_duration(f"engine_wall:{spec.engine}", wall_s)
     series = meta.pop("_telemetry_series", None)
     facts = {k: meta[k] for k in ("sharded", "population", "clustered")
              if k in meta}

@@ -27,8 +27,8 @@ import numpy as np
 
 from repro.core import plan_round
 from repro.data import client_batches
-from repro.obs import (make_collector, record_memory_analysis, resolve_metrics,
-                       resolve_telemetry_request, span)
+from repro.obs import (make_collector, phase, record_memory_analysis,
+                       resolve_metrics, resolve_telemetry_request, span)
 from .round import (make_fl_round, resolve_adversary, resolve_aggregator,
                     stack_global_params)
 from .workloads import Workload, get_workload
@@ -175,13 +175,17 @@ def run_fl_host(plan: np.ndarray, fl_cfg, *, strategy: Optional[str] = None,
         # for the mixture exactly as it does for the single-model trajectory.
         @jax.jit
         def eval_jit(p, w):
-            l_c, m_c = jax.vmap(lambda q: eval_fn(q, eval_batch))(p)
-            tot = jnp.maximum(w.sum(), 1.0)
-            return ((l_c * w).sum() / tot,
-                    {"accuracy": (m_c["accuracy"] * w).sum() / tot},
-                    m_c["accuracy"], l_c)
+            with phase("eval"):
+                l_c, m_c = jax.vmap(lambda q: eval_fn(q, eval_batch))(p)
+                tot = jnp.maximum(w.sum(), 1.0)
+                return ((l_c * w).sum() / tot,
+                        {"accuracy": (m_c["accuracy"] * w).sum() / tot},
+                        m_c["accuracy"], l_c)
     else:
-        eval_jit = jax.jit(lambda p: eval_fn(p, eval_batch))
+        @jax.jit
+        def eval_jit(p):
+            with phase("eval"):
+                return eval_fn(p, eval_batch)
 
     hist_acc, hist_loss, hist_sel = [], [], []
     c_acc, c_loss, c_assign = [], [], []

@@ -83,8 +83,8 @@ from repro.core import (STRATEGIES, get_aggregator, interpolate,
 from repro.core.selection import NEG_INF
 from repro.data import client_batches
 from repro.kernels.dispatch import client_histograms, masked_weighted_mean
-from repro.obs import (collect_metrics, record_memory_analysis,
-                       resolve_metrics, resolve_telemetry_request)
+from repro.obs import (collect_metrics, phase, record_memory_analysis,
+                       resolve_metrics, resolve_telemetry_request, span)
 from repro.optim import apply_updates, get_optimizer
 from .client import local_gradient, local_train
 from .workloads import Workload, get_workload, materialize_rows
@@ -305,44 +305,52 @@ def make_hier_trial_fn(fl_cfg, ds=None, *, strategy: str,
                                                   keepdims=False)
             avail_t = jax.lax.dynamic_index_in_dim(avail, t % avail.shape[0],
                                                    0, keepdims=False)
-            ids, live_b, _, _ = streamed_selection(
-                lambda b, _ids: jax.lax.dynamic_slice_in_dim(
-                    plan_t, b * block_size, block_size, 0),
-                lambda b: jax.lax.dynamic_slice_in_dim(
-                    avail_t, b * block_size, block_size, 0),
-                num_blocks=e_blocks, block_size=block_size,
-                num_classes=n_classes, strategy=strategy,
-                key=jax.random.fold_in(kt, 1), budget=budget)
-            live = live_b.astype(jnp.float32)
-            # Registry-mode payload: sim's exact materialize key — the only
-            # way to bit-match its shape-dependent PRNG draws (see module
-            # docstring); phase A above still never built dense statistics.
-            data = wl.materialize(ds, plan_t, jax.random.fold_in(kt, 0))
-            batches = client_batches(data, fl_cfg.batch_size, wl.batch_keys)
-            data_sel = jax.tree_util.tree_map(lambda x: x[ids], batches)
-            sizes = data_sel["valid"].reshape(budget, -1).sum(-1).astype(
-                jnp.float32)
-            block_ids = ids // block_size
-            if agg.base == "fedsgd":
-                grads, _ = jax.vmap(
-                    lambda b: local_gradient(params, b, loss_fn))(data_sel)
-                agg_g = two_tier_weighted_mean(grads, live, sizes, block_ids,
-                                               e_blocks)
-                new_params = apply_updates(
-                    params,
-                    jax.tree_util.tree_map(lambda g: -fl_cfg.lr * g, agg_g))
-            else:
-                trained, _ = jax.vmap(
-                    lambda b: local_train(params, opt, b, loss_fn,
-                                          fl_cfg.local_epochs))(data_sel)
-                agg_p = two_tier_weighted_mean(trained, live, sizes,
-                                               block_ids, e_blocks)
-                new_params = interpolate(params, agg_p, fl_cfg.server_lr)
-            any_live = live.sum() > 0
-            new_params = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(any_live, new, old),
-                new_params, params)
-            ev_loss, ev_m = eval_fn(new_params, eval_batch)
+            with phase("select"):
+                ids, live_b, _, _ = streamed_selection(
+                    lambda b, _ids: jax.lax.dynamic_slice_in_dim(
+                        plan_t, b * block_size, block_size, 0),
+                    lambda b: jax.lax.dynamic_slice_in_dim(
+                        avail_t, b * block_size, block_size, 0),
+                    num_blocks=e_blocks, block_size=block_size,
+                    num_classes=n_classes, strategy=strategy,
+                    key=jax.random.fold_in(kt, 1), budget=budget)
+                live = live_b.astype(jnp.float32)
+            with phase("materialize"):
+                # Registry-mode payload: sim's exact materialize key — the
+                # only way to bit-match its shape-dependent PRNG draws (see
+                # module docstring); phase A above still never built dense
+                # statistics.
+                data = wl.materialize(ds, plan_t, jax.random.fold_in(kt, 0))
+                batches = client_batches(data, fl_cfg.batch_size,
+                                         wl.batch_keys)
+                data_sel = jax.tree_util.tree_map(lambda x: x[ids], batches)
+                sizes = data_sel["valid"].reshape(budget, -1).sum(-1).astype(
+                    jnp.float32)
+                block_ids = ids // block_size
+            with phase("train"):
+                # fedsgd clients report gradients, fedavg clients weights.
+                if agg.base == "fedsgd":
+                    updates, _ = jax.vmap(
+                        lambda b: local_gradient(params, b, loss_fn))(data_sel)
+                else:
+                    updates, _ = jax.vmap(
+                        lambda b: local_train(params, opt, b, loss_fn,
+                                              fl_cfg.local_epochs))(data_sel)
+            with phase("aggregate"):
+                mean = two_tier_weighted_mean(updates, live, sizes, block_ids,
+                                              e_blocks)
+                if agg.base == "fedsgd":
+                    new_params = apply_updates(
+                        params,
+                        jax.tree_util.tree_map(lambda g: -fl_cfg.lr * g, mean))
+                else:
+                    new_params = interpolate(params, mean, fl_cfg.server_lr)
+                any_live = live.sum() > 0
+                new_params = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(any_live, new, old),
+                    new_params, params)
+            with phase("eval"):
+                ev_loss, ev_m = eval_fn(new_params, eval_batch)
             main = (ev_m["accuracy"], ev_loss, live.sum(), live.sum())
             if metrics:
                 # Rebuild the dense (N,) selection mask from the streamed
@@ -482,9 +490,11 @@ def make_async_trial_fn(fl_cfg, ds=None, *, strategy: str,
                                                   keepdims=False)
             avail_t = jax.lax.dynamic_index_in_dim(avail, t % avail.shape[0],
                                                    0, keepdims=False)
-            data = wl.materialize(ds, plan_t, jax.random.fold_in(kt, 0))
-            hists = data["hists"] * avail_t[:, None]
-            batches = client_batches(data, fl_cfg.batch_size, wl.batch_keys)
+            with phase("materialize"):
+                data = wl.materialize(ds, plan_t, jax.random.fold_in(kt, 0))
+                hists = data["hists"] * avail_t[:, None]
+                batches = client_batches(data, fl_cfg.batch_size,
+                                         wl.batch_keys)
             theta_t = jax.tree_util.tree_map(lambda r: r[t % ring_len], ring)
             blocks_t = jax.lax.dynamic_index_in_dim(sched_blocks, t, 0,
                                                     keepdims=False)
@@ -510,37 +520,47 @@ def make_async_trial_fn(fl_cfg, ds=None, *, strategy: str,
                 theta_stale = jax.tree_util.tree_map(
                     lambda r: jax.lax.dynamic_index_in_dim(
                         r, (t - tau) % ring_len, 0, keepdims=False), ring)
-                hists_e = jax.lax.dynamic_slice_in_dim(
-                    hists, e * block_size, block_size, 0)
-                r = select(jax.random.fold_in(jax.random.fold_in(kt, 1), j),
-                           hists_e, blk_budget)
-                mask = r.mask * (hists_e.sum(-1) > 0)
-                idx_local = r.order[:blk_budget]
-                live = mask[idx_local]
-                idx = e * block_size + idx_local
-                data_sel = jax.tree_util.tree_map(lambda x: x[idx], batches)
-                sizes = data_sel["valid"].reshape(blk_budget, -1).sum(-1)\
-                    .astype(jnp.float32)
-                if agg.base == "fedsgd":
-                    grads, _ = jax.vmap(
-                        lambda b: local_gradient(theta_stale, b,
-                                                 loss_fn))(data_sel)
-                    g_e = masked_weighted_mean(grads, live, sizes)
-                    delta = jax.tree_util.tree_map(
-                        lambda g: -fl_cfg.lr * g.astype(jnp.float32), g_e)
-                else:
-                    trained, _ = jax.vmap(
-                        lambda b: local_train(theta_stale, opt, b, loss_fn,
-                                              fl_cfg.local_epochs))(data_sel)
-                    bar_e = masked_weighted_mean(trained, live, sizes)
-                    delta = jax.tree_util.tree_map(
-                        lambda a, s: a.astype(jnp.float32)
-                        - s.astype(jnp.float32), bar_e, theta_stale)
-                # Block weight: live data size; an empty block (count=0)
-                # contributes exactly zero to both numerator and denominator.
-                w = (live * sizes).sum() * staleness_weight(tau, alpha)
-                buf_num = jax.tree_util.tree_map(
-                    lambda acc, d: acc + w * d, buf_num, delta)
+                with phase("select"):
+                    hists_e = jax.lax.dynamic_slice_in_dim(
+                        hists, e * block_size, block_size, 0)
+                    r = select(
+                        jax.random.fold_in(jax.random.fold_in(kt, 1), j),
+                        hists_e, blk_budget)
+                    mask = r.mask * (hists_e.sum(-1) > 0)
+                    idx_local = r.order[:blk_budget]
+                    live = mask[idx_local]
+                    idx = e * block_size + idx_local
+                    data_sel = jax.tree_util.tree_map(lambda x: x[idx],
+                                                      batches)
+                    sizes = data_sel["valid"].reshape(blk_budget, -1).sum(-1)\
+                        .astype(jnp.float32)
+                with phase("train"):
+                    if agg.base == "fedsgd":
+                        updates, _ = jax.vmap(
+                            lambda b: local_gradient(theta_stale, b,
+                                                     loss_fn))(data_sel)
+                    else:
+                        updates, _ = jax.vmap(
+                            lambda b: local_train(theta_stale, opt, b,
+                                                  loss_fn,
+                                                  fl_cfg.local_epochs)
+                        )(data_sel)
+                with phase("aggregate"):
+                    mean = masked_weighted_mean(updates, live, sizes)
+                    if agg.base == "fedsgd":
+                        delta = jax.tree_util.tree_map(
+                            lambda g: -fl_cfg.lr * g.astype(jnp.float32),
+                            mean)
+                    else:
+                        delta = jax.tree_util.tree_map(
+                            lambda a, s: a.astype(jnp.float32)
+                            - s.astype(jnp.float32), mean, theta_stale)
+                    # Block weight: live data size; an empty block (count=0)
+                    # contributes exactly zero to both numerator and
+                    # denominator.
+                    w = (live * sizes).sum() * staleness_weight(tau, alpha)
+                    buf_num = jax.tree_util.tree_map(
+                        lambda acc, d: acc + w * d, buf_num, delta)
                 if metrics:
                     sel_mask = sel_mask.at[idx].add(live)
                     return (buf_num, buf_den + w, n_live + live.sum(),
@@ -552,17 +572,19 @@ def make_async_trial_fn(fl_cfg, ds=None, *, strategy: str,
                 buf_num, buf_den, n_live, sel_mask = buf_out
             else:
                 buf_num, buf_den, n_live = buf_out
-            denom = jnp.maximum(buf_den, 1e-12)
-            theta_new = jax.tree_util.tree_map(
-                lambda p, acc: (p + server_lr * (acc / denom)).astype(p.dtype),
-                theta_t, buf_num)
-            theta_new = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(buf_den > 0, new, old),
-                theta_new, theta_t)
-            ring = jax.tree_util.tree_map(
-                lambda r, n: jax.lax.dynamic_update_index_in_dim(
-                    r, n, (t + 1) % ring_len, 0), ring, theta_new)
-            ev_loss, ev_m = eval_fn(theta_new, eval_batch)
+            with phase("aggregate"):
+                denom = jnp.maximum(buf_den, 1e-12)
+                theta_new = jax.tree_util.tree_map(
+                    lambda p, acc: (p + server_lr * (acc / denom)).astype(
+                        p.dtype), theta_t, buf_num)
+                theta_new = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(buf_den > 0, new, old),
+                    theta_new, theta_t)
+                ring = jax.tree_util.tree_map(
+                    lambda r, n: jax.lax.dynamic_update_index_in_dim(
+                        r, n, (t + 1) % ring_len, 0), ring, theta_new)
+            with phase("eval"):
+                ev_loss, ev_m = eval_fn(theta_new, eval_batch)
             main = (ev_m["accuracy"], ev_loss, n_live)
             if metrics:
                 state = {"hists": hists,
@@ -620,9 +642,10 @@ def _run_cells(spec, lowered, make_trial, out_width: int,
                 plan = low.plan[r] if low.per_seed else low.plan
                 args = (jnp.asarray(plan, jnp.int32), jnp.int32(seed), av)
                 if compiled is None:
-                    t0 = time.perf_counter()
-                    compiled = fn.lower(*args).compile()
-                    compile_s += time.perf_counter() - t0
+                    with span("compile", engine=engine_label,
+                              what=f"{low.name}:{strat}") as sp:
+                        compiled = fn.lower(*args).compile()
+                    compile_s += sp.duration_s
                     record_memory_analysis(
                         f"{engine_label}:{low.name}:{strat}", compiled)
                 t0 = time.perf_counter()
@@ -784,33 +807,41 @@ def make_population_round(*, plan_fn: Callable[[Array, Array], Array],
         kp = jax.random.fold_in(key_t, 0)      # plan stream
         kd = jax.random.fold_in(key_t, 1)      # payload stream
         ks = jax.random.fold_in(key_t, 2)      # strategy stream
-        ids, live_b, scores, stats = streamed_selection(
-            lambda b, ids_b: plan_fn(kp, ids_b),
-            lambda b: jnp.ones((block_size,), jnp.float32),
-            num_blocks=e_blocks, block_size=block_size,
-            num_classes=n_classes, strategy=strategy, key=ks, budget=budget)
-        live = live_b.astype(jnp.float32)
-        labels_sel = plan_fn(kp, ids)          # id-keyed ⇒ same rows as scan
-        data = materialize_rows(wl, ds, labels_sel, kd, ids)
-        batches = client_batches(data, batch_size, wl.batch_keys)
-        sizes = data["valid"].reshape(budget, -1).sum(-1).astype(jnp.float32)
-        trained, _ = jax.vmap(
-            lambda b: local_train(params, opt, b, loss_fn,
-                                  local_epochs))(batches)
-        # Two-tier reduction over the edges that actually own a selected
-        # client: at most ``budget`` of the N/block_size edges are touched,
-        # so remap their block ids into a dense ≤budget rank space before
-        # forming partials — empty edges ship nothing, the reassociated sum
-        # is unchanged, and the (num_edges, |θ|) partial tree stays
-        # O(budget·|θ|) instead of O(N/block_size·|θ|).
-        owner = ids // block_size
-        uniq = jnp.unique(owner, size=budget, fill_value=e_blocks)
-        agg_p = two_tier_weighted_mean(trained, live, sizes,
-                                       jnp.searchsorted(uniq, owner), budget)
-        new_params = interpolate(params, agg_p, server_lr)
-        any_live = live.sum() > 0
-        new_params = jax.tree_util.tree_map(
-            lambda new, old: jnp.where(any_live, new, old), new_params, params)
+        with phase("select"):
+            ids, live_b, scores, stats = streamed_selection(
+                lambda b, ids_b: plan_fn(kp, ids_b),
+                lambda b: jnp.ones((block_size,), jnp.float32),
+                num_blocks=e_blocks, block_size=block_size,
+                num_classes=n_classes, strategy=strategy, key=ks,
+                budget=budget)
+            live = live_b.astype(jnp.float32)
+        with phase("materialize"):
+            labels_sel = plan_fn(kp, ids)      # id-keyed ⇒ same rows as scan
+            data = materialize_rows(wl, ds, labels_sel, kd, ids)
+            batches = client_batches(data, batch_size, wl.batch_keys)
+            sizes = data["valid"].reshape(budget, -1).sum(-1).astype(
+                jnp.float32)
+        with phase("train"):
+            trained, _ = jax.vmap(
+                lambda b: local_train(params, opt, b, loss_fn,
+                                      local_epochs))(batches)
+        with phase("aggregate"):
+            # Two-tier reduction over the edges that actually own a selected
+            # client: at most ``budget`` of the N/block_size edges are
+            # touched, so remap their block ids into a dense ≤budget rank
+            # space before forming partials — empty edges ship nothing, the
+            # reassociated sum is unchanged, and the (num_edges, |θ|)
+            # partial tree stays O(budget·|θ|) instead of O(N/block_size·|θ|).
+            owner = ids // block_size
+            uniq = jnp.unique(owner, size=budget, fill_value=e_blocks)
+            agg_p = two_tier_weighted_mean(trained, live, sizes,
+                                           jnp.searchsorted(uniq, owner),
+                                           budget)
+            new_params = interpolate(params, agg_p, server_lr)
+            any_live = live.sum() > 0
+            new_params = jax.tree_util.tree_map(
+                lambda new, old: jnp.where(any_live, new, old), new_params,
+                params)
         info = {"selected": ids, "live": live, "scores": scores,
                 "num_selected": live.sum(), "hist_sum": stats["hist_sum"],
                 "n_valid": stats["n_valid"],

@@ -28,6 +28,7 @@ from repro.core import (Aggregator, cluster_counts, get_aggregator,
                         get_strategy, interpolate, kmeans_cluster,
                         selection_budget)
 from repro.kernels.dispatch import masked_weighted_mean
+from repro.obs import phase
 from repro.optim import apply_updates, get_optimizer
 from .client import local_train, local_gradient
 
@@ -175,56 +176,62 @@ def client_update_step(global_params: PyTree, data_sel: Dict[str, Array],
                                      jax.tree_util.tree_leaves(updates))))
         return jnp.sqrt(sq)
 
-    if agg.base == "fedsgd":
-        grads, m = jax.vmap(
-            lambda b: local_gradient(global_params, b, loss_fn))(data_sel)
-        grads = _as_reported(grads, None)
-        if want_client_norms:
-            m = dict(m, update_norm=_norms(grads, None))
-        agg_g = reduce(grads, live, sizes)
-        new_params = apply_updates(
-            global_params,
-            jax.tree_util.tree_map(lambda g: -fl_cfg.lr * g, agg_g))
-    else:
-        if stale_params is None:
-            trained, m = jax.vmap(
-                lambda b: local_train(global_params, opt, b, loss_fn,
-                                      fl_cfg.local_epochs))(data_sel)
-            base = global_params
+    # fedsgd clients report gradients, fedavg clients trained weights.
+    with phase("train"):
+        if agg.base == "fedsgd":
+            updates, m = jax.vmap(
+                lambda b: local_gradient(global_params, b, loss_fn))(data_sel)
+            updates = _as_reported(updates, None)
+            if want_client_norms:
+                m = dict(m, update_norm=_norms(updates, None))
         else:
-            # Per-slot training base: byzantine slots start from the stale
-            # global, honest slots from the current one.
-            a_bool = adv > 0
-            base = jax.tree_util.tree_map(
-                lambda g, st: jnp.where(
-                    _slot_bcast(a_bool, g[None]),
-                    jnp.broadcast_to(st, (n_sel,) + st.shape),
-                    jnp.broadcast_to(g, (n_sel,) + g.shape)),
-                global_params, stale_params)
-            trained, m = jax.vmap(
-                lambda p, b: local_train(p, opt, b, loss_fn,
-                                         fl_cfg.local_epochs))(base, data_sel)
-        trained = _as_reported(
-            trained,
-            base if stale_params is not None else
-            jax.tree_util.tree_map(
-                lambda g: jnp.broadcast_to(g, (n_sel,) + g.shape),
-                global_params) if poison_scale is not None else None)
-        if want_client_norms:
-            nb = (base if stale_params is not None else
-                  jax.tree_util.tree_map(
-                      lambda g: jnp.broadcast_to(g, (n_sel,) + g.shape),
-                      global_params))
-            m = dict(m, update_norm=_norms(trained, nb))
-        agg_p = reduce(trained, live, sizes)
-        new_params = interpolate(global_params, agg_p, fl_cfg.server_lr)
+            if stale_params is None:
+                updates, m = jax.vmap(
+                    lambda b: local_train(global_params, opt, b, loss_fn,
+                                          fl_cfg.local_epochs))(data_sel)
+                base = global_params
+            else:
+                # Per-slot training base: byzantine slots start from the
+                # stale global, honest slots from the current one.
+                a_bool = adv > 0
+                base = jax.tree_util.tree_map(
+                    lambda g, st: jnp.where(
+                        _slot_bcast(a_bool, g[None]),
+                        jnp.broadcast_to(st, (n_sel,) + st.shape),
+                        jnp.broadcast_to(g, (n_sel,) + g.shape)),
+                    global_params, stale_params)
+                updates, m = jax.vmap(
+                    lambda p, b: local_train(p, opt, b, loss_fn,
+                                             fl_cfg.local_epochs)
+                )(base, data_sel)
+            updates = _as_reported(
+                updates,
+                base if stale_params is not None else
+                jax.tree_util.tree_map(
+                    lambda g: jnp.broadcast_to(g, (n_sel,) + g.shape),
+                    global_params) if poison_scale is not None else None)
+            if want_client_norms:
+                nb = (base if stale_params is not None else
+                      jax.tree_util.tree_map(
+                          lambda g: jnp.broadcast_to(g, (n_sel,) + g.shape),
+                          global_params))
+                m = dict(m, update_norm=_norms(updates, nb))
 
-    # Algorithm 1's count=0 degradation: an empty selection must leave the
-    # global params untouched (the ε-denominator mean would zero them).
-    any_live = live.sum() > 0
-    new_params = jax.tree_util.tree_map(
-        lambda new, old: jnp.where(any_live, new, old),
-        new_params, global_params)
+    with phase("aggregate"):
+        mean = reduce(updates, live, sizes)
+        if agg.base == "fedsgd":
+            new_params = apply_updates(
+                global_params,
+                jax.tree_util.tree_map(lambda g: -fl_cfg.lr * g, mean))
+        else:
+            new_params = interpolate(global_params, mean, fl_cfg.server_lr)
+        # Algorithm 1's count=0 degradation: an empty selection must leave
+        # the global params untouched (the ε-denominator mean would zero
+        # them).
+        any_live = live.sum() > 0
+        new_params = jax.tree_util.tree_map(
+            lambda new, old: jnp.where(any_live, new, old),
+            new_params, global_params)
     return new_params, m
 
 
@@ -255,30 +262,32 @@ def clustered_update_step(global_stack: PyTree, cluster_sel: Array,
     member = (cluster_sel[None, :] == jnp.arange(m_clusters)[:, None])
     live_mc = member.astype(live.dtype) * live[None, :]
 
-    if agg.base == "fedsgd":
-        grads, m = jax.vmap(
-            lambda p, b: local_gradient(p, b, loss_fn))(params_sel, data_sel)
+    with phase("train"):
+        if agg.base == "fedsgd":
+            updates, m = jax.vmap(
+                lambda p, b: local_gradient(p, b, loss_fn))(params_sel,
+                                                            data_sel)
+        else:
+            updates, m = jax.vmap(
+                lambda p, b: local_train(p, opt, b, loss_fn,
+                                         fl_cfg.local_epochs)
+            )(params_sel, data_sel)
 
-        def update_one(g_c, live_c):
-            agg_g = reduce(grads, live_c, sizes)
+    def update_one(g_c, live_c):
+        mean = reduce(updates, live_c, sizes)
+        if agg.base == "fedsgd":
             return apply_updates(
-                g_c, jax.tree_util.tree_map(lambda g: -fl_cfg.lr * g, agg_g))
-    else:
-        trained, m = jax.vmap(
-            lambda p, b: local_train(p, opt, b, loss_fn,
-                                     fl_cfg.local_epochs))(params_sel, data_sel)
+                g_c, jax.tree_util.tree_map(lambda g: -fl_cfg.lr * g, mean))
+        return interpolate(g_c, mean, fl_cfg.server_lr)
 
-        def update_one(g_c, live_c):
-            agg_p = reduce(trained, live_c, sizes)
-            return interpolate(g_c, agg_p, fl_cfg.server_lr)
-
-    new_stack = jax.vmap(update_one)(global_stack, live_mc)
-    any_live_c = live_mc.sum(-1) > 0                       # (M,)
-    new_stack = jax.tree_util.tree_map(
-        lambda new, old: jnp.where(
-            any_live_c.reshape((m_clusters,) + (1,) * (new.ndim - 1)),
-            new, old),
-        new_stack, global_stack)
+    with phase("aggregate"):
+        new_stack = jax.vmap(update_one)(global_stack, live_mc)
+        any_live_c = live_mc.sum(-1) > 0                       # (M,)
+        new_stack = jax.tree_util.tree_map(
+            lambda new, old: jnp.where(
+                any_live_c.reshape((m_clusters,) + (1,) * (new.ndim - 1)),
+                new, old),
+            new_stack, global_stack)
     return new_stack, m
 
 
@@ -333,18 +342,21 @@ def make_fl_round(loss_fn, fl_cfg, strategy_name: str | None = None,
                  hists: Array, key: Array, adv: Array | None = None,
                  stale_params: PyTree | None = None
                  ) -> Tuple[PyTree, Dict[str, Array]]:
-        sel = strategy(key, hists, n_sel)
-        # The gather width is the STRATEGY's static budget, not
-        # clients_per_round: "full" gathers the whole population, a wide
-        # registered strategy gathers its declared slot count untruncated.
-        budget = selection_budget(sel, n_sel, hists.shape[0])
-        idx = sel.order[:budget]                      # clients asked to train
-        live = sel.mask[idx]                          # 0 where count < budget
-        data_sel = jax.tree_util.tree_map(lambda x: x[idx], round_batches)
+        with phase("select"):
+            sel = strategy(key, hists, n_sel)
+            # The gather width is the STRATEGY's static budget, not
+            # clients_per_round: "full" gathers the whole population, a wide
+            # registered strategy gathers its declared slot count
+            # untruncated.
+            budget = selection_budget(sel, n_sel, hists.shape[0])
+            idx = sel.order[:budget]                  # clients asked to train
+            live = sel.mask[idx]                      # 0 where count < budget
+            data_sel = jax.tree_util.tree_map(lambda x: x[idx], round_batches)
         extra = {}
         if agg.clustered:
-            assign, cent = kmeans_cluster(hists, agg.n_clusters,
-                                          n_iters=agg.kmeans_iters)
+            with phase("cluster"):
+                assign, cent = kmeans_cluster(hists, agg.n_clusters,
+                                              n_iters=agg.kmeans_iters)
             new_params, m = clustered_update_step(
                 global_params, assign[idx], data_sel, live, loss_fn, opt,
                 fl_cfg, agg)

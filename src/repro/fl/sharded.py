@@ -73,6 +73,7 @@ from repro.core.aggregation import (exchange_selected_shards,
                                     gather_client_shards, interpolate,
                                     psum_weighted_mean)
 from repro.kernels.dispatch import client_histograms, weighted_sum_tree
+from repro.obs import phase
 
 Array = jax.Array
 PyTree = Any
@@ -254,62 +255,70 @@ def make_sharded_fl_round(mesh: Mesh, client_axis: str,
         stale_params = rest.pop(0) if with_stale else None
         # labels/valid: (num_clients, n_i) sharded over the client axis →
         # per-shard (per_group, n_i); batch leaves likewise (per_group, ...).
-        hist = client_histograms(jnp.where(valid, labels, 0), num_classes,
-                                 valid)
-        if avail is not None:
-            hist = hist * avail[:, None].astype(hist.dtype)  # dark → empty
-        hists_all = jax.lax.all_gather(hist, client_axis, tiled=True)  # (N,C)
-        sel = select_fn(key, hists_all, n_select)    # replicated on all shards
-        sizes = hists_all.sum(-1)                    # n_i (valid counts)
-        g = jax.lax.axis_index(client_axis)
+        with phase("materialize"):
+            hist = client_histograms(jnp.where(valid, labels, 0), num_classes,
+                                     valid)
+            if avail is not None:
+                hist = hist * avail[:, None].astype(hist.dtype)  # dark → empty
+        with phase("select"):
+            hists_all = jax.lax.all_gather(hist, client_axis,
+                                           tiled=True)       # (N, C)
+            sel = select_fn(key, hists_all, n_select)  # replicated everywhere
+            sizes = hists_all.sum(-1)                  # n_i (valid counts)
+            g = jax.lax.axis_index(client_axis)
 
-        if mode == "gather":
-            # Re-shard: the top-B_pad selected clients' batch shards move so
-            # each group trains exactly `slots` of them — the other N − B_pad
-            # clients spend zero training FLOPs.
-            my_slots = jax.lax.dynamic_slice_in_dim(
-                sel.order[:budget_padded], g * slots, slots)
-            if exchange == "a2a":
-                my_batch = exchange_selected_shards(
-                    batch, sel.order[:budget_padded], client_axis,
-                    num_groups=n_groups, per_group=per_group)
+            if mode == "gather":
+                # Re-shard: the top-B_pad selected clients' batch shards move
+                # so each group trains exactly `slots` of them — the other
+                # N − B_pad clients spend zero training FLOPs.
+                my_slots = jax.lax.dynamic_slice_in_dim(
+                    sel.order[:budget_padded], g * slots, slots)
+                if exchange == "a2a":
+                    my_batch = exchange_selected_shards(
+                        batch, sel.order[:budget_padded], client_axis,
+                        num_groups=n_groups, per_group=per_group)
+                else:
+                    my_batch = jax.tree_util.tree_map(
+                        lambda x: x[my_slots],
+                        gather_client_shards(batch, client_axis))
             else:
-                my_batch = jax.tree_util.tree_map(
-                    lambda x: x[my_slots],
-                    gather_client_shards(batch, client_axis))
-        else:
-            my_slots = g * per_group + jnp.arange(per_group, dtype=jnp.int32)
-            my_batch = batch
-        live = sel.mask[my_slots]           # 0 on dead/padded slots
+                my_slots = g * per_group + jnp.arange(per_group,
+                                                      dtype=jnp.int32)
+                my_batch = batch
+            live = sel.mask[my_slots]           # 0 on dead/padded slots
 
         dt = agg_dtype or jnp.float32
         if n_clusters > 1:
-            # Replicated, deterministic — every shard computes the identical
-            # assignment from the identical all-gathered histogram matrix.
-            assign, cent = kmeans_cluster(hists_all, n_clusters,
-                                          n_iters=kmeans_iters)
-            cl_my = assign[my_slots]                       # (slots,)
-            params_slot = jax.tree_util.tree_map(
-                lambda g: g[cl_my], params)                # each slot's θ_c
-            new_local = jax.vmap(local_step)(params_slot, my_batch)
-            delta = jax.tree_util.tree_map(
-                lambda a, b: (a.astype(jnp.float32)
-                              - b.astype(jnp.float32)).astype(dt),
-                new_local, params_slot)
-            w = live * sizes[my_slots]
-            member = (cl_my[None, :] == jnp.arange(n_clusters)[:, None])
-            w_mc = member.astype(w.dtype) * w[None, :]     # (M, slots)
-            # One weighted delta-psum per cluster (vmapped over the
-            # membership-masked weight rows); a memberless cluster's
-            # numerator is exactly zero, so its model survives unchanged.
-            agg_delta = jax.vmap(
-                lambda wc: psum_weighted_mean(delta, wc, client_axis,
-                                              local_sum=weighted_sum_tree)
-            )(w_mc)
-            new_global = jax.tree_util.tree_map(
-                lambda p, d: (p.astype(jnp.float32)
-                              + server_lr * d).astype(p.dtype),
-                params, agg_delta)
+            with phase("cluster"):
+                # Replicated, deterministic — every shard computes the
+                # identical assignment from the identical all-gathered
+                # histogram matrix.
+                assign, cent = kmeans_cluster(hists_all, n_clusters,
+                                              n_iters=kmeans_iters)
+            with phase("train"):
+                cl_my = assign[my_slots]                   # (slots,)
+                params_slot = jax.tree_util.tree_map(
+                    lambda g: g[cl_my], params)            # each slot's θ_c
+                new_local = jax.vmap(local_step)(params_slot, my_batch)
+            with phase("aggregate"):
+                delta = jax.tree_util.tree_map(
+                    lambda a, b: (a.astype(jnp.float32)
+                                  - b.astype(jnp.float32)).astype(dt),
+                    new_local, params_slot)
+                w = live * sizes[my_slots]
+                member = (cl_my[None, :] == jnp.arange(n_clusters)[:, None])
+                w_mc = member.astype(w.dtype) * w[None, :]  # (M, slots)
+                # One weighted delta-psum per cluster (vmapped over the
+                # membership-masked weight rows); a memberless cluster's
+                # numerator is exactly zero, so its model survives unchanged.
+                agg_delta = jax.vmap(
+                    lambda wc: psum_weighted_mean(delta, wc, client_axis,
+                                                  local_sum=weighted_sum_tree)
+                )(w_mc)
+                new_global = jax.tree_util.tree_map(
+                    lambda p, d: (p.astype(jnp.float32)
+                                  + server_lr * d).astype(p.dtype),
+                    params, agg_delta)
             valid_all = (hists_all.sum(-1) > 0).astype(jnp.float32)
             info = {"mask": sel.mask, "num_selected": sel.mask.sum(),
                     "scores": sel.scores, "cluster_assign": assign,
@@ -319,74 +328,78 @@ def make_sharded_fl_round(mesh: Mesh, client_axis: str,
             return new_global, info
 
         n_slots = live.shape[0]
-        if with_stale:
-            # Byzantine slots train from the τ-rounds-old global tree the
-            # caller carries; honest slots from the current one — the same
-            # per-slot base jnp.where the host round builds.
-            a_bool = adv[my_slots] > 0
-            base = jax.tree_util.tree_map(
-                lambda gp, st: jnp.where(
-                    _slot_bcast(a_bool, gp[None]),
-                    jnp.broadcast_to(st, (n_slots,) + st.shape),
-                    jnp.broadcast_to(gp, (n_slots,) + gp.shape)),
-                params, stale_params)
-            new_local = jax.vmap(local_step)(base, my_batch)
-        else:
-            base = None
-            new_local = jax.vmap(local_step, in_axes=(None, 0))(params,
-                                                                my_batch)
-        if poison_scale is not None:
-            # Byzantine slots report base + s·(θ' − base) — with the fedsgd
-            # local_step (θ − lr·∇) and base = θ this is exactly the host
-            # round's scaled-gradient report, so one statement covers both
-            # families.
-            s = float(poison_scale)
-            a = adv[my_slots].astype(jnp.float32)
-            pb = base if base is not None else jax.tree_util.tree_map(
-                lambda gp: jnp.broadcast_to(gp, (n_slots,) + gp.shape),
-                params)
-            new_local = jax.tree_util.tree_map(
-                lambda u, b: jnp.where(_slot_bcast(a, u) > 0,
-                                       (b + s * (u - b)).astype(u.dtype), u),
-                new_local, pb)
-        # Aggregating DELTAS (not params) tolerates low precision: bf16
-        # halves the cross-pod all-reduce bytes (§Perf, FL-round lever).
-        delta = jax.tree_util.tree_map(
-            lambda a, b: (a.astype(jnp.float32)
-                          - b.astype(jnp.float32)).astype(dt),
-            new_local, params)
+        with phase("train"):
+            if with_stale:
+                # Byzantine slots train from the τ-rounds-old global tree
+                # the caller carries; honest slots from the current one —
+                # the same per-slot base jnp.where the host round builds.
+                a_bool = adv[my_slots] > 0
+                base = jax.tree_util.tree_map(
+                    lambda gp, st: jnp.where(
+                        _slot_bcast(a_bool, gp[None]),
+                        jnp.broadcast_to(st, (n_slots,) + st.shape),
+                        jnp.broadcast_to(gp, (n_slots,) + gp.shape)),
+                    params, stale_params)
+                new_local = jax.vmap(local_step)(base, my_batch)
+            else:
+                base = None
+                new_local = jax.vmap(local_step, in_axes=(None, 0))(
+                    params, my_batch)
+            if poison_scale is not None:
+                # Byzantine slots report base + s·(θ' − base) — with the
+                # fedsgd local_step (θ − lr·∇) and base = θ this is exactly
+                # the host round's scaled-gradient report, so one statement
+                # covers both families.
+                s = float(poison_scale)
+                a = adv[my_slots].astype(jnp.float32)
+                pb = base if base is not None else jax.tree_util.tree_map(
+                    lambda gp: jnp.broadcast_to(gp, (n_slots,) + gp.shape),
+                    params)
+                new_local = jax.tree_util.tree_map(
+                    lambda u, b: jnp.where(
+                        _slot_bcast(a, u) > 0,
+                        (b + s * (u - b)).astype(u.dtype), u),
+                    new_local, pb)
         info = {"mask": sel.mask, "num_selected": sel.mask.sum(),
                 "scores": sel.scores}
-        if reduce_fn is not None:
-            # GATHER-REDUCE: all-gather the B_pad selected deltas (still the
-            # compact delta form — bf16 agg_dtype halves these bytes too),
-            # rebuild the trained stack and run the robust reduction
-            # replicated on every shard; dead/padded slots are masked by the
-            # reduction itself.  live/sizes come from the replicated
-            # selection, so no second collective is needed.
-            order_b = sel.order[:budget_padded]
-            delta_all = gather_client_shards(delta, client_axis)
-            trained = jax.tree_util.tree_map(
-                lambda p, d: p.astype(jnp.float32) + d.astype(jnp.float32),
-                params, delta_all)
-            live_all = sel.mask[order_b]
-            agg_p = reduce_fn(trained, live_all, sizes[order_b])
-            new_global = interpolate(params, agg_p, server_lr)
-            any_live = live_all.sum() > 0
-            new_global = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(any_live, new, old),
-                new_global, params)
-            return new_global, info
-        # The in-shard Σ_s w·Δ slot reduction routes through the compute
-        # dispatch (fused Pallas kernel on TPU, plain XLA elsewhere); the
-        # psum pair then finishes the replicated mean.
-        agg_delta = psum_weighted_mean(delta, live * sizes[my_slots],
-                                       client_axis,
-                                       local_sum=weighted_sum_tree)
-        new_global = jax.tree_util.tree_map(
-            lambda p, d: (p.astype(jnp.float32)
-                          + server_lr * d).astype(p.dtype),
-            params, agg_delta)
+        with phase("aggregate"):
+            # Aggregating DELTAS (not params) tolerates low precision: bf16
+            # halves the cross-pod all-reduce bytes (§Perf, FL-round lever).
+            delta = jax.tree_util.tree_map(
+                lambda a, b: (a.astype(jnp.float32)
+                              - b.astype(jnp.float32)).astype(dt),
+                new_local, params)
+            if reduce_fn is not None:
+                # GATHER-REDUCE: all-gather the B_pad selected deltas (still
+                # the compact delta form — bf16 agg_dtype halves these bytes
+                # too), rebuild the trained stack and run the robust
+                # reduction replicated on every shard; dead/padded slots are
+                # masked by the reduction itself.  live/sizes come from the
+                # replicated selection, so no second collective is needed.
+                order_b = sel.order[:budget_padded]
+                delta_all = gather_client_shards(delta, client_axis)
+                trained = jax.tree_util.tree_map(
+                    lambda p, d: p.astype(jnp.float32)
+                    + d.astype(jnp.float32), params, delta_all)
+                live_all = sel.mask[order_b]
+                agg_p = reduce_fn(trained, live_all, sizes[order_b])
+                new_global = interpolate(params, agg_p, server_lr)
+                any_live = live_all.sum() > 0
+                new_global = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(any_live, new, old),
+                    new_global, params)
+            else:
+                # The in-shard Σ_s w·Δ slot reduction routes through the
+                # compute dispatch (fused Pallas kernel on TPU, plain XLA
+                # elsewhere); the psum pair then finishes the replicated
+                # mean.
+                agg_delta = psum_weighted_mean(delta, live * sizes[my_slots],
+                                               client_axis,
+                                               local_sum=weighted_sum_tree)
+                new_global = jax.tree_util.tree_map(
+                    lambda p, d: (p.astype(jnp.float32)
+                                  + server_lr * d).astype(p.dtype),
+                    params, agg_delta)
         return new_global, info
 
     def add_client_axis(spec):

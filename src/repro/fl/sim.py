@@ -42,6 +42,7 @@ exactly one implementation.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Sequence
 
 import jax
@@ -51,8 +52,8 @@ import numpy as np
 from repro.core import (STRATEGIES, cluster_counts, kmeans_cluster,
                         registered_strategies, selection_budget, strategy_id)
 from repro.data import client_batches
-from repro.obs import (collect_metrics, record_memory_analysis,
-                       resolve_metrics, resolve_telemetry_request)
+from repro.obs import (collect_metrics, phase, record_memory_analysis,
+                       resolve_metrics, resolve_telemetry_request, span)
 from repro.optim import get_optimizer
 from .round import (client_update_step, clustered_update_step,
                     resolve_adversary, resolve_aggregator,
@@ -274,31 +275,35 @@ def make_trial_fn(fl_cfg, ds=None, *,
                 params, ring = carry
             else:
                 params = carry
-            # Same fold_in tree as the host loop — parity is bit-for-bit in
-            # the randomness, so trajectories differ only by op reordering.
-            kt = jax.random.fold_in(key, 1000 + t)
-            plan_t = jax.lax.dynamic_index_in_dim(plan, t % t_static, 0,
-                                                  keepdims=False)
-            avail_t = jax.lax.dynamic_index_in_dim(avail, t % avail.shape[0], 0,
-                                                   keepdims=False)
-            data = wl.materialize(ds, plan_t, jax.random.fold_in(kt, 0))
-            # Availability is applied ONCE, here: a dark client reports an
-            # empty histogram, so every registry strategy's validity gate
-            # excludes it.  (The old second application — re-masking `live`
-            # with avail_t[idx] — was redundant with this and is gone.)
-            hists = data["hists"] * avail_t[:, None]
-            batches = client_batches(data, fl_cfg.batch_size, wl.batch_keys)
-            mask, scores, order, budget = _select(
-                sid, jax.random.fold_in(kt, 1), hists, n_sel, universe)
-            # Enforce the registry validity contract engine-side: a client
-            # with an empty (possibly availability-zeroed) histogram is never
-            # live, even under a strategy whose own gate forgot it — here the
-            # plan may be intact (mask-mode avail), so the dark client's data
-            # is real and training it would silently leak influence.
-            mask = mask * (hists.sum(-1) > 0)
-            idx = order[:budget]          # the strategy's static gather width
-            live = mask[idx]
-            data_sel = jax.tree_util.tree_map(lambda x: x[idx], batches)
+            with phase("materialize"):
+                # Same fold_in tree as the host loop — parity is bit-for-bit
+                # in the randomness, so trajectories differ only by op
+                # reordering.
+                kt = jax.random.fold_in(key, 1000 + t)
+                plan_t = jax.lax.dynamic_index_in_dim(plan, t % t_static, 0,
+                                                      keepdims=False)
+                avail_t = jax.lax.dynamic_index_in_dim(
+                    avail, t % avail.shape[0], 0, keepdims=False)
+                data = wl.materialize(ds, plan_t, jax.random.fold_in(kt, 0))
+                # Availability is applied ONCE, here: a dark client reports
+                # an empty histogram, so every registry strategy's validity
+                # gate excludes it.
+                hists = data["hists"] * avail_t[:, None]
+                batches = client_batches(data, fl_cfg.batch_size,
+                                         wl.batch_keys)
+            with phase("select"):
+                mask, scores, order, budget = _select(
+                    sid, jax.random.fold_in(kt, 1), hists, n_sel, universe)
+                # Enforce the registry validity contract engine-side: a
+                # client with an empty (possibly availability-zeroed)
+                # histogram is never live, even under a strategy whose own
+                # gate forgot it — here the plan may be intact (mask-mode
+                # avail), so the dark client's data is real and training it
+                # would silently leak influence.
+                mask = mask * (hists.sum(-1) > 0)
+                idx = order[:budget]      # the strategy's static gather width
+                live = mask[idx]
+                data_sel = jax.tree_util.tree_map(lambda x: x[idx], batches)
 
             def emit(new_params, main, cent=None, assign=None, norms=None):
                 # Metric collection is additive: the trajectory tuple is
@@ -322,22 +327,24 @@ def make_trial_fn(fl_cfg, ds=None, *,
                 return new_carry, (main, collect_metrics(metrics, state))
 
             if agg.clustered:
-                assign, cent = kmeans_cluster(hists, agg.n_clusters,
-                                              n_iters=agg.kmeans_iters)
+                with phase("cluster"):
+                    assign, cent = kmeans_cluster(hists, agg.n_clusters,
+                                                  n_iters=agg.kmeans_iters)
                 new_params, m = clustered_update_step(
                     params, assign[idx], data_sel, live, loss_fn, opt,
                     fl_cfg, agg)
-                loss_c, ev_m = jax.vmap(
-                    lambda p: eval_fn(p, eval_batch))(new_params)
-                acc_c = ev_m["accuracy"]
-                # The scalar trajectory is the mixture over per-cluster
-                # models, weighted by each cluster's VALID population (every
-                # client the round could have trained, not just the selected
-                # ones) — a single comparable number against the one-model
-                # baseline.
-                valid = (hists.sum(-1) > 0).astype(jnp.float32)
-                w = cluster_counts(assign, agg.n_clusters, weights=valid)
-                tot = jnp.maximum(w.sum(), 1.0)
+                with phase("eval"):
+                    loss_c, ev_m = jax.vmap(
+                        lambda p: eval_fn(p, eval_batch))(new_params)
+                    acc_c = ev_m["accuracy"]
+                    # The scalar trajectory is the mixture over per-cluster
+                    # models, weighted by each cluster's VALID population
+                    # (every client the round could have trained, not just
+                    # the selected ones) — a single comparable number
+                    # against the one-model baseline.
+                    valid = (hists.sum(-1) > 0).astype(jnp.float32)
+                    w = cluster_counts(assign, agg.n_clusters, weights=valid)
+                    tot = jnp.maximum(w.sum(), 1.0)
                 return emit(new_params,
                             ((acc_c * w).sum() / tot,
                              (loss_c * w).sum() / tot,
@@ -366,14 +373,22 @@ def make_trial_fn(fl_cfg, ds=None, *,
                 norms = (jnp.zeros(hists.shape[0], jnp.float32)
                          .at[idx].set(m["update_norm"] * live))
 
-            ev_loss, ev_m = eval_fn(new_params, eval_batch)
+            with phase("eval"):
+                ev_loss, ev_m = eval_fn(new_params, eval_batch)
             return emit(new_params, (ev_m["accuracy"], ev_loss, live.sum(),
                                      mask.sum()), norms=norms)
 
         _, traj = jax.lax.scan(round_body, carry0, jnp.arange(num_rounds))
         return traj
 
-    return trial
+    @functools.wraps(trial)
+    def traced_trial(*args, **kwargs):
+        # The trial runs only under jit/vmap, so this span is its Python
+        # tracing: once per lowering.
+        with span("trace:trial"):
+            return trial(*args, **kwargs)
+
+    return traced_trial
 
 
 def _ones_avail(plan: np.ndarray) -> jnp.ndarray:
@@ -437,18 +452,17 @@ def simulate(plan: np.ndarray, fl_cfg, *, strategy: Optional[str] = None,
     if adv is not None:
         args += (jnp.asarray(adv, jnp.float32),)
     fn = jax.jit(trial)
-    t0 = time.perf_counter()
-    lowered = fn.lower(*args)
-    compiled = lowered.compile()
-    t1 = time.perf_counter()
+    with span("compile", engine="sim", what="trial") as sp:
+        compiled = fn.lower(*args).compile()
     record_memory_analysis("sim:trial", compiled)
+    t1 = time.perf_counter()
     out = jax.block_until_ready(compiled(*args))
     t2 = time.perf_counter()
     out, tel = _split_telemetry(out)
     acc, loss, nsel, msum = out[:4]
     _assert_budget_invariant(nsel, msum)
     return GridResult(np.asarray(acc), np.asarray(loss), np.asarray(nsel),
-                      wall_s=t2 - t1, compile_s=t1 - t0, telemetry=tel,
+                      wall_s=t2 - t1, compile_s=sp.duration_s, telemetry=tel,
                       **_cluster_fields(out))
 
 
@@ -562,17 +576,17 @@ def grid_arrays(plans: np.ndarray, fl_cfg, *, strategies: Sequence[str],
     f = jax.vmap(f, in_axes=strat_axes)                  # strategies
     f = jax.vmap(f, in_axes=case_axes)                   # cases
     fn = jax.jit(f)
-    t0 = time.perf_counter()
-    compiled = fn.lower(*args).compile()
-    t1 = time.perf_counter()
+    with span("compile", engine="sim", what="grid") as sp:
+        compiled = fn.lower(*args).compile()
     record_memory_analysis("sim:grid", compiled)
+    t1 = time.perf_counter()
     out = jax.block_until_ready(compiled(*args))
     t2 = time.perf_counter()
     out, tel = _split_telemetry(out)
     acc, loss, nsel, msum = out[:4]
     _assert_budget_invariant(nsel, msum)
     return GridResult(np.asarray(acc), np.asarray(loss), np.asarray(nsel),
-                      wall_s=t2 - t1, compile_s=t1 - t0, telemetry=tel,
+                      wall_s=t2 - t1, compile_s=sp.duration_s, telemetry=tel,
                       **_cluster_fields(out))
 
 

@@ -51,28 +51,38 @@ def _pool(x: Array) -> Array:
 
 
 def cnn_apply(params: PyTree, images: Array) -> Array:
-    """images: (B, H, W, C) → logits (B, num_classes)."""
-    x = jax.nn.relu(_conv(images, params["conv1"]["w"], params["conv1"]["b"]))
-    x = _pool(x)
-    x = jax.nn.relu(_conv(x, params["conv2"]["w"], params["conv2"]["b"]))
-    x = _pool(x)
-    x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(x @ params["fc1"]["w"] + params["fc1"]["b"])
-    return x @ params["fc2"]["w"] + params["fc2"]["b"]
+    """images: (B, H, W, C) → logits (B, num_classes).
+
+    Each layer is a ``jax.named_scope`` (``cnn.conv1`` … ``cnn.dense``), so
+    its forward and backward ops are named in the compiled program."""
+    with jax.named_scope("cnn.conv1"):
+        x = jax.nn.relu(_conv(images, params["conv1"]["w"],
+                              params["conv1"]["b"]))
+    with jax.named_scope("cnn.pool1"):
+        x = _pool(x)
+    with jax.named_scope("cnn.conv2"):
+        x = jax.nn.relu(_conv(x, params["conv2"]["w"], params["conv2"]["b"]))
+    with jax.named_scope("cnn.pool2"):
+        x = _pool(x)
+    with jax.named_scope("cnn.dense"):
+        x = x.reshape(x.shape[0], -1)
+        x = jax.nn.relu(x @ params["fc1"]["w"] + params["fc1"]["b"])
+        return x @ params["fc2"]["w"] + params["fc2"]["b"]
 
 
 def cnn_loss(params: PyTree, images: Array, labels: Array,
              valid: Array | None = None) -> Tuple[Array, Dict[str, Array]]:
     """Categorical cross-entropy (paper's loss), with padding mask support."""
     logits = cnn_apply(params, images).astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    nll = logz - gold
-    if valid is None:
-        valid = jnp.ones_like(nll)
-    else:
-        valid = valid.astype(jnp.float32)
-    denom = jnp.maximum(valid.sum(), 1.0)
-    loss = (nll * valid).sum() / denom
-    acc = ((jnp.argmax(logits, -1) == labels) * valid).sum() / denom
+    with jax.named_scope("cnn.loss"):
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+        nll = logz - gold
+        if valid is None:
+            valid = jnp.ones_like(nll)
+        else:
+            valid = valid.astype(jnp.float32)
+        denom = jnp.maximum(valid.sum(), 1.0)
+        loss = (nll * valid).sum() / denom
+        acc = ((jnp.argmax(logits, -1) == labels) * valid).sum() / denom
     return loss, {"accuracy": acc, "n": denom}

@@ -6,7 +6,8 @@ Three layers (see the module docstrings for the contracts):
 - :mod:`repro.obs.registry` — ``register_metric`` open registry of traced
   round metrics the engines compile into their round bodies.
 - :mod:`repro.obs.trace` — host-side span API emitting Chrome trace_event
-  JSON, plus ``jax.profiler`` / ``memory_analysis`` hooks gated on
+  JSON and ``repro:`` profiler annotations, the FL round's stage scopes
+  (``phase``), plus ``jax.profiler`` / ``memory_analysis`` hooks gated on
   ``REPRO_TRACE_DIR``.
 - :mod:`repro.obs.envelope` / :mod:`repro.obs.report` — the versioned
   ``meta["telemetry"]`` envelope and the ``python -m repro.obs report``
@@ -34,12 +35,12 @@ from repro.obs.registry import (
 from repro.obs.report import health_flags, render_report, report_file
 from repro.obs.trace import (
     ENV_TRACE_DIR,
+    PHASES,
     events,
-    instant,
     memory_snapshots,
     pallas_kernel_calls,
+    phase,
     profiler,
-    record_duration,
     record_memory_analysis,
     span,
     span_summary,
@@ -67,12 +68,12 @@ __all__ = [
     "render_report",
     "report_file",
     "ENV_TRACE_DIR",
+    "PHASES",
     "events",
-    "instant",
     "memory_snapshots",
     "pallas_kernel_calls",
+    "phase",
     "profiler",
-    "record_duration",
     "record_memory_analysis",
     "span",
     "span_summary",

@@ -1,11 +1,22 @@
-"""Host-side trace spans + profiler hooks.
+"""Host-side trace spans, the FL round's stage scopes, and profiler hooks.
 
 A *span* times one host-side pipeline stage (``lower_scenarios``, compile,
 engine execute, eval).  Events accumulate in a process-global buffer in
 Chrome ``trace_event`` format (complete ``"ph": "X"`` events, microsecond
 timestamps) so :func:`write_trace` output loads directly into Perfetto /
-``chrome://tracing``.  ``compile_s`` / ``wall_s`` engine timings fold into
-the same stream as spans, so one file tells the whole wall-clock story.
+``chrome://tracing``.  Each span also enters a
+``jax.profiler.TraceAnnotation`` named ``repro:<name>``, and its timestamp
+is read from the clock the profiler stamps its events with (``time.time_ns``,
+the realtime clock), so under any profiler session the program's spans sit
+in the same ``.xplane.pb`` as the device ops, on one time axis, and a span
+starts at the same instant in the Chrome file and in the profile.
+
+:func:`phase` names one stage of the FL round (:data:`PHASES`) where its
+math is written: ``jax.named_scope("fl.<stage>")`` puts the stage into the
+``op_name`` metadata of every HLO instruction the block lowers to (the
+backward pass included), and a ``trace:fl.<stage>`` span times the Python
+tracing of the block.  Both act only while JAX traces, never per round; the
+compiled program is the same with or without them, up to metadata.
 
 ``REPRO_TRACE_DIR=<dir>`` switches on the heavyweight hooks: engine
 execution additionally runs under ``jax.profiler.trace`` (XLA-level
@@ -24,14 +35,16 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
+
 ENV_TRACE_DIR = "REPRO_TRACE_DIR"
 
 _LOCK = threading.Lock()
 _EVENTS: List[Dict[str, Any]] = []
 _MEMORY: List[Dict[str, Any]] = []
-# trace_event timestamps are µs relative to an arbitrary epoch; pin one per
-# process so spans from different modules line up on the same axis.
-_T0 = time.perf_counter()
+
+# The FL round's stages, in round order; ``phase(name)`` takes one of these.
+PHASES = ("materialize", "select", "train", "aggregate", "eval", "cluster")
 
 
 def trace_dir() -> Optional[str]:
@@ -40,24 +53,23 @@ def trace_dir() -> Optional[str]:
     return d or None
 
 
-def _now_us() -> float:
-    return (time.perf_counter() - _T0) * 1e6
-
-
 class Span:
-    """Handle yielded by :func:`span`; ``duration_s`` is valid after exit."""
+    """Handle yielded by :func:`span`; ``duration_s`` is valid after exit.
+
+    ``start_us`` is on the profiler's clock (µs since the Unix epoch); the
+    duration comes from the monotonic ``perf_counter``."""
 
     def __init__(self, name: str, args: Dict[str, Any]):
         self.name = name
         self.args = args
-        self.start_us = _now_us()
+        self.start_us = time.time_ns() / 1e3
+        self._t0 = time.perf_counter()
         self.duration_s = 0.0
 
     def close(self) -> None:
-        end = _now_us()
-        self.duration_s = (end - self.start_us) / 1e6
+        self.duration_s = time.perf_counter() - self._t0
         ev = {"name": self.name, "ph": "X", "ts": self.start_us,
-              "dur": end - self.start_us, "pid": os.getpid(),
+              "dur": self.duration_s * 1e6, "pid": os.getpid(),
               "tid": threading.get_ident()}
         if self.args:
             ev["args"] = dict(self.args)
@@ -68,34 +80,26 @@ class Span:
 @contextlib.contextmanager
 def span(name: str, **args: Any):
     """Time a host-side stage: ``with span("compile", engine="sim") as s: …``;
-    records one complete trace event on exit (also on exception)."""
-    s = Span(name, args)
-    try:
-        yield s
-    finally:
-        s.close()
+    records one complete trace event on exit (also on exception), and is a
+    ``repro:<name>`` host event in any running ``jax.profiler`` trace."""
+    with jax.profiler.TraceAnnotation("repro:" + name):
+        s = Span(name, args)
+        try:
+            yield s
+        finally:
+            s.close()
 
 
-def instant(name: str, **args: Any) -> None:
-    """Record a zero-duration marker event."""
-    ev = {"name": name, "ph": "i", "ts": _now_us(), "s": "p",
-          "pid": os.getpid(), "tid": threading.get_ident()}
-    if args:
-        ev["args"] = dict(args)
-    with _LOCK:
-        _EVENTS.append(ev)
-
-
-def record_duration(name: str, seconds: float, **args: Any) -> None:
-    """Fold an externally-measured duration (an engine's ``compile_s`` /
-    ``wall_s``) into the event stream as a complete event ending now."""
-    dur_us = max(float(seconds), 0.0) * 1e6
-    ev = {"name": name, "ph": "X", "ts": _now_us() - dur_us, "dur": dur_us,
-          "pid": os.getpid(), "tid": threading.get_ident()}
-    if args:
-        ev["args"] = dict(args)
-    with _LOCK:
-        _EVENTS.append(ev)
+@contextlib.contextmanager
+def phase(name: str):
+    """Name one stage of the FL round where its math is written:
+    ``with phase("train"): …`` scopes the block's ops as ``fl.train`` in the
+    HLO ``op_name`` metadata and times its Python tracing as the span
+    ``trace:fl.train``.  ``name`` is one of :data:`PHASES`."""
+    if name not in PHASES:
+        raise ValueError(f"unknown FL round phase {name!r}; have {PHASES}")
+    with jax.named_scope(f"fl.{name}"), span(f"trace:fl.{name}"):
+        yield
 
 
 def events() -> List[Dict[str, Any]]:
@@ -149,22 +153,23 @@ def profiler(label: str):
     backend, a trace already running) raises: the caller asked for a trace,
     and a run without it would look traced when it was not."""
     d = trace_dir()
-    with span(f"engine_execute:{label}"):
-        if d is None:
+    if d is None:
+        with span(f"engine_execute:{label}"):
             yield
-            return
-        import jax
-        prof_dir = os.path.join(d, "jax")
-        os.makedirs(prof_dir, exist_ok=True)
-        try:
-            jax.profiler.start_trace(prof_dir)
-        except Exception as e:
-            raise RuntimeError(f"{ENV_TRACE_DIR}={d}: jax.profiler could not "
-                               f"start a trace in {prof_dir}") from e
-        try:
+        return
+    prof_dir = os.path.join(d, "jax")
+    os.makedirs(prof_dir, exist_ok=True)
+    try:
+        jax.profiler.start_trace(prof_dir)
+    except Exception as e:
+        raise RuntimeError(f"{ENV_TRACE_DIR}={d}: jax.profiler could not "
+                           f"start a trace in {prof_dir}") from e
+    # The span opens inside the profiler session, so the profile holds it.
+    try:
+        with span(f"engine_execute:{label}"):
             yield
-        finally:
-            jax.profiler.stop_trace()
+    finally:
+        jax.profiler.stop_trace()
 
 
 _KERNEL_CALL = re.compile(

@@ -12,6 +12,7 @@ The acceptance pins:
 """
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -21,9 +22,10 @@ import pytest
 from repro.configs.paper_cnn import FLConfig
 from repro.core import case_label_plan
 from repro.fl import ExperimentSpec, ScenarioSpec, run
-from repro.obs import (BASE_AXES, TELEMETRY_SCHEMA_VERSION, build_envelope,
-                       get_metric, health_flags, metric_id,
-                       register_metric, registered_metrics, render_report,
+from repro.obs import (BASE_AXES, PHASES, TELEMETRY_SCHEMA_VERSION,
+                       build_envelope, get_metric, health_flags, metric_id,
+                       phase, register_metric, registered_metrics,
+                       render_report,
                        resolve_metrics, resolve_telemetry_request,
                        series_arrays, span, span_summary)
 from repro.obs.registry import _METRIC_IDS, _METRICS
@@ -290,6 +292,124 @@ class TestTrace:
             with profiler("sim"):
                 pass
         assert "profiler unavailable" in str(info.value.__cause__)
+
+
+    def test_span_is_a_profiler_event_on_the_same_clock(self, tmp_path):
+        import glob
+        import warnings
+
+        import jax
+        from jax.profiler import ProfileData
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with span("clock_probe"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                                recursive=True))[-1]
+        start_ns, found = None, []
+        for plane in ProfileData.from_file(path).planes:
+            with warnings.catch_warnings():
+                warnings.simplefilter("ignore", DeprecationWarning)
+                stats = dict(plane.stats)
+            start_ns = stats.get("profile_start_time", start_ns)
+            found += [ev.start_ns for line in plane.lines
+                      for ev in line.events if ev.name == "repro:clock_probe"]
+        assert len(found) == 1 and start_ns is not None
+        chrome = [e for e in trace_events() if e["name"] == "clock_probe"][-1]
+        # The profile's event times are offsets from the session's start.
+        assert abs(chrome["ts"] - (start_ns + found[0]) / 1e3) < 1000.0
+
+
+# ---------------------------------------------------------------------------
+# FL round stages as named scopes
+# ---------------------------------------------------------------------------
+
+def _micro_trial_hlo(aggregation=None):
+    """(optimized HLO text, jaxpr text) of one micro sim trial."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.fl.sim import make_trial_fn
+    plan = case_label_plan("iid", seed=3, num_rounds=2, num_clients=6,
+                           samples_per_client=8, majority=5)
+    trial = make_trial_fn(MICRO, aggregation=aggregation, rounds=2,
+                          eval_n_per_class=2, strategies=("labelwise",))
+    args = (jnp.asarray(plan, jnp.int32), jnp.int32(0), jnp.int32(0),
+            jnp.ones(plan.shape[:2], jnp.float32))
+    text = jax.jit(trial).lower(*args).compile().as_text()
+    return text, str(jax.make_jaxpr(trial)(*args))
+
+
+_METADATA = re.compile(r',? ?metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}')
+
+
+def _without_metadata(hlo_text):
+    """The HLO's computations with every ``metadata={…}`` removed (the
+    stack-frame tables before the first computation go too)."""
+    first = min(i for i in (hlo_text.find("\n%"), hlo_text.find("\nENTRY"))
+                if i >= 0)
+    return _METADATA.sub("", hlo_text[first:])
+
+
+class TestPhases:
+    def test_every_stage_is_named_in_the_compiled_program(self):
+        text, _ = _micro_trial_hlo()
+        names = set(re.findall(r'op_name="([^"]*)"', text))
+        for stage in PHASES:
+            if stage == "cluster":
+                continue
+            assert any(f"fl.{stage}" in n for n in names), stage
+        # Local training's backward pass keeps its stage and model scope.
+        assert any("fl.train" in n and "transpose(jvp(cnn.conv1))" in n
+                   for n in names)
+        assert not any("fl.cluster" in n for n in names)
+        text, _ = _micro_trial_hlo("clustered_fedavg")
+        assert "fl.cluster" in text
+
+    def test_scopes_change_metadata_only(self, monkeypatch):
+        import contextlib
+
+        from repro.fl import round as fl_round
+        from repro.fl import sim
+        text, jaxpr = _micro_trial_hlo()
+        for mod in (sim, fl_round):
+            monkeypatch.setattr(mod, "phase",
+                                lambda name: contextlib.nullcontext())
+        bare_text, bare_jaxpr = _micro_trial_hlo()
+        assert "fl.train" not in bare_text
+        assert bare_jaxpr == jaxpr
+        assert _without_metadata(bare_text) == _without_metadata(text)
+
+    def test_trial_tracing_is_one_span_per_lowering(self):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.fl.sim import make_trial_fn
+
+        def count(name):
+            return sum(e["name"] == name for e in trace_events())
+
+        plan = case_label_plan("iid", seed=3, num_rounds=2, num_clients=6,
+                               samples_per_client=8, majority=5)
+        args = (jnp.asarray(plan, jnp.int32), jnp.int32(0), jnp.int32(0),
+                jnp.ones(plan.shape[:2], jnp.float32))
+        trial = make_trial_fn(MICRO, rounds=2, eval_n_per_class=2,
+                              strategies=("labelwise",))
+        before = {n: count(n) for n in ("trace:trial", "trace:fl.train")}
+        compiled = jax.jit(trial).lower(*args).compile()
+        assert count("trace:trial") == before["trace:trial"] + 1
+        assert count("trace:fl.train") == before["trace:fl.train"] + 1
+        for _ in range(2):                   # running it traces nothing
+            jax.block_until_ready(compiled(*args))
+        assert count("trace:trial") == before["trace:trial"] + 1
+
+    def test_unknown_phase_raises(self):
+        with pytest.raises(ValueError, match="unknown FL round phase"):
+            with phase("nope"):
+                pass
 
 
 # ---------------------------------------------------------------------------
