@@ -14,6 +14,9 @@ for 3 rounds — one compiled program.  Checks:
 * 30 clients train every round under both strategies;
 * the compiled grid holds both Pallas kernels (``label_hist_kernel``,
   ``weighted_agg_kernel``) as TPU custom calls, not interpreted;
+* the compiled grid holds no ``lax`` convolution under ``cnn.conv1``
+  (``repro.obs.convolutions_by_scope``): the one-channel first layer is a
+  sum over its window taps;
 * on round 0's real inputs (the materialized batch and the 30 locally
   trained client models), each kernel agrees with its XLA reference on the
   chip: histograms bit-identical, the FedAvg mean within the f32 ulp
@@ -186,12 +189,16 @@ def one_chip(check: Checks, fl) -> None:
     print(f"sim grid compiled.memory_analysis(): {snap}")
     print(f"peak_bytes_in_use after the grid: {peak_bytes(jax.devices()[0])}")
     kernels = snap.get("pallas_kernels", {})
+    convs = snap.get("convolutions", {})
     print(f"tpu_custom_call in the compiled grid: {sum(kernels.values())} "
-          f"{kernels}")
+          f"{kernels}; lax convolutions by CNN scope: {convs}")
     print_trajectories(res, "sim")
     check_trajectories(check, res, fl, "sim")
     check(all(kernels.get(k, 0) > 0 for k in KERNELS),
           f"the compiled grid calls both {KERNELS} as tpu_custom_call")
+    check("convolutions" in snap and convs.get("cnn.conv1", 0) == 0,
+          "the compiled grid holds no convolution under cnn.conv1 (its "
+          "one-channel input is summed over the window taps)")
     check_kernels_against_references(check, fl)
 
 

@@ -1,5 +1,5 @@
 """The paper's local-client model (§III-B): Conv2D–Pool–Conv2D–Pool–Flatten–
-Dense–Dense, pure JAX (lax.conv), sized for 28×28×1 synthetic images.
+Dense–Dense, pure JAX, sized for 28×28×1 synthetic images.
 
 This is the model every FL client trains in the reproduction experiments; it
 is deliberately tiny ("low computation ability of local clients", §VI).
@@ -7,7 +7,7 @@ is deliberately tiny ("low computation ability of local clients", §VI).
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,10 +38,35 @@ def cnn_init(key: Array, num_classes: int = 10, image_size: int = 28,
     }
 
 
+def _taps(x: Array, kh: int, kw: int) -> List[Array]:
+    """The kh·kw shifted (..., H, W) views of a one-channel (..., H, W, 1)
+    image, SAME-padded for a stride-1 window, in row-major (HWIO) order."""
+    h, w = x.shape[-3:-1]
+    pad = [(0, 0)] * (x.ndim - 3) + [((kh - 1) // 2, kh // 2),
+                                     ((kw - 1) // 2, kw // 2)]
+    xp = jnp.pad(x[..., 0], pad)
+    return [xp[..., i:i + h, j:j + w] for i in range(kh) for j in range(kw)]
+
+
 def _conv(x: Array, w: Array, b: Array) -> Array:
-    y = jax.lax.conv_general_dilated(
-        x, w, window_strides=(1, 1), padding="SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    """SAME, stride-1 convolution, NHWC × HWIO.
+
+    A one-channel input is a sum over its window taps, each tap's shifted
+    image times that tap's row of weights.  ``lax.conv`` would do the same
+    arithmetic, but vmapped over clients with per-client weights it becomes
+    a grouped convolution of one input channel per group, which the TPU
+    lays out and relays out at several times the cost of the work."""
+    kh, kw, cin, cout = w.shape
+    if cin == 1:
+        wk = w.reshape(kh * kw, cout)
+        taps = _taps(x, kh, kw)
+        y = taps[0][..., None] * wk[0]
+        for k in range(1, kh * kw):
+            y = y + taps[k][..., None] * wk[k]
+    else:
+        y = jax.lax.conv_general_dilated(
+            x, w, window_strides=(1, 1), padding="SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
     return y + b
 
 

@@ -36,6 +36,7 @@ from repro.obs.report import health_flags, render_report, report_file
 from repro.obs.trace import (
     ENV_TRACE_DIR,
     PHASES,
+    convolutions_by_scope,
     events,
     memory_snapshots,
     pallas_kernel_calls,
@@ -69,6 +70,7 @@ __all__ = [
     "report_file",
     "ENV_TRACE_DIR",
     "PHASES",
+    "convolutions_by_scope",
     "events",
     "memory_snapshots",
     "pallas_kernel_calls",

@@ -188,10 +188,70 @@ def pallas_kernel_calls(hlo_text: str) -> Dict[str, int]:
     return out
 
 
+_HLO_LINE = re.compile(r'^\s*(?:ROOT )?%([\w.\-]+) = ')
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r'calls=%([\w.\-]+)')
+_OPERAND = re.compile(r'%([\w.\-]+)')
+_CNN_SCOPE = re.compile(r'cnn\.\w+')
+
+
+def convolutions_by_scope(hlo_text: str) -> Dict[str, int]:
+    """``convolution`` instructions of an optimized HLO module that lower
+    ``lax.conv_general_dilated``, counted by the ``cnn.*`` scope their
+    ``op_name`` carries (``"unscoped"`` where none is found).
+
+    An instruction without metadata takes the ``op_name`` of the fusion
+    that calls its computation; where that has none either, the scope most
+    of its nearest producers with metadata carry.  The TPU compiler writes
+    matrix products as ``convolution`` too; those keep the ``dot_general``
+    their ``op_name`` ends in and are not counted."""
+    op_name: Dict[str, str] = {}
+    operands: Dict[str, List[str]] = {}
+    caller: Dict[str, str] = {}         # called computation → calling instr
+    home: Dict[str, str] = {}           # instruction → its computation
+    convs: List[str] = []
+    comp = ""
+    for line in hlo_text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0][1:]
+            continue
+        m = _HLO_LINE.match(line)
+        if not m:
+            continue
+        name, rest = m.group(1), line[m.end():]
+        home[name] = comp
+        on = _OP_NAME.search(rest)
+        if on:
+            op_name[name] = on.group(1)
+        operands[name] = _OPERAND.findall(rest.split("), ", 1)[0])
+        for callee in _CALLS.findall(rest):
+            caller.setdefault(callee, name)
+        if re.match(r'\S+ convolution\(', rest):
+            convs.append(name)
+
+    out: Dict[str, int] = {}
+    for name in convs:
+        while name not in op_name and home.get(name) in caller:
+            name = caller[home[name]]
+        if op_name.get(name, "").endswith("dot_general"):
+            continue
+        frontier, named, seen = {name}, [], {name}
+        while frontier and not named:
+            named = [op_name[n] for n in sorted(frontier) if n in op_name]
+            frontier = {o for n in frontier
+                        for o in operands.get(n, ())} - seen
+            seen |= frontier
+        scopes = [s for n in named for s in _CNN_SCOPE.findall(n)[-1:]]
+        scope = max(scopes, key=scopes.count) if scopes else "unscoped"
+        out[scope] = out.get(scope, 0) + 1
+    return out
+
+
 def record_memory_analysis(label: str, compiled: Any) -> None:
     """Best-effort ``compiled.memory_analysis()`` snapshot for one AOT
     compile, plus ``pallas_kernels`` — :func:`pallas_kernel_calls` of the
-    compiled program — when it holds any.  Backends without the API (or
+    compiled program — when it holds any, and ``convolutions`` —
+    :func:`convolutions_by_scope` of it.  Backends without the API (or
     donation-opaque executables) are skipped silently."""
     try:
         ma = compiled.memory_analysis()
@@ -204,9 +264,11 @@ def record_memory_analysis(label: str, compiled: Any) -> None:
             v = getattr(ma, field, None)
             if v is not None:
                 snap[field] = int(v)
-        kernels = pallas_kernel_calls(compiled.as_text())
+        text = compiled.as_text()
+        kernels = pallas_kernel_calls(text)
         if kernels:
             snap["pallas_kernels"] = kernels
+        snap["convolutions"] = convolutions_by_scope(text)
         if len(snap) > 1:
             with _LOCK:
                 _MEMORY.append(snap)

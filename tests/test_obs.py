@@ -23,9 +23,10 @@ from repro.configs.paper_cnn import FLConfig
 from repro.core import case_label_plan
 from repro.fl import ExperimentSpec, ScenarioSpec, run
 from repro.obs import (BASE_AXES, PHASES, TELEMETRY_SCHEMA_VERSION,
-                       build_envelope, get_metric, health_flags, metric_id,
-                       phase, register_metric, registered_metrics,
-                       render_report,
+                       build_envelope, convolutions_by_scope, get_metric,
+                       health_flags, memory_snapshots, metric_id, phase,
+                       record_memory_analysis, register_metric,
+                       registered_metrics, render_report,
                        resolve_metrics, resolve_telemetry_request,
                        series_arrays, span, span_summary)
 from repro.obs.registry import _METRIC_IDS, _METRICS
@@ -410,6 +411,70 @@ class TestPhases:
         with pytest.raises(ValueError, match="unknown FL round phase"):
             with phase("nope"):
                 pass
+
+
+# Hand-written optimized HLO in the TPU compiler's style: a convolution
+# with its own op_name; a matrix product written as a convolution; one with
+# no metadata in a fusion that carries the scope; one whose fusion carries
+# none either, named only by its producer.
+_CONV_HLO = """\
+%fused_computation.1 (param_0: f32[4,8,8,1], param_1: f32[3,3,1,8]) -> f32[4,8,8,8] {
+  %param_0 = f32[4,8,8,1]{3,2,1,0} parameter(0)
+  %param_1 = f32[3,3,1,8]{3,2,1,0} parameter(1)
+  ROOT %convolution.1 = f32[4,8,8,8]{3,2,1,0} convolution(%param_0, %param_1), window={size=3x3 pad=1_1x1_1}, dim_labels=b01f_01io->b01f
+}
+
+%fused_computation.2 (param_0.1: f32[4,8,8,1], param_1.1: f32[3,3,1,8]) -> f32[4,8,8,8] {
+  %param_0.1 = f32[4,8,8,1]{3,2,1,0} parameter(0)
+  %param_1.1 = f32[3,3,1,8]{3,2,1,0} parameter(1)
+  ROOT %convolution.2 = f32[4,8,8,8]{3,2,1,0} convolution(%param_0.1, %param_1.1), window={size=3x3 pad=1_1x1_1}, dim_labels=b01f_01io->b01f, feature_group_count=1
+}
+
+ENTRY %main (p0: f32[4,8,8,1], p1: f32[3,3,1,8], p2: f32[4,8,8,8], p3: f32[3,3,8,8], p4: f32[4,64], p5: f32[64,8]) -> f32[4,8] {
+  %p0 = f32[4,8,8,1]{3,2,1,0} parameter(0)
+  %p1 = f32[3,3,1,8]{3,2,1,0} parameter(1)
+  %p2 = f32[4,8,8,8]{3,2,1,0} parameter(2)
+  %p3 = f32[3,3,8,8]{3,2,1,0} parameter(3)
+  %p4 = f32[4,64]{1,0} parameter(4)
+  %p5 = f32[64,8]{1,0} parameter(5)
+  %convolution.3 = f32[4,8,8,8]{3,2,1,0} convolution(%p2, %p3), window={size=3x3 pad=1_1x1_1}, dim_labels=b01f_01io->b01f, metadata={op_name="jit(f)/fl.train/jvp(cnn.conv2)/conv_general_dilated"}
+  %convolution.4 = f32[4,8]{1,0} convolution(%p4, %p5), dim_labels=bf_io->bf, metadata={op_name="jit(f)/fl.train/jvp(cnn.dense)/dot_general"}
+  %fusion.1 = f32[4,8,8,8]{3,2,1,0} fusion(%p0, %p1), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(f)/fl.train/transpose(jvp(cnn.conv1))/conv_general_dilated"}
+  %copy.1 = f32[3,3,1,8]{3,2,1,0} copy(%p1), metadata={op_name="jit(f)/fl.train/jvp(cnn.conv1)/reshape"}
+  %copy.2 = f32[3,3,1,8]{2,3,1,0} copy(%copy.1)
+  %fusion.2 = f32[4,8,8,8]{3,2,1,0} fusion(%p0, %copy.2), kind=kOutput, calls=%fused_computation.2
+  %convolution.5 = f32[4,8,8,8]{3,2,1,0} convolution(%p2, %p3), window={size=3x3 pad=1_1x1_1}, dim_labels=b01f_01io->b01f
+  ROOT %reduce.1 = f32[4,8]{1,0} add(%convolution.4, %convolution.4)
+}
+"""
+
+
+class TestConvolutionCount:
+    def test_rules_on_hand_written_hlo(self):
+        assert convolutions_by_scope(_CONV_HLO) == {
+            "cnn.conv1": 2, "cnn.conv2": 1, "unscoped": 1}
+
+    def test_micro_trial_has_no_conv1_convolution(self):
+        """conv1 is a sum over its taps; conv2 stays a convolution; the
+        compile snapshot carries the same count."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.fl.sim import make_trial_fn
+        plan = case_label_plan("iid", seed=3, num_rounds=1, num_clients=6,
+                               samples_per_client=8, majority=5)
+        trial = make_trial_fn(MICRO, rounds=1, eval_n_per_class=1,
+                              strategies=("random",))
+        args = (jnp.asarray(plan, jnp.int32), jnp.int32(0), jnp.int32(0),
+                jnp.ones(plan.shape[:2], jnp.float32))
+        compiled = jax.jit(trial).lower(*args).compile()
+        counts = convolutions_by_scope(compiled.as_text())
+        assert counts.get("cnn.conv1", 0) == 0
+        assert counts.get("cnn.conv2", 0) >= 1
+        record_memory_analysis("test:convolutions", compiled)
+        snap = [m for m in memory_snapshots()
+                if m["label"] == "test:convolutions"][-1]
+        assert snap["convolutions"] == counts
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,8 @@ from repro.configs.paper_cnn import FLConfig
 from repro.kernels import dispatch
 from repro.kernels.label_hist.label_hist import label_hist_kernel
 from repro.kernels.weighted_agg.weighted_agg import weighted_agg_kernel
-from repro.obs import memory_snapshots, pallas_kernel_calls, record_memory_analysis
+from repro.obs import (convolutions_by_scope, memory_snapshots,
+                       pallas_kernel_calls, record_memory_analysis)
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -112,3 +113,8 @@ def test_sim_trial_compiles_at_paper_width(one_chip, monkeypatch):
     snap = memory_snapshots()[-1]
     assert snap["label"] == "test:tpu_trial"
     assert snap["pallas_kernels"] == kernels
+    # The TPU compiler writes matrix products as convolution instructions
+    # too; none lowers a lax.conv under cnn.conv1, conv2 keeps its own.
+    convs = snap["convolutions"]
+    assert convs == convolutions_by_scope(compiled.as_text())
+    assert convs.get("cnn.conv1", 0) == 0 and convs.get("cnn.conv2", 0) > 0
