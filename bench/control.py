@@ -7,10 +7,11 @@
 In one process: the cell's own program, built as ``bench/run.py`` builds it,
 runs calls until ``--seeds`` trial seeds have run every strategy; then the
 plain reference recomputes every trial (the lower readings: the largest
-gaps sound runs give), and the reference in bfloat16 — the control, the
-precision step a later change would be tempted by — takes the program's
-place on the first ``--control-seeds`` seeds (the upper readings: the
-smallest the control gives).  Each fault named in ``--faults``
+gaps sound runs give), and the reference in the precision its module
+names as ``CONTROL_PRECISION`` — the control, the step below the
+configuration's precision that a later change would be tempted by — takes
+the program's place on the first ``--control-seeds`` seeds (the upper
+readings: the smallest the control gives).  Each fault named in ``--faults``
 (``bench/faults.py``) is planted in turn, the program built and run again
 on the same plans and seeds, and read against the same references.  As a
 run judges its ``check_trials`` checked trials together, the trials are
@@ -66,7 +67,7 @@ def main(argv=None) -> int:
     outs = {}
     for v in variants:
         with (faults.planted(v) if v != "program" else contextlib.nullcontext()):
-            engine = cells.module("engines", tr["engine"]).Engine(cfg, tr, plans)
+            engine = cells.engine(cfg, tr)(cfg, tr, plans)
             engine.setup(0)
             outs[v] = [engine.call(i, seeds[i]) for i in range(n_calls)]
             engine.free()
@@ -93,7 +94,8 @@ def main(argv=None) -> int:
                     line[v + "_loss"] = prog["loss"].tolist()
                 if n_seed < args.control_seeds:
                     ctl = ref.run_trial(cfg, tr, plan_i[r], strat, int(seeds[i][r]),
-                                        precision="bfloat16", ties=False)[0]
+                                        precision=ref.CONTROL_PRECISION,
+                                        ties=False)[0]
                     trials["control"].append(correct.trial_gaps(ctl, want))
                     line["control"] = trials["control"][-1]
                     line["control_loss"] = ctl["loss"].tolist()

@@ -9,47 +9,24 @@ to how ``grid_arrays`` batches trials is therefore not seen here.
 from __future__ import annotations
 
 import time
+import types
 from typing import Any, Callable, Dict
 
 import numpy as np
 
 
 def fl_config(cfg: Dict[str, Any], traffic: Dict[str, Any]):
-    from repro.configs.paper_cnn import FLConfig
-    return FLConfig(num_clients=cfg["num_clients"],
-                    clients_per_round=cfg["clients_per_round"],
-                    global_epochs=traffic["rounds_per_call"],
-                    local_epochs=cfg["local_epochs"],
-                    batch_size=cfg["batch_size"], lr=cfg["lr"],
-                    optimizer=cfg["optimizer"],
-                    aggregation=traffic["aggregation"],
-                    server_lr=cfg["server_lr"])
+    """The FL settings the trial reads, from the configuration and the mix."""
+    return types.SimpleNamespace(
+        num_clients=cfg["num_clients"],
+        clients_per_round=cfg["clients_per_round"],
+        global_epochs=traffic["rounds_per_call"],
+        local_epochs=cfg["local_epochs"], batch_size=cfg["batch_size"],
+        lr=cfg["lr"], optimizer=cfg["optimizer"],
+        aggregation=traffic["aggregation"], server_lr=cfg["server_lr"])
 
 
-def dataset(cfg: Dict[str, Any]):
-    from repro.data import ImageDataset
-    return ImageDataset(num_classes=cfg["num_classes"],
-                        image_size=cfg["image_size"],
-                        channels=cfg["channels"], noise=cfg["noise"],
-                        seed=cfg["template_seed"])
-
-
-def check_model_shapes(cfg: Dict[str, Any], ds) -> None:
-    """The program builds the CNN the configuration states, or we stop."""
-    from repro.fl.workloads import get_workload
-    shapes = get_workload(cfg["workload"]).param_shapes(ds)
-    flat = (cfg["image_size"] // 4) ** 2 * cfg["conv2"]
-    want = {"conv1": (3, 3, cfg["channels"], cfg["conv1"]),
-            "conv2": (3, 3, cfg["conv1"], cfg["conv2"]),
-            "fc1": (flat, cfg["hidden"]),
-            "fc2": (cfg["hidden"], cfg["num_classes"])}
-    got = {k: tuple(v["w"].shape) for k, v in shapes.items()}
-    if got != want:
-        raise RuntimeError(f"the program's CNN is {got}, the configuration "
-                           f"states {want}")
-
-
-def grid_fn(cfg: Dict[str, Any], traffic: Dict[str, Any], ds):
+def grid_fn(cfg: Dict[str, Any], traffic: Dict[str, Any], ds, workload):
     """The trial vmapped as ``grid_arrays`` nests it: (plans, strategy ids,
     seeds, availability) → (case, strategy, seed, round) trajectories."""
     import jax
@@ -59,7 +36,7 @@ def grid_fn(cfg: Dict[str, Any], traffic: Dict[str, Any], ds):
         fl_config(cfg, traffic), ds, aggregation=traffic["aggregation"],
         rounds=traffic["rounds_per_call"],
         eval_n_per_class=cfg["eval_n_per_class"],
-        strategies=tuple(traffic["strategies"]), workload=cfg["workload"])
+        strategies=tuple(traffic["strategies"]), workload=workload)
     # The grid_arrays vmap nest: seeds (per-seed plans), strategies, cases.
     f = jax.vmap(trial, in_axes=(0, None, 0, None))
     f = jax.vmap(f, in_axes=(None, 0, None, None))
@@ -70,7 +47,12 @@ class Engine:
     """Set-up builds the program once; ``call`` runs one grid of trials.
 
     ``plans(i)`` gives call ``i``'s (R, T, N, n) host plans; each is drawn
-    when its call is first made and kept for the check."""
+    when its call is first made and kept for the check.
+
+    The client model is set up by three methods, and only they know it:
+    :meth:`workload`, :meth:`dataset` and :meth:`check_model`.  These build
+    the paper CNN; an engine for another model subclasses this class in a
+    file of its own and overrides them."""
 
     def __init__(self, cfg: Dict[str, Any], traffic: Dict[str, Any],
                  plans: Callable[[int], np.ndarray]):
@@ -88,9 +70,9 @@ class Engine:
         import jax.numpy as jnp
 
         cfg, traffic = self.cfg, self.traffic
-        ds = dataset(cfg)
-        check_model_shapes(cfg, ds)
-        f = grid_fn(cfg, traffic, ds)
+        ds = self.dataset()
+        self.check_model(ds)
+        f = grid_fn(cfg, traffic, ds, self.workload())
         t, n = self.plan(warm, 0).shape[:2]
         self.avail = jnp.ones((1, t, n), jnp.float32)
         self.sids = jnp.arange(len(self.strategies), dtype=jnp.int32)
@@ -108,6 +90,35 @@ class Engine:
                 "temp_size_in_bytes", "argument_size_in_bytes",
                 "output_size_in_bytes", "generated_code_size_in_bytes")
                 if hasattr(ma, k)}
+
+    def workload(self):
+        """The client workload the program trains: a registered name, or a
+        ``repro.fl.workloads.Workload``."""
+        return self.cfg["workload"]
+
+    def dataset(self):
+        """The dataset the workload draws its clients' samples from."""
+        from repro.data import ImageDataset
+        cfg = self.cfg
+        return ImageDataset(num_classes=cfg["num_classes"],
+                            image_size=cfg["image_size"],
+                            channels=cfg["channels"], noise=cfg["noise"],
+                            seed=cfg["template_seed"])
+
+    def check_model(self, ds) -> None:
+        """The program builds the model the configuration states, or we stop."""
+        from repro.fl.workloads import get_workload
+        cfg = self.cfg
+        shapes = get_workload(self.workload()).param_shapes(ds)
+        flat = (cfg["image_size"] // 4) ** 2 * cfg["conv2"]
+        want = {"conv1": (3, 3, cfg["channels"], cfg["conv1"]),
+                "conv2": (3, 3, cfg["conv1"], cfg["conv2"]),
+                "fc1": (flat, cfg["hidden"]),
+                "fc2": (cfg["hidden"], cfg["num_classes"])}
+        got = {k: tuple(v["w"].shape) for k, v in shapes.items()}
+        if got != want:
+            raise RuntimeError(f"the program's CNN is {got}, the configuration "
+                               f"states {want}")
 
     def _host_plans(self, i: int) -> np.ndarray:
         if i not in self.plans:

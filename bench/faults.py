@@ -9,7 +9,7 @@ context is open; the engine has to be built inside it.
 * ``half_the_clients``   — aggregation leaves out half of the round's
   clients and takes the weighted mean over the rest;
 * ``half_of_each_batch`` — every local batch trains on its first half of
-  samples, the mean taken over those;
+  samples (rows), the mean taken over those;
 * ``answer_altered``     — the eval loss is scaled by 1.5 where it is made;
 * ``strategies_swapped`` — each strategy's trajectories come back under
   the next strategy's name;
@@ -41,43 +41,42 @@ def _half_the_clients(stack: contextlib.ExitStack) -> None:
     stack.enter_context(mock.patch.object(rnd, "masked_weighted_mean", half))
 
 
-def _replace_cnn(stack: contextlib.ExitStack, **fields) -> None:
-    import repro.fl.workloads as wls
-    wl = wls.get_workload("cnn")
-    stack.enter_context(mock.patch.dict(
-        wls._WORKLOADS, {"cnn": dataclasses.replace(wl, **fields)}))
+def _wrap_workload(stack: contextlib.ExitStack, field: str,
+                   wrap: Callable) -> None:
+    """Whatever workload the program's trial is built on, by name or as an
+    object, has the function its ``field`` factory builds wrapped by
+    ``wrap``: the fault reaches every client model an engine sets up."""
+    import repro.fl.sim as sim
+    resolve = sim.get_workload
+
+    def wrapped(workload):
+        wl = resolve(workload)
+        make = getattr(wl, field)
+        return dataclasses.replace(wl, **{field: lambda ds: wrap(make(ds))})
+    stack.enter_context(mock.patch.object(sim, "get_workload", wrapped))
 
 
 def _half_of_each_batch(stack: contextlib.ExitStack) -> None:
     import jax.numpy as jnp
 
-    import repro.fl.workloads as wls
-    make_loss = wls.get_workload("cnn").make_loss
-
-    def halved(ds):
-        loss = make_loss(ds)
-
+    def halved(loss):
         def first_half(params, batch):
             valid = batch["valid"]
-            keep = jnp.arange(valid.shape[-1]) < valid.shape[-1] // 2
+            keep = jnp.arange(valid.shape[0]) < valid.shape[0] // 2
+            keep = keep.reshape(keep.shape + (1,) * (valid.ndim - 1))
             return loss(params, dict(batch, valid=jnp.where(
                 keep, valid, jnp.zeros_like(valid))))
         return first_half
-    _replace_cnn(stack, make_loss=halved)
+    _wrap_workload(stack, "make_loss", halved)
 
 
 def _answer_altered(stack: contextlib.ExitStack) -> None:
-    import repro.fl.workloads as wls
-    make_eval = wls.get_workload("cnn").make_eval
-
-    def scaled(ds):
-        ev = make_eval(ds)
-
+    def scaled(ev):
         def altered(params, batch):
             loss, m = ev(params, batch)
             return loss * 1.5, m
         return altered
-    _replace_cnn(stack, make_eval=scaled)
+    _wrap_workload(stack, "make_eval", scaled)
 
 
 def _rolled(axis: int) -> Callable[[contextlib.ExitStack], None]:

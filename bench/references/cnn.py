@@ -32,7 +32,8 @@ Semantics it holds the program to (the FL round of Algorithm 1):
   gradients, their weighted mean takes one step of −lr;
 * eval: mean cross-entropy and accuracy of the new global model.
 
-``precision="bfloat16"`` runs the same trial with weights, images,
+``precision=CONTROL_PRECISION`` (bfloat16, the step below the float32
+the configuration states) runs the same trial with weights, images,
 activations and the optimizer's moments in bfloat16: the control that the
 correctness check must refuse.
 """
@@ -48,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 B1, B2, EPS = 0.9, 0.999, 1e-8
+CONTROL_PRECISION = "bfloat16"
 NEG_INF = -1e30
 # Scores this close (relative) are ties float32 arithmetic may order
 # either way: about 16 float32 ulps.
@@ -257,6 +259,46 @@ def selected_samples(cfg, traffic, plan: np.ndarray, strategy: str,
                                cfg["clients_per_round"])[0]
         total += int((hists.sum(-1)[idx] * live).sum())
     return total
+
+
+def forward_flops(cfg: Dict[str, Any]) -> Dict[str, int]:
+    """Multiply-add FLOPs (2 per MAC) of one sample's forward pass, by layer:
+    conv 3×3 SAME at full resolution, then at half, then the two dense
+    layers.  Biases, activations and pooling are not counted."""
+    s, ch = cfg["image_size"], cfg["channels"]
+    c1, c2, hid, ncls = cfg["conv1"], cfg["conv2"], cfg["hidden"], cfg["num_classes"]
+    flat = (s // 4) ** 2 * c2
+    return {"conv1": 2 * s * s * c1 * 9 * ch,
+            "conv2": 2 * (s // 2) ** 2 * c2 * 9 * c1,
+            "fc1": 2 * flat * hid,
+            "fc2": 2 * hid * ncls}
+
+
+def train_flops_per_sample(cfg: Dict[str, Any]) -> int:
+    """Forward + backward FLOPs one trained sample requires: the forward, the
+    weight gradient of every layer (as much again), and the input gradient
+    of every layer but the first (the image needs none)."""
+    fwd = forward_flops(cfg)
+    return 3 * sum(fwd.values()) - fwd["conv1"]
+
+
+def num_params(cfg: Dict[str, Any]) -> int:
+    """The f32 parameters one client reports: what aggregation reads."""
+    s, ch = cfg["image_size"], cfg["channels"]
+    c1, c2, hid, ncls = cfg["conv1"], cfg["conv2"], cfg["hidden"], cfg["num_classes"]
+    flat = (s // 4) ** 2 * c2
+    return (9 * ch * c1 + c1 + 9 * c1 * c2 + c2 + flat * hid + hid
+            + hid * ncls + ncls)
+
+
+def trial_train_flops(cfg, traffic, plan: np.ndarray, strategy: str,
+                      seed: int) -> int:
+    """Forward + backward FLOPs the trial's selected clients' local training
+    requires: every valid sample they hold, once per local epoch (once under
+    fedsgd, one gradient a client).  Padding is not counted."""
+    passes = 1 if traffic["aggregation"] == "fedsgd" else cfg["local_epochs"]
+    return (train_flops_per_sample(cfg) * passes
+            * selected_samples(cfg, traffic, plan, strategy, seed))
 
 
 def run_trial(cfg: Dict[str, Any], traffic: Dict[str, Any], plan: np.ndarray,

@@ -39,8 +39,11 @@ def main(argv) -> int:
     for name in argv:
         cell = cells.load_cell(name)
         cfg, tr = cell.config, cell.traffic
-        f = sim.grid_fn(cfg, tr, sim.dataset(cfg))
-        plan =traffic_gen.trial_plan(cfg, tr, np.random.default_rng(0))
+        engine = cells.engine(cfg, tr)(cfg, tr, None)
+        ds = engine.dataset()
+        engine.check_model(ds)
+        f = sim.grid_fn(cfg, tr, ds, engine.workload())
+        plan = traffic_gen.trial_plan(cfg, tr, np.random.default_rng(0))
         r, s = tr["seeds_per_call"], len(tr["strategies"])
         args = (jax.ShapeDtypeStruct((1, r) + plan.shape, jnp.int32, sharding=one),
                 jax.ShapeDtypeStruct((s,), jnp.int32, sharding=one),

@@ -43,21 +43,19 @@ def _annotate(name: str):
 
 
 def window_work(cell, engine, calls, seeds) -> dict:
-    """The required work of the given window calls, from shapes and plans."""
+    """The required work of the given window calls, from shapes and plans:
+    the model's counts come from the configuration's reference module."""
     from bench import cells, work
     cfg, tr = cell.config, cell.traffic
     ref = cells.module("references", cfg["reference"])
     s_n, r_n, t_n = len(tr["strategies"]), tr["seeds_per_call"], tr["rounds_per_call"]
     n_clients, n_max = engine.plan(0, 0).shape[1:]
-    k, params = cfg["clients_per_round"], work.cnn_num_params(cfg)
-    per_sample = 1 if tr["aggregation"] == "fedsgd" else cfg["local_epochs"]
-    trained = sum(
-        per_sample * ref.selected_samples(cfg, tr, engine.plan(c.index, r),
-                                          tr["strategies"][s],
-                                          int(seeds[c.index][r]))
-        for c in calls for s in range(s_n) for r in range(r_n))
+    k, params = cfg["clients_per_round"], ref.num_params(cfg)
+    train = sum(ref.trial_train_flops(cfg, tr, engine.plan(c.index, r),
+                                      tr["strategies"][s], int(seeds[c.index][r]))
+                for c in calls for s in range(s_n) for r in range(r_n))
     # Data, and so its histograms, is shared by the strategies of one seed.
-    return {"trained_samples": trained,
+    return {"train_flops": train,
             "label_hist_bytes": len(calls) * t_n * r_n * work.label_hist_bytes(
                 n_clients, n_max, cfg["num_classes"]),
             "weighted_agg_bytes": len(calls) * t_n * s_n * r_n
@@ -78,7 +76,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     strategies = tr["strategies"]
     s_n, r_n, t_n = len(strategies), tr["seeds_per_call"], tr["rounds_per_call"]
     log = []
-    engine = cells.module("engines", tr["engine"]).Engine(
+    engine = cells.engine(cfg, tr)(
         cfg, tr, lambda i: traffic_gen.call_plans(cfg, tr, seed, i))
     engine.setup(WARM_CALL)
     engine.call(WARM_CALL, traffic_gen.call_seeds(tr, seed, WARM_CALL))
@@ -113,6 +111,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     memory_peak = max(max(int(st.get("peak_bytes_in_use", 0)),
                           int(st.get("peak_bytes_reserved", 0))) for st in stats)
     log.append(f"memory_stats {stats}")
+    # The traced ops are named by the executable that ran: keep its text.
+    hlo_text = engine.compiled.as_text() if trace else None
     engine.free()
     gc.collect()
 
@@ -158,9 +158,11 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     else:
         summary = trace_reduce.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        # What a per-layer reader reads (``bench/metrics/<name>.py``).
         ctx = {"config": cfg, "traffic": tr, "spans": engine.spans,
                "trace": summary, "peak": peak, "chips": cell.chips,
-               "window_s": w.seconds,
+               "window_s": w.seconds, "trial_rounds": rounds,
+               "hlo_text": hlo_text,
                "work": window_work(cell, engine, calls, seeds)}
         metrics = {}
         for m in cell.per_layer:

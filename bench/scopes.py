@@ -3,13 +3,16 @@ per-op device seconds of a traced window.
 
 The program names each stage of its round with ``jax.named_scope``
 (``fl.materialize``, ``fl.select``, ``fl.train``, ``fl.aggregate``,
-``fl.eval``, ``fl.cluster``) and each layer of its model likewise
-(``cnn.conv1`` … ``cnn.loss``, ``opt.update``).  A scope lands in the
+``fl.eval``, ``fl.cluster``) and each layer of its model likewise; the
+configuration lists the model's scopes under ``model_scopes``, each split
+into its forward and backward pass, and under ``model_scopes_whole`` those
+of them read whole, such as the optimizer's update.  A scope lands in the
 ``op_name`` metadata of every HLO instruction it lowers to, wrapped by the
 transformations that ran over it (``vmap(fl.train)``,
-``transpose(jvp(cnn.conv1))`` on the backward pass).  A trace names each
-device op by its instruction, so the instruction's scope is the op's.  This
-module parses the text itself and imports nothing of the program.
+``transpose(jvp(<scope>))`` on the backward pass).  A trace names each
+device op by its instruction, so the instruction's scope is the op's.  The
+harness passes the text of the executable that ran the window.  This module
+parses the text itself and imports nothing of the program.
 
 An instruction's stage is found by the first rule that applies:
 
@@ -38,15 +41,12 @@ import collections
 import dataclasses
 import re
 import sys
-import traceback
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 from bench import trace_reduce
 
 STAGES = ("materialize", "select", "train", "aggregate", "eval", "cluster")
 _STAGE = re.compile(r"(?<![\w.])fl\.(%s)\b" % "|".join(STAGES))
-_MODEL = re.compile(
-    r"(?<![\w.])(cnn\.(?:conv1|pool1|conv2|pool2|dense|loss)|opt\.update)\b")
 _RELAYOUT = frozenset({"copy", "copy-start", "copy-done", "reshape",
                        "transpose", "bitcast"})
 _PLUMBING = frozenset({"tuple", "get-tuple-element", "parameter"})
@@ -128,16 +128,26 @@ def stage_of(op_name: str) -> Optional[str]:
     return found[-1] if found else None
 
 
-def model_scope_of(op_name: str) -> Optional[str]:
-    """The innermost model scope one op_name names: ``opt.update``, or a CNN
-    layer as ``<scope>:fwd`` / ``<scope>:bwd`` (a ``transpose(…)`` wrapper
-    is the backward pass)."""
-    found = _MODEL.findall(op_name)
-    if not found:
-        return None
-    if not found[-1].startswith("cnn."):
-        return found[-1]
-    return found[-1] + (":bwd" if "transpose(" in op_name else ":fwd")
+def model_scope_key(model_scopes: Iterable[str], whole: Iterable[str] = ()
+                    ) -> Callable[[str], Optional[str]]:
+    """op_name → the innermost of the configuration's model scopes that it
+    names, or None: a scope in ``whole`` by its name alone, any other split
+    by direction as ``<scope>:fwd`` / ``<scope>:bwd`` (a ``transpose(…)``
+    wrapper is the backward pass)."""
+    names = sorted(model_scopes, key=len, reverse=True)
+    if not names:
+        return lambda op_name: None
+    whole = frozenset(whole)
+    pattern = re.compile(r"(?<![\w.])(%s)\b" % "|".join(map(re.escape, names)))
+
+    def key(op_name: str) -> Optional[str]:
+        found = pattern.findall(op_name)
+        if not found:
+            return None
+        if found[-1] in whole:
+            return found[-1]
+        return found[-1] + (":bwd" if "transpose(" in op_name else ":fwd")
+    return key
 
 
 def attribute(instrs: Dict[str, Instr],
@@ -206,40 +216,10 @@ def seconds_by(op_s: Dict[str, float], key: Dict[str, Optional[str]]
     return by, unscoped, total
 
 
-def _trial_rounds(ctx) -> Optional[int]:
-    """Trial-rounds in the window: the harness's count where it passes one,
-    else the aggregation work's count ÷ one trial-round's (bench.work)."""
-    if ctx.get("trial_rounds"):
-        return int(ctx["trial_rounds"])
-    from bench import work
-    per = work.weighted_agg_bytes(ctx["config"]["clients_per_round"],
-                                  work.cnn_num_params(ctx["config"]))
-    total = ctx.get("work", {}).get("weighted_agg_bytes")
-    return round(total / per) if total else None
-
-
-def _program_text(ctx) -> Optional[str]:
-    """The optimized HLO of the program the window ran: the harness's copy
-    where it passes one, else the cell's engine lowers and compiles it again
-    for the same shapes (a load from the persistent compile cache)."""
-    if ctx.get("hlo_text"):
-        return ctx["hlo_text"]
-    from bench import cells, traffic_gen
-    cfg, tr = ctx["config"], ctx["traffic"]
-    engine = cells.module("engines", tr["engine"]).Engine(
-        cfg, tr, lambda i: traffic_gen.call_plans(cfg, tr, 0, i))
-    engine.setup(0)
-    try:
-        return engine.compiled.as_text()
-    finally:
-        engine.free()
-
-
 def _set_up_trace_s() -> Dict[str, float]:
     """Seconds of the program's ``trace:`` spans (``trace:trial``,
     ``trace:fl.<stage>``), the last event of each name: those of the cell's
-    set-up, read before a reader lowers the program again.  Empty where the
-    program records no such spans."""
+    set-up.  Empty where the program records no such spans."""
     try:
         from repro.obs import events
     except ImportError:
@@ -261,19 +241,20 @@ def _log(msg: str) -> None:
 def read(ctx) -> Optional[dict]:
     """Seconds by stage and model scope for the traced window, computed once
     per ``ctx``; None where there is no trace, the window ran on the CPU,
-    there is no program text, or the program names no stage."""
+    the harness passed no program text or trial-round count, or the program
+    names no stage.
+
+    ``ctx`` carries ``trace``, ``hlo_text`` (the executable's text),
+    ``trial_rounds`` (the window's, as the harness counted them) and
+    ``config`` (whose ``model_scopes`` and ``model_scopes_whole``, where
+    given, are read too)."""
     if "_scopes" in ctx:
         return ctx["_scopes"]
     ctx["_scopes"] = None
     if not ctx.get("trace") or not _on_accelerator():
         return None
     traced = _set_up_trace_s()
-    try:
-        text = _program_text(ctx)
-    except Exception:                          # a reader must not fail the run
-        _log(f"no program text:\n{traceback.format_exc()}")
-        return None
-    rounds = _trial_rounds(ctx)
+    text, rounds = ctx.get("hlo_text"), ctx.get("trial_rounds")
     if not text or not rounds:
         return None
     instrs = parse(text)
@@ -282,7 +263,10 @@ def read(ctx) -> Optional[dict]:
     if not by_stage:
         _log("the program names no FL round stage")
         return None
-    by_model, _, _ = seconds_by(op_s, attribute(instrs, model_scope_of))
+    cfg = ctx.get("config", {})
+    model_of = model_scope_key(cfg.get("model_scopes", ()),
+                               cfg.get("model_scopes_whole", ()))
+    by_model, _, _ = seconds_by(op_s, attribute(instrs, model_of))
     missing = sum(s for n, s in op_s.items() if n not in instrs
                   and not trace_reduce._CONTAINER.match(n))
     out = {"trial_rounds": rounds, "stage_s": by_stage,

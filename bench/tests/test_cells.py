@@ -46,7 +46,7 @@ def test_every_cell_finds_its_files_by_name(w):
     cell = cells.load_cell(w["name"])
     assert cell.config["name"] == w["config"]
     assert set(cell.limits) == {"num_selected_gap", "loss_mean_gap", "loss_gap"}
-    cells.module("engines", cell.traffic["engine"]).Engine
+    cells.engine(cell.config, cell.traffic)
     cells.module("references", cell.config["reference"]).run_trial
     for m in cell.per_layer:
         assert callable(cells.module("metrics", m["name"]).read)

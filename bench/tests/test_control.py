@@ -21,8 +21,8 @@ def test_the_control_is_refused():
     trials = []
     for strat in tr["strategies"]:
         want = ref.run_trial(cfg, tr, plan, strat, seed)
-        ctl = ref.run_trial(cfg, tr, plan, strat, seed, precision="bfloat16",
-                            ties=False)[0]
+        ctl = ref.run_trial(cfg, tr, plan, strat, seed,
+                            precision=ref.CONTROL_PRECISION, ties=False)[0]
         trials.append(correct.trial_gaps(ctl, want))
     numbers = correct.aggregate(trials)
     ok, _, _ = correct.judge(numbers, real.limits)

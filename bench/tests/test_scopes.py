@@ -76,12 +76,19 @@ def test_the_four_rules_on_hand_written_hlo():
 
 
 def test_model_scope_and_direction():
-    assert scopes.model_scope_of(
-        "jit(g)/fl.train/vmap(jvp(cnn.conv1))/conv") == "cnn.conv1:fwd"
-    assert scopes.model_scope_of(
-        "jit(g)/vmap(transpose(jvp(cnn.pool2)))/select") == "cnn.pool2:bwd"
-    assert scopes.model_scope_of("jit(g)/fl.train/opt.update/mul") == "opt.update"
-    assert scopes.model_scope_of("jit(g)/fl.train/mul") is None
+    cfg = cells.load_cell("paper_cnn.case1b").config
+    key = scopes.model_scope_key(cfg["model_scopes"], cfg["model_scopes_whole"])
+    assert key("jit(g)/fl.train/vmap(jvp(cnn.conv1))/conv") == "cnn.conv1:fwd"
+    assert key("jit(g)/vmap(transpose(jvp(cnn.pool2)))/select") == "cnn.pool2:bwd"
+    assert key("jit(g)/fl.train/opt.update/mul") == "opt.update"
+    assert key("jit(g)/fl.train/mul") is None
+    assert key("jit(g)/fl.train/cnn.conv10/mul") is None
+    # The scopes are the configuration's: another model names its own.
+    other = scopes.model_scope_key(["lm.attn", "lm.moe"], ["lm.moe"])
+    assert other("jit(g)/vmap(transpose(jvp(lm.attn)))/dot") == "lm.attn:bwd"
+    assert other("jit(g)/lm.moe/dot") == "lm.moe"
+    assert other("jit(g)/vmap(jvp(cnn.conv1))/conv") is None
+    assert scopes.model_scope_key([])("jit(g)/cnn.conv1/conv") is None
     assert scopes.stage_of("jit(g)/fl.trainer/x") is None
     assert scopes.stage_of("jit(g)/fl.select/inner/fl.eval/x") == "eval"
 
@@ -155,11 +162,11 @@ def _trace(op_s):
 
 @pytest.mark.parametrize("name", STAGE_METRICS + ["unscoped_device_share"])
 def test_readers_find_nothing_without_a_trace_or_program_text(
-        name, monkeypatch, accelerator):
+        name, accelerator):
     read = cells.module("metrics", name).read
     assert read({"trace": None, "hlo_text": HLO, "trial_rounds": 1}) is None
-    monkeypatch.setattr(scopes, "_program_text", lambda ctx: None)
     assert read({"trace": _trace({"fusion.1": 1.0}), "trial_rounds": 1}) is None
+    assert read({"trace": _trace({"fusion.1": 1.0}), "hlo_text": HLO}) is None
 
 
 @pytest.mark.parametrize("name", STAGE_METRICS + ["unscoped_device_share"])
@@ -170,38 +177,62 @@ def test_readers_find_nothing_in_a_program_without_stages(name, accelerator):
     assert cells.module("metrics", name).read(ctx) is None
 
 
-def test_a_failing_relower_is_no_reading(monkeypatch, accelerator):
-    def refuse(ctx):
-        raise RuntimeError("no device")
-    monkeypatch.setattr(scopes, "_program_text", refuse)
-    ctx = {"trace": _trace({"fusion.1": 1.0}), "trial_rounds": 1}
-    assert cells.module("metrics", "train_device_ms").read(ctx) is None
+def test_no_program_text_is_no_reading_and_builds_no_engine(monkeypatch,
+                                                            accelerator):
+    """Without the harness's program text there is no reading, and no
+    reader builds an engine to lower the program again."""
+    def refuse(kind, name):
+        raise AssertionError(f"a reader built {kind}.{name}")
+    monkeypatch.setattr(cells, "module", refuse)
+    ctx = {"trace": _trace({"fusion.1": 1.0}), "trial_rounds": 1,
+           "config": cells.load_cell("paper_cnn.case1b").config,
+           "traffic": cells.load_cell("paper_cnn.case1b").traffic}
+    assert scopes.stage_ms(ctx, "train") is None
 
 
-def test_trial_rounds_come_from_the_aggregation_work():
-    from bench import work
-    cfg = cells.load_cell("paper_cnn.case1b").config
-    per = work.weighted_agg_bytes(cfg["clients_per_round"],
-                                  work.cnn_num_params(cfg))
-    ctx = {"config": cfg, "work": {"weighted_agg_bytes": 18 * per}}
-    assert scopes._trial_rounds(ctx) == 18
-    assert scopes._trial_rounds(dict(ctx, trial_rounds=5)) == 5
+@pytest.fixture(scope="module")
+def traced_tiny(tmp_path_factory):
+    """One traced tiny run, read as on an accelerator, with the ``ctx`` the
+    harness handed the readers."""
+    seen = []
+    read = scopes.read
+
+    def spy(ctx):
+        seen.append(ctx)
+        return read(ctx)
+    cell = tiny.cell(strategies=("labelwise",))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(scopes, "_on_accelerator", lambda: True)
+        mp.setattr(scopes, "read", spy)
+        result, _ = run.run_cell(
+            cell, BIG_SEED, 0.5, True, jax.devices(), CPU_PEAK,
+            trace_dir=str(tmp_path_factory.mktemp("trace")))
+    return cell, result, seen[0]
 
 
-def test_the_log_gives_set_up_trace_seconds_before_the_relower(
-        monkeypatch, capsys, accelerator):
+def test_trial_rounds_come_from_the_aggregation_work(traced_tiny):
+    """The trial-rounds the stage readers divide by are the harness's own
+    count of the window's work, whatever the model: no model's sizes
+    enter it."""
+    cell, result, ctx = traced_tiny
+    rounds = result["attempted"] * cell.traffic["rounds_per_call"]
+    assert ctx["trial_rounds"] == rounds
+    assert ctx["_scopes"]["trial_rounds"] == rounds
+    # The program text is the executable's that ran the window.
+    assert "fl.train" in ctx["hlo_text"]
+    assert set(ctx["_scopes"]["model_s"]) >= {"cnn.conv1:fwd", "cnn.conv1:bwd",
+                                              "opt.update"}
+
+
+def test_the_log_gives_set_up_trace_seconds(monkeypatch, capsys, accelerator):
     import repro.obs
     spans = [{"name": "trace:trial", "ph": "X", "dur": 9e6},
              {"name": "trace:fl.train", "ph": "X", "dur": 1e6},
              {"name": "trace:trial", "ph": "X", "dur": 2.5e6},
              {"name": "compile", "ph": "X", "dur": 4e6}]
     monkeypatch.setattr(repro.obs, "events", lambda: list(spans))
-
-    def relower(ctx):                            # a reader's own lowering
-        spans.append({"name": "trace:trial", "ph": "X", "dur": 7e6})
-        return HLO
-    monkeypatch.setattr(scopes, "_program_text", relower)
-    ctx = {"trace": _trace({"fusion.1": 1.0}), "trial_rounds": 1}
+    ctx = {"trace": _trace({"fusion.1": 1.0}), "trial_rounds": 1,
+           "hlo_text": HLO}
     assert scopes.read(ctx) is not None
     log = capsys.readouterr().err
     assert "set-up trace seconds {'trace:trial': 2.5, 'trace:fl.train': 1.0}" in log
@@ -225,10 +256,8 @@ def test_per_layer_entries_name_their_source_and_layer():
         assert m["source"] in sources and 1 <= len(m["layer"]) <= 200
 
 
-def test_a_traced_tiny_cell_reports_the_stages(tmp_path, accelerator):
-    cell = tiny.cell(strategies=("labelwise",))
-    result, _ = run.run_cell(cell, BIG_SEED, 0.5, True, jax.devices(),
-                             CPU_PEAK, trace_dir=str(tmp_path / "trace"))
+def test_a_traced_tiny_cell_reports_the_stages(traced_tiny):
+    _, result, _ = traced_tiny
     assert result["correct"] is True
     m = result["metrics"]
     assert set(NEW_METRICS) <= set(m)

@@ -4,17 +4,19 @@ from __future__ import annotations
 import pytest
 
 from bench import cells, work
+from bench.references import cnn
 
-PAPER = cells.load_cell("paper_cnn.case1b").config
+PAPER_CELL = cells.load_cell("paper_cnn.case1b")
+PAPER = PAPER_CELL.config
 
 
 def test_cnn_flops_by_hand():
-    f = work.cnn_forward_flops(PAPER)
+    f = cnn.forward_flops(PAPER)
     assert f == {"conv1": 2 * 28 * 28 * 32 * 9, "conv2": 2 * 14 * 14 * 64 * 9 * 32,
                  "fc1": 2 * 3136 * 128, "fc2": 2 * 128 * 10}
     fwd = sum(f.values())
-    assert work.cnn_train_flops_per_sample(PAPER) == 3 * fwd - f["conv1"]
-    assert work.cnn_train_flops_per_sample(PAPER) == 24_995_328
+    assert cnn.train_flops_per_sample(PAPER) == 3 * fwd - f["conv1"]
+    assert cnn.train_flops_per_sample(PAPER) == 24_995_328
 
 
 def test_param_count_matches_the_programs_cnn():
@@ -22,11 +24,15 @@ def test_param_count_matches_the_programs_cnn():
 
     from bench.engines import sim
     from repro.fl.workloads import get_workload
-    ds = sim.dataset(PAPER)
-    shapes = get_workload("cnn").param_shapes(ds)
+    engine = sim.Engine(PAPER, PAPER_CELL.traffic, None)
+    ds = engine.dataset()
+    shapes = get_workload(engine.workload()).param_shapes(ds)
     n = sum(x.size for x in jax.tree_util.tree_leaves(shapes))
-    assert n == work.cnn_num_params(PAPER) == 421_642
-    sim.check_model_shapes(PAPER, ds)
+    assert n == cnn.num_params(PAPER) == 421_642
+    engine.check_model(ds)
+    with pytest.raises(RuntimeError):
+        sim.Engine(dict(PAPER, hidden=64), PAPER_CELL.traffic,
+                   None).check_model(ds)
 
 
 def test_roofline_share_and_bound():

@@ -1,6 +1,11 @@
 """Operations and bytes the algorithm needs, computed from shapes, and the
 table of device peaks.  These are the yardstick of the utilization and
 roofline metrics; what the program happens to execute does not enter them.
+
+This module holds what every client model shares: the peaks, the roofline,
+and the work of the two kernels of the FL round, which read label plans and
+flat parameter stacks.  A model's own counts (its parameters, its training
+FLOPs) live in its reference module, ``bench/references/<name>.py``.
 """
 from __future__ import annotations
 
@@ -20,36 +25,6 @@ def peaks(device_kind: str, path: str = PEAKS_FILE) -> Dict[str, Any]:
         raise KeyError(f"no peaks for device kind {device_kind!r}; the table "
                        f"has {sorted(table)} — add the device with its source")
     return table[device_kind]
-
-
-def cnn_forward_flops(cfg: Dict[str, Any]) -> Dict[str, int]:
-    """Multiply-add FLOPs (2 per MAC) of one sample's forward pass, by layer:
-    conv 3×3 SAME at full resolution, then at half, then the two dense
-    layers.  Biases, activations and pooling are not counted."""
-    s, ch = cfg["image_size"], cfg["channels"]
-    c1, c2, hid, ncls = cfg["conv1"], cfg["conv2"], cfg["hidden"], cfg["num_classes"]
-    flat = (s // 4) ** 2 * c2
-    return {"conv1": 2 * s * s * c1 * 9 * ch,
-            "conv2": 2 * (s // 2) ** 2 * c2 * 9 * c1,
-            "fc1": 2 * flat * hid,
-            "fc2": 2 * hid * ncls}
-
-
-def cnn_train_flops_per_sample(cfg: Dict[str, Any]) -> int:
-    """Forward + backward FLOPs one trained sample requires: the forward, the
-    weight gradient of every layer (as much again), and the input gradient
-    of every layer but the first (the image needs none)."""
-    fwd = cnn_forward_flops(cfg)
-    total = sum(fwd.values())
-    return 3 * total - fwd["conv1"]
-
-
-def cnn_num_params(cfg: Dict[str, Any]) -> int:
-    s, ch = cfg["image_size"], cfg["channels"]
-    c1, c2, hid, ncls = cfg["conv1"], cfg["conv2"], cfg["hidden"], cfg["num_classes"]
-    flat = (s // 4) ** 2 * c2
-    return (9 * ch * c1 + c1 + 9 * c1 * c2 + c2 + flat * hid + hid
-            + hid * ncls + ncls)
 
 
 def label_hist_bytes(clients: int, samples: int, classes: int) -> int:
