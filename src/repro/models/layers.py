@@ -626,37 +626,38 @@ def _ssd_chunked(x: Array, dt: Array, A: Array, B: Array, C: Array,
 
     x: (b, S, H, P) f32; dt: (b, S, H); A: (H,) (negative); B, C: (b, S, G, N)
     with G dividing H.  Returns (y: (b,S,H,P), final_state: (b,H,P,N)).
+
+    Heads are viewed as (G, H/G): head h reads group h // (H/G).  C·B, which
+    depends on the group alone, is contracted once per group; B and C meet the
+    per-head x, decay and state only inside a contraction, so no head-repeated
+    copy of them is formed.
     """
     b, s, h, pdim = x.shape
     g, n = B.shape[2], B.shape[3]
     assert s % chunk == 0, (s, chunk)
     nc = s // chunk
-    rep = h // g
-    Bh = jnp.repeat(B, rep, axis=2)      # (b,S,H,N)
-    Ch = jnp.repeat(C, rep, axis=2)
+    r = h // g
 
-    xc = x.reshape(b, nc, chunk, h, pdim)
-    dtc = dt.reshape(b, nc, chunk, h)
-    Bc = Bh.reshape(b, nc, chunk, h, n)
-    Cc = Ch.reshape(b, nc, chunk, h, n)
+    dtc = dt.reshape(b, nc, chunk, g, r)
+    xdt = x.reshape(b, nc, chunk, g, r, pdim) * dtc[..., None]   # dt_s x_s
+    Bc = B.reshape(b, nc, chunk, g, n)
+    Cc = C.reshape(b, nc, chunk, g, n)
 
-    dA = dtc * A                                      # (b,nc,q,h) log-decay per step
-    cum = jnp.cumsum(dA, axis=2)                      # inclusive cumulative log decay
+    cum = jnp.cumsum(dtc * A.reshape(g, r), axis=2)   # (b,nc,q,g,r) inclusive log decay
     # Intra-chunk (quadratic) term: M[t, s] = exp(cum_t − cum_s) C_t·B_s dt_s, s ≤ t.
-    seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]          # (b,nc,q,q,h)
+    seg = cum[:, :, :, None] - cum[:, :, None, :]                # (b,nc,q,q,g,r)
     tri = jnp.tril(jnp.ones((chunk, chunk), jnp.bool_))
-    seg = jnp.where(tri[None, None, :, :, None], seg, -jnp.inf)
-    L = jnp.exp(seg)
-    scores = jnp.einsum("bcqhn,bcshn->bcqsh", Cc, Bc) * L
-    y_intra = jnp.einsum("bcqsh,bcsh,bcshp->bcqhp", scores, dtc, xc)
+    L = jnp.exp(jnp.where(tri[None, None, :, :, None, None], seg, -jnp.inf))
+    cb = jnp.einsum("bcqgn,bcsgn->bcqsg", Cc, Bc)                # once per group
+    y_intra = jnp.einsum("bcqsgr,bcsgrp->bcqgrp", L * cb[..., None], xdt)
 
     # Per-chunk input→final-state term: S_c = Σ_s exp(cum_Q − cum_s) dt_s B_s ⊗ x_s.
-    decay_to_end = jnp.exp(cum[:, :, -1:, :] - cum)              # (b,nc,q,h)
-    state_in = jnp.einsum("bcsh,bcsh,bcshn,bcshp->bchpn",
-                          decay_to_end, dtc, Bc, xc)
+    decay_to_end = jnp.exp(cum[:, :, -1:] - cum)                 # (b,nc,q,g,r)
+    state_in = jnp.einsum("bcsgn,bcsgrp->bcgrpn",
+                          Bc, xdt * decay_to_end[..., None]).reshape(b, nc, h, pdim, n)
 
     # Inter-chunk recurrence over nc: S←exp(cum_Q)·S_prev + S_c (scan, f32).
-    chunk_decay = jnp.exp(cum[:, :, -1, :])                      # (b,nc,h)
+    chunk_decay = jnp.exp(cum[:, :, -1]).reshape(b, nc, h)
 
     def step(carry, inp):
         dcy, s_in = inp
@@ -666,10 +667,11 @@ def _ssd_chunked(x: Array, dt: Array, A: Array, B: Array, C: Array,
     s0 = jnp.zeros((b, h, pdim, n), jnp.float32) if init_state is None else init_state
     final, entering = jax.lax.scan(
         step, s0, (jnp.moveaxis(chunk_decay, 1, 0), jnp.moveaxis(state_in, 1, 0)))
-    entering = jnp.moveaxis(entering, 0, 1)                      # (b,nc,h,p,n)
+    entering = jnp.moveaxis(entering, 0, 1).reshape(b, nc, g, r, pdim, n)
 
-    # Inter-chunk output: y_t += C_t · (exp(cum_t) · S_entering).
-    y_inter = jnp.einsum("bcqhn,bchpn->bcqhp", Cc * jnp.exp(cum)[..., None], entering)
+    # Inter-chunk output: y_t += exp(cum_t) · (C_t · S_entering).
+    y_inter = (jnp.einsum("bcqgn,bcgrpn->bcqgrp", Cc, entering)
+               * jnp.exp(cum)[..., None])
     y = (y_intra + y_inter).reshape(b, s, h, pdim)
     return y, final
 

@@ -232,6 +232,28 @@ def test_training_and_eval_matmuls_run_in_float32():
         assert "precision = [DEFAULT, DEFAULT]" not in text
 
 
+def test_the_scan_contracts_c_dot_b_once_per_group():
+    """The chunked SSD at the cell's widths (4,096 tokens, the preset's
+    heads, head width, state, groups and chunk) compiles to the analytic
+    count, in which C·B is contracted once per group and not once per
+    head: at one group for 128 heads the per-head form counts twice it."""
+    g = GRANITE
+    b, s, h, p, n, grp, q = (1, 4096, g.ssm_heads, g.ssm_head_dim,
+                             g.ssm_state, g.ssm_groups, g.ssm_chunk)
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    with jax.default_matmul_precision("float32"):
+        compiled = jax.jit(lambda x, dt, a, bm, cm: L._ssd_chunked(
+            x, dt, a, bm, cm, q)).lower(
+                f32(b, s, h, p), f32(b, s, h), f32(h), f32(b, s, grp, n),
+                f32(b, s, grp, n)).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    analytic = 2 * b * s * (q * h * p          # intra-chunk scores · x
+                            + 2 * h * p * n    # chunk states, inter-chunk output
+                            + q * grp * n)     # C·B, once per group
+    assert abs(cost["flops"] / analytic - 1) < 0.1, (cost["flops"], analytic)
+
+
 def test_sim_and_host_engines_agree_through_the_shared_step():
     wl = tiny_workload()
     sc = ScenarioSpec.from_case("case1b", samples_per_client=4)

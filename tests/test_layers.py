@@ -120,22 +120,49 @@ class TestMoE:
 
 
 class TestSSD:
-    @pytest.mark.parametrize("s,chunk", [(32, 8), (64, 16), (64, 64)])
-    @pytest.mark.parametrize("g", [1, 2])
-    def test_chunked_equals_sequential(self, s, chunk, g):
-        b, h, pdim, n = 2, 4, 8, 16
+    @staticmethod
+    def _inputs(s, g, b=2, h=4, pdim=8, n=16):
         ks = jax.random.split(KEY, 5)
         x = jax.random.normal(ks[0], (b, s, h, pdim))
         dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
         A = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.5)
         B = jax.random.normal(ks[3], (b, s, g, n)) * 0.5
         C = jax.random.normal(ks[4], (b, s, g, n)) * 0.5
+        return x, dt, A, B, C
+
+    @pytest.mark.parametrize("s,chunk", [(32, 8), (64, 16), (64, 64)])
+    @pytest.mark.parametrize("g", [1, 2, 4])      # 4 = H: one group a head
+    def test_chunked_equals_sequential(self, s, chunk, g):
+        x, dt, A, B, C = self._inputs(s, g)
         y_chunk, f_chunk = L._ssd_chunked(x, dt, A, B, C, chunk)
         y_ref, f_ref = L._ssd_reference(x, dt, A, B, C)
         np.testing.assert_allclose(np.asarray(y_chunk), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(np.asarray(f_chunk), np.asarray(f_ref),
                                    rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("g", [1, 2, 4])
+    def test_chunked_gradients_equal_sequential(self, g):
+        """The chunked scan's gradients in x, dt, B and C are the
+        sequential recurrence's, at float32 precision."""
+        s, chunk = 64, 16
+        x, dt, A, B, C = self._inputs(s, g)
+        w_y = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+        w_f = jax.random.normal(jax.random.PRNGKey(8),
+                                x.shape[:1] + x.shape[2:] + B.shape[3:])
+
+        def scalar(ssd):
+            def f(x, dt, B, C):
+                y, final = ssd(x, dt, A, B, C)
+                return jnp.sum(y * w_y) + jnp.sum(final * w_f)
+            return jax.grad(f, argnums=(0, 1, 2, 3))
+
+        with jax.default_matmul_precision("float32"):
+            got = scalar(lambda *a: L._ssd_chunked(*a, chunk))(x, dt, B, C)
+            want = scalar(L._ssd_reference)(x, dt, B, C)
+        for name, gv, wv in zip("x dt B C".split(), got, want):
+            np.testing.assert_allclose(np.asarray(gv), np.asarray(wv),
+                                       rtol=1e-4, atol=1e-4, err_msg=name)
 
     def test_prefill_then_decode_equals_full(self):
         """Mamba block: prefill state + single-step recurrence == full scan."""
