@@ -19,21 +19,14 @@ import traceback
 
 SUITES = [
     ("fig5_kl", "benchmarks.fig5_kl"),
-    ("selection_cost", "benchmarks.selection_cost"),
-    ("kernel_bench", "benchmarks.kernel_bench"),
     ("table1_six_cases", "benchmarks.table1_six_cases"),
     ("fig6_fig7_bias_sweep", "benchmarks.fig6_fig7_bias_sweep"),
     ("fig8_fig9_cases_a", "benchmarks.fig8_fig9_cases_a"),
     ("fig10_table2_proportion", "benchmarks.fig10_table2_proportion"),
     ("dirichlet_ablation", "benchmarks.dirichlet_ablation"),
-    ("hotpath", "benchmarks.hotpath"),
-    ("sim_grid", "benchmarks.sim_grid"),
-    ("workload_grid", "benchmarks.workload_grid"),
     ("clustered", "benchmarks.clustered"),
     ("robust", "benchmarks.robust"),
-    ("sharded_round", "benchmarks.sharded_round"),
     ("population", "benchmarks.population"),
-    ("roofline_report", "benchmarks.roofline_report"),
 ]
 
 
@@ -41,21 +34,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)
-    ap.add_argument("--sim-grid", action="store_true",
-                    help="only run the compiled-engine vs host-loop grid "
-                         "comparison and emit BENCH_sim_grid.json")
-    ap.add_argument("--sharded-round", action="store_true",
-                    help="only run the gather-based vs masked-psum SPMD "
-                         "round comparison (8/16/32 emulated devices) and "
-                         "emit BENCH_sharded_round.json")
-    ap.add_argument("--workload-grid", action="store_true",
-                    help="only run the per-workload (cnn vs lm) compiled "
-                         "grid vs host-loop comparison and emit "
-                         "BENCH_workloads.json")
-    ap.add_argument("--hotpath", action="store_true",
-                    help="only run the round hot-path micro-bench (one_hot "
-                         "vs fused histogram, tree-map vs fused "
-                         "aggregation) and emit BENCH_hotpath.json")
     ap.add_argument("--clustered", action="store_true",
                     help="only run the clustered_fedavg (per-cluster global "
                          "models) vs single-model fedavg accuracy "
@@ -72,14 +50,6 @@ def main(argv=None) -> int:
                          "compiled-memory measurements, async FedBuff demo) "
                          "and emit BENCH_population.json")
     args = ap.parse_args(argv)
-    if args.sim_grid:
-        args.only = "sim_grid"
-    if args.sharded_round:
-        args.only = "sharded_round"
-    if args.workload_grid:
-        args.only = "workload_grid"
-    if args.hotpath:
-        args.only = "hotpath"
     if args.clustered:
         args.only = "clustered"
     if args.robust:

@@ -5,9 +5,9 @@ IID converges.
 The experiment is declared as data (repro.fl.experiment): seven case
 scenarios × 1 strategy × 1 seed, dispatched to the compiled simulation
 engine — the scenario axis is vmapped, the round loop is a device-resident
-lax.scan, no per-case re-jits.  Swap ``engine="host"`` for the legacy
-per-round loop or add strategies/seeds/transforms without touching any
-engine code.
+lax.scan, no per-case re-jits.  Swap ``engine="host"`` to call the same
+round step once per round from Python, or add strategies/seeds/transforms
+without touching any engine code.
 
     PYTHONPATH=src python examples/six_noniid_cases.py
 """

@@ -28,10 +28,8 @@ def histogram(labels: Array, num_classes: int, valid: Array | None = None) -> Ar
     written into the ``(…, C)`` output column by column — the ``(…, n, C)``
     f32 one-hot the old formulation materialized never exists, so the
     per-round memory high-water mark is O(n) instead of O(n·C) per client.
-    Measured on the 2-core CPU container this is also 2–7× faster than the
-    one-hot contraction at every engine shape, including under ``vmap`` over
-    a trial grid where a scatter/segment-sum formulation degrades badly
-    (batched scatter); ``benchmarks/hotpath.py`` records the comparison.
+    A scatter/segment-sum formulation is avoided on purpose: under ``vmap``
+    over a trial grid it becomes a batched scatter.
     Counts are sums of {0, 1} (or 0/1 validity weights), so the result is
     bit-identical to the one-hot form (exact integer-valued f32 arithmetic;
     pinned by tests/test_compute_dispatch.py).  Out-of-range labels (−1

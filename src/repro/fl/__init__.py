@@ -1,11 +1,10 @@
 from .client import local_train, local_gradient
-from .round import (clustered_update_step, make_fl_round, resolve_aggregator,
+from .round import (clustered_update_step, resolve_aggregator,
                     stack_global_params)
 from .workloads import (Workload, get_workload, lm_workload, register_workload,
                         registered_workloads)
 from .loop import run_fl, run_fl_host, FLHistory, success_rate
-from .sharded import (exchange_bytes_per_device, make_sharded_fl_round,
-                      topn_mask_from_scores)
+from .sharded import exchange_bytes_per_device, make_sharded_fl_round
 from .sim import (GridResult, grid_arrays, make_trial_fn, run_grid, simulate,
                   stack_case_plans, strategy_id)
 from .experiment import (ExperimentResult, ExperimentSpec, LoweredScenario,
@@ -20,7 +19,7 @@ from repro.core import (Aggregator, register_aggregator,
                         registered_aggregators, register_strategy,
                         registered_strategies)
 
-__all__ = ["local_train", "local_gradient", "make_fl_round", "run_fl",
+__all__ = ["local_train", "local_gradient", "run_fl",
            "clustered_update_step", "resolve_aggregator",
            "stack_global_params", "Aggregator", "register_aggregator",
            "registered_aggregators",
@@ -28,7 +27,6 @@ __all__ = ["local_train", "local_gradient", "make_fl_round", "run_fl",
            "Workload", "get_workload", "lm_workload", "register_workload",
            "registered_workloads",
            "exchange_bytes_per_device", "make_sharded_fl_round",
-           "topn_mask_from_scores",
            "GridResult", "grid_arrays", "make_trial_fn", "run_grid",
            "simulate", "stack_case_plans", "strategy_id",
            "ExperimentResult", "ExperimentSpec", "LoweredScenario",

@@ -41,8 +41,8 @@ Five orthogonal registries make every axis pluggable without engine edits:
   *ordered* list of TransformSpecs (availability dropout, quantity skew, …)
   that lower onto the base plan host-side before the arrays enter a device.
 * **engines** — ``register_engine(name, fn)``: "sim" (the compiled vmapped
-  grid, one XLA program), "host" (the legacy per-round loop, the parity
-  oracle), "sharded" (the gather-based SPMD pod-scale round: clients in
+  grid, one XLA program), "host" (the same round step called per round
+  from Python), "sharded" (the gather-based SPMD pod-scale round: clients in
   equal blocks per mesh slice, any registered strategy, training FLOPs
   scale with the selection budget), "hier" (hierarchical two-tier rounds:
   block-streamed selection + edge/global reduction — repro.fl.population;
@@ -850,7 +850,8 @@ def _engine_sim(spec: ExperimentSpec, lowered: Sequence[LoweredScenario], ds):
 
 
 def _engine_host(spec: ExperimentSpec, lowered: Sequence[LoweredScenario], ds):
-    """Legacy per-round host loop over every grid cell — the parity oracle."""
+    """The sim engine's round step driven per round from Python, one grid
+    cell at a time."""
     from .loop import run_fl_host
     agg = get_aggregator(spec.aggregation or spec.fl.aggregation)
     adv_masks = spec.adversary_masks()

@@ -5,9 +5,10 @@ it is a thin shim over the declarative experiment surface
 
 * ``engine="sim"`` (default) — the compiled simulator (repro.fl.sim): the
   round loop is a device-resident lax.scan, one jit for the whole trial.
-* ``engine="host"`` — the legacy per-round host loop (``run_fl_host`` below),
-  kept as the parity oracle (tests/test_fl_sim.py) and the baseline the
-  BENCH_sim_grid speedup is measured against.
+* ``engine="host"`` — ``run_fl_host`` below: the compiled engine's round
+  step, compiled once and called per round from Python.  The sim and host
+  engines are two drivers of one step; tests/test_fl_sim.py pins that they
+  agree.
 * ``engine="sharded"`` — the SPMD pod-scale round (one mesh slice per
   client; see repro.fl.experiment._engine_sharded for its constraints).
 
@@ -18,23 +19,16 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from collections import deque
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import plan_round
-from repro.data import client_batches
-from repro.obs import (make_collector, phase, record_memory_analysis,
-                       resolve_metrics, resolve_telemetry_request, span)
-from .round import (make_fl_round, resolve_adversary, resolve_aggregator,
-                    stack_global_params)
-from .workloads import Workload, frozen_base, get_workload, with_frozen
-
-Array = jax.Array
-PyTree = Any
+from repro.obs import record_memory_analysis, span
+from .round import resolve_aggregator
+from .sim import _ones_avail, _split_telemetry, make_round_step
+from .workloads import Workload, frozen_base
 
 
 @dataclasses.dataclass
@@ -52,9 +46,9 @@ class FLHistory:
     cluster_accuracy: Optional[List[List[float]]] = None
     cluster_loss: Optional[List[List[float]]] = None
     cluster_assign: Optional[List[List[int]]] = None
-    # AOT round/eval compile time, excluded from wall_s (the host engine's
-    # half of the wall_s/compile_s honesty fix), and the per-round in-graph
-    # metric series (name → (rounds, …) lists) when telemetry is on.
+    # AOT compile time of the round step, excluded from wall_s, and the
+    # per-round in-graph metric series (name → (rounds, …) stacks) when
+    # telemetry is on.
     compile_s: float = 0.0
     telemetry: Optional[Dict[str, Any]] = None
 
@@ -77,7 +71,7 @@ def run_fl(plan: np.ndarray, fl_cfg, *, strategy: Optional[str] = None,
 
     Thin shim over the declarative surface (repro.fl.experiment): the plan
     becomes a single explicit-plan ScenarioSpec and ``engine`` picks the
-    runner from the engine registry ("sim" compiled grid, "host" legacy loop,
+    runner from the engine registry ("sim" compiled grid, "host" per-round loop,
     "sharded" SPMD).  ``workload`` names the registered client workload
     (repro.fl.workloads) — "cnn" (the paper model, default) or any other
     registered bundle such as "lm"."""
@@ -121,169 +115,72 @@ def run_fl_host(plan: np.ndarray, fl_cfg, *, strategy: Optional[str] = None,
                 telemetry: Sequence[str] = (),
                 adversary: Optional[dict] = None,
                 adv: Optional[np.ndarray] = None) -> FLHistory:
-    """Legacy host-driven loop: one jitted round per step, eval on host.
+    """Host-driven loop: the compiled engine's round step
+    (repro.fl.sim.make_round_step), compiled once and called per round from
+    Python, with the trajectories pulled to the host after every round.
 
-    The parity oracle generalizes over the same workload registry as the
-    compiled engine, so host≡sim trajectory pins hold per workload.
-
-    The round and eval functions are AOT-compiled on the first round under a
-    ``repro.obs`` compile span, so ``FLHistory.compile_s`` is real and
-    ``wall_s`` excludes it (the engines' wall-clock numbers are comparable).
-    ``telemetry`` names registered round metrics (or ``("auto",)``) evaluated
-    on the round's device arrays; the series land in
-    ``FLHistory.telemetry[name]`` as (rounds, …) stacks.
-
-    ``adversary`` + ``adv`` (the (N,) byzantine mask) enable the engine-level
-    attack behaviors, matching the compiled engine exactly
-    (repro.fl.sim.make_trial_fn): byzantine clients poison their reported
-    deltas and/or train from a τ-rounds-old global kept in a host-side
-    window — the oracle half of the attacked-run host≡sim parity pins."""
-    wl = get_workload(workload)
-    ds = wl.dataset(ds)
+    ``plan`` is the composed (T, N, n) plan, so availability is all ones
+    and the strategy universe is ``(strategy,)``.  The step is AOT-compiled
+    under a ``repro.obs`` compile span before the first round, so
+    ``FLHistory.compile_s`` is real and ``wall_s`` excludes it.
+    ``telemetry`` names registered round metrics (or ``("auto",)``); the
+    series land in ``FLHistory.telemetry[name]`` as (rounds, …) stacks.
+    ``adversary`` + ``adv`` (the (N,) byzantine mask) enable the
+    engine-level attack behaviors, as in :func:`repro.fl.sim.make_trial_fn`.
+    """
+    name = strategy or fl_cfg.selection
     seed = fl_cfg.seed if seed is None else seed
     # `is None`, not falsy-or: rounds=0 is a zero-round dry-run (empty
     # history), not a request for the full schedule.
     rounds = fl_cfg.global_epochs if rounds is None else rounds
-    agg = resolve_aggregator(aggregation, fl_cfg)
-    poison_scale, tau = resolve_adversary(adversary)
-    attacked = poison_scale is not None or tau > 0
-    if attacked and adv is None:
+    rs = make_round_step(fl_cfg, ds, aggregation=aggregation,
+                         eval_n_per_class=eval_n_per_class, strategies=(name,),
+                         workload=workload, telemetry=telemetry,
+                         adversary=adversary)
+    if rs.attacked and adv is None:
         raise ValueError("adversary behaviors requested but no (N,) adv "
                          "byzantine mask passed")
     key = jax.random.PRNGKey(seed)
-    params = wl.init(jax.random.fold_in(key, 1), ds)
-    if agg.clustered:
-        if wl.frozen is not None:
-            raise ValueError(
-                f"workload {wl.name!r} has a frozen part; clustered "
-                "aggregation families train whole per-cluster models")
-        params = stack_global_params(params, agg.n_clusters)
-    # A frozen base is an input of the jitted round and eval, not a constant.
-    frozen = frozen_base(wl, ds)
-    # Metrics resolve BEFORE the round builds: the delta_outlier series needs
-    # the round to compute per-client update norms (a round-shape static).
-    avail_keys = ["hists", "mask", "num_classes", "params_old", "params_new"]
-    if agg.clustered:
-        avail_keys += ["assign", "n_clusters", "centroids", "prev_centroids"]
-    else:
-        avail_keys += ["client_update_norms"] + list(wl.counters)
-    metrics = resolve_metrics(resolve_telemetry_request(telemetry), avail_keys)
-    needs_norms = not agg.clustered and any(
-        "client_update_norms" in m.requires for m in metrics)
-    fl_round = make_fl_round(wl.make_loss(ds), fl_cfg, strategy, agg,
-                             poison_scale=poison_scale, with_stale=tau > 0,
-                             want_client_norms=needs_norms,
-                             counters=wl.counters, map_clients=wl.map_clients)
-    eval_batch = wl.eval_set(ds, eval_n_per_class)
-    eval_fn = wl.make_eval(ds)
-    if agg.clustered:
-        # Per-cluster eval + the valid-population mixture — the same f32 jnp
-        # ops as the compiled simulator's scan body, so host≡sim parity holds
-        # for the mixture exactly as it does for the single-model trajectory.
-        @jax.jit
-        def eval_jit(p, w):
-            with phase("eval"):
-                l_c, m_c = jax.vmap(lambda q: eval_fn(q, eval_batch))(p)
-                tot = jnp.maximum(w.sum(), 1.0)
-                return ((l_c * w).sum() / tot,
-                        {"accuracy": (m_c["accuracy"] * w).sum() / tot},
-                        m_c["accuracy"], l_c)
-    else:
-        @jax.jit
-        def eval_jit(p, frozen=None):
-            with phase("eval"):
-                return eval_fn(p, with_frozen(eval_batch, frozen))
-
-    hist_acc, hist_loss, hist_sel = [], [], []
-    c_acc, c_loss, c_assign = [], [], []
-    tel: Dict[str, List[np.ndarray]] = {}
+    # Built under jit, as inside the sim trial: op by op, the init rounds
+    # differently, and the two engines would start from other bits.
+    carry = jax.jit(rs.init, static_argnums=1)(key, plan.shape)
+    inputs = (key, jnp.asarray(plan, jnp.int32), jnp.int32(0),
+              _ones_avail(plan),
+              jnp.asarray(adv, jnp.float32) if rs.attacked else None,
+              frozen_base(rs.workload, ds))
     compile_s = 0.0
-    round_exec = eval_exec = collector = prev_cent = None
-    adv_dev = jnp.asarray(adv, jnp.float32) if attacked else None
-    # stale_update window: θ_{t−τ}..θ_t, so [0] is the byzantine training
-    # base (θ₀ while the run is younger than τ) — the host-side mirror of
-    # the compiled engine's scan-carried ring.
-    past = deque([params], maxlen=tau + 1) if tau else None
-    t0 = time.time()
+    if rounds:
+        with span("compile", engine="host", what="round") as sp:
+            step = jax.jit(rs.step).lower(carry, jnp.int32(0),
+                                          *inputs).compile()
+        compile_s = sp.duration_s
+        record_memory_analysis("host:round", step)
+    ys = []
+    t0 = time.perf_counter()
     for t in range(rounds):
-        kt = jax.random.fold_in(key, 1000 + t)
-        data = wl.materialize(ds, plan_round(plan, t),
-                              jax.random.fold_in(kt, 0))
-        batches = client_batches(data, fl_cfg.batch_size, wl.batch_keys)
-        key_t = jax.random.fold_in(kt, 1)
-        extra_args = ()
-        if attacked:
-            extra_args = (adv_dev, past[0] if tau else None)
-        if frozen is not None:
-            extra_args += (None,) * (2 - len(extra_args)) + (frozen,)
-        if round_exec is None:
-            # AOT-compile once so compile_s is accounted (not folded into
-            # wall_s) — round shapes are static across rounds.
-            with span("compile", engine="host", what="round") as sp:
-                round_exec = fl_round.lower(params, batches, data["hists"],
-                                            key_t, *extra_args).compile()
-            compile_s += sp.duration_s
-            record_memory_analysis("host:round", round_exec)
-        params_old = params
-        params, info = round_exec(params, batches, data["hists"], key_t,
-                                  *extra_args)
-        if tau:
-            past.append(params)
-        if agg.clustered:
-            if eval_exec is None:
-                with span("compile", engine="host", what="eval") as sp:
-                    eval_exec = eval_jit.lower(
-                        params, info["cluster_weights"]).compile()
-                compile_s += sp.duration_s
-            loss, m, acc_c, loss_c = eval_exec(params, info["cluster_weights"])
-            c_acc.append(np.asarray(acc_c, np.float32).tolist())
-            c_loss.append(np.asarray(loss_c, np.float32).tolist())
-            c_assign.append(np.asarray(info["cluster_assign"],
-                                       np.int32).tolist())
-        else:
-            ev_args = () if frozen is None else (frozen,)
-            if eval_exec is None:
-                with span("compile", engine="host", what="eval") as sp:
-                    eval_exec = eval_jit.lower(params, *ev_args).compile()
-                compile_s += sp.duration_s
-            loss, m = eval_exec(params, *ev_args)
-        ns, ms = float(info["num_selected"]), float(info["mask_sum"])
+        carry, out = step(carry, jnp.int32(t), *inputs)
+        main, tel = _split_telemetry(jax.device_get(out))
+        ns, ms = float(main[2]), float(main[3])
         assert ns == ms, (
             f"round {t}: selection budget violated — trained {ns} clients but "
             f"mask selects {ms}; a strategy's mask escaped its budget window")
-        if metrics:
-            if collector is None:
-                statics = {"num_classes": int(data["hists"].shape[1]),
-                           "n_clusters": agg.n_clusters}
-                collector = jax.jit(make_collector(metrics, statics))
-                if agg.clustered:
-                    prev_cent = jnp.zeros_like(info["cluster_centroids"])
-            dyn = {"hists": data["hists"], "mask": info["mask"],
-                   "params_old": params_old, "params_new": params}
-            if needs_norms:
-                dyn["client_update_norms"] = info["client_update_norms"]
-            dyn.update(info.get("counters", {}))
-            if agg.clustered:
-                dyn.update(assign=info["cluster_assign"],
-                           centroids=info["cluster_centroids"],
-                           prev_centroids=prev_cent)
-                prev_cent = info["cluster_centroids"]
-            for name, v in collector(dyn).items():
-                tel.setdefault(name, []).append(np.asarray(v))
-        hist_acc.append(float(m["accuracy"]))
-        hist_loss.append(float(loss))
-        hist_sel.append(float(info["num_selected"]))
+        ys.append((main, tel))
         if verbose:
-            print(f"  round {t + 1:3d}/{rounds}: acc={hist_acc[-1]:.4f} "
-                  f"loss={hist_loss[-1]:.4f} selected={hist_sel[-1]:.0f}")
-    wall_s = time.time() - t0 - compile_s
-    return FLHistory(hist_acc, hist_loss, hist_sel, wall_s,
-                     cluster_accuracy=c_acc if agg.clustered else None,
-                     cluster_loss=c_loss if agg.clustered else None,
-                     cluster_assign=c_assign if agg.clustered else None,
+            print(f"  round {t + 1:3d}/{rounds}: acc={float(main[0]):.4f} "
+                  f"loss={float(main[1]):.4f} selected={ns:.0f}")
+    wall_s = time.perf_counter() - t0
+
+    def column(i, dtype=np.float32):
+        return [np.asarray(main[i], dtype).tolist() for main, _ in ys]
+    clustered = resolve_aggregator(aggregation, fl_cfg).clustered
+    return FLHistory(column(0), column(1), column(2), wall_s,
+                     cluster_accuracy=column(4) if clustered else None,
+                     cluster_loss=column(5) if clustered else None,
+                     cluster_assign=column(6, np.int32) if clustered else None,
                      compile_s=compile_s,
-                     telemetry={n: np.stack(v) for n, v in tel.items()}
-                     if tel else None)
+                     telemetry={n: np.stack([tl[n] for _, tl in ys])
+                                for n in ys[0][1]}
+                     if ys and ys[0][1] else None)
 
 
 def success_rate(histories: List[FLHistory], threshold: float = 0.2) -> float:

@@ -69,6 +69,7 @@ sharded engine's delta-psum.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 import time
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
@@ -79,14 +80,17 @@ import numpy as np
 
 from repro.core import (STRATEGIES, get_aggregator, interpolate,
                         merge_label_statistics, partial_label_statistics,
-                        selection_budget, topk_by_score, two_tier_weighted_mean)
+                        topk_by_score, two_tier_weighted_mean)
 from repro.core.selection import NEG_INF
 from repro.data import client_batches
 from repro.kernels.dispatch import client_histograms, masked_weighted_mean
 from repro.obs import (collect_metrics, phase, record_memory_analysis,
                        resolve_metrics, resolve_telemetry_request, span)
-from repro.optim import apply_updates, get_optimizer
+from repro.optim import get_optimizer
 from .client import local_gradient, local_train
+from .round import client_update_step
+from .sharded import _static_budget
+from .sim import _ones_avail
 from .workloads import (Workload, get_workload, materialize_rows,
                         reject_frozen)
 
@@ -178,18 +182,6 @@ def _resolve_blocks(num_clients: int, options: Dict[str, Any]) -> Tuple[int, int
             f"num_blocks ({e}) must be a positive divisor of num_clients "
             f"({num_clients}) — every edge aggregator owns an equal block")
     return e, num_clients // e
-
-
-def _static_budget(strategy: str, num_clients: int, num_classes: int,
-                   n_select: int) -> int:
-    """The strategy's STATIC gather width, resolved from a dummy call.
-
-    Every builtin's declared budget is a shape-only fact (``_clamped`` /
-    the population size), so one call on a zeros histogram matrix pins it
-    without touching real data."""
-    r = STRATEGIES[strategy](jax.random.PRNGKey(0),
-                             jnp.zeros((num_clients, num_classes)), n_select)
-    return selection_budget(r, n_select, num_clients)
 
 
 # ---------------------------------------------------------------------------
@@ -285,8 +277,8 @@ def make_hier_trial_fn(fl_cfg, ds=None, *, strategy: str,
     _check_block_engine(agg, (strategy,), "hier", num_classes=n_classes)
     e_blocks, block_size = _resolve_blocks(
         n_clients, {} if num_blocks is None else {"num_blocks": num_blocks})
-    budget = _static_budget(strategy, n_clients, n_classes,
-                            fl_cfg.clients_per_round)
+    budget = _static_budget(STRATEGIES[strategy], fl_cfg.clients_per_round,
+                            n_clients, n_classes)
     num_rounds = fl_cfg.global_epochs if rounds is None else rounds
     opt = get_optimizer(fl_cfg.optimizer, fl_cfg.lr)
     loss_fn = wl.make_loss(ds)
@@ -326,31 +318,14 @@ def make_hier_trial_fn(fl_cfg, ds=None, *, strategy: str,
                 batches = client_batches(data, fl_cfg.batch_size,
                                          wl.batch_keys)
                 data_sel = jax.tree_util.tree_map(lambda x: x[ids], batches)
-                sizes = data_sel["valid"].reshape(budget, -1).sum(-1).astype(
-                    jnp.float32)
                 block_ids = ids // block_size
-            with phase("train"):
-                # fedsgd clients report gradients, fedavg clients weights.
-                if agg.base == "fedsgd":
-                    updates, _ = jax.vmap(
-                        lambda b: local_gradient(params, b, loss_fn))(data_sel)
-                else:
-                    updates, _ = jax.vmap(
-                        lambda b: local_train(params, opt, b, loss_fn,
-                                              fl_cfg.local_epochs))(data_sel)
-            with phase("aggregate"):
-                mean = two_tier_weighted_mean(updates, live, sizes, block_ids,
-                                              e_blocks)
-                if agg.base == "fedsgd":
-                    new_params = apply_updates(
-                        params,
-                        jax.tree_util.tree_map(lambda g: -fl_cfg.lr * g, mean))
-                else:
-                    new_params = interpolate(params, mean, fl_cfg.server_lr)
-                any_live = live.sum() > 0
-                new_params = jax.tree_util.tree_map(
-                    lambda new, old: jnp.where(any_live, new, old),
-                    new_params, params)
+            # The shared update with the flat mean replaced by the two-tier
+            # block mean (a reassociation of the same Σw·x / Σw).
+            two_tier = dataclasses.replace(
+                agg, reduce=lambda u, m, w: two_tier_weighted_mean(
+                    u, m, w, block_ids, e_blocks))
+            new_params, _ = client_update_step(params, data_sel, live, loss_fn,
+                                               opt, fl_cfg, two_tier)
             with phase("eval"):
                 ev_loss, ev_m = eval_fn(new_params, eval_batch)
             main = (ev_m["accuracy"], ev_loss, live.sum(), live.sum())
@@ -457,8 +432,8 @@ def make_async_trial_fn(fl_cfg, ds=None, *, strategy: str,
     # Block-local selection: each edge asks its own clients_per_round (capped
     # by the block), so K round-robin arrivals ≈ one flat round's budget.
     select = STRATEGIES[strategy]
-    blk_budget = _static_budget(strategy, block_size, n_classes,
-                                min(fl_cfg.clients_per_round, block_size))
+    blk_budget = _static_budget(select, min(fl_cfg.clients_per_round,
+                                            block_size), block_size, n_classes)
     opt = get_optimizer(fl_cfg.optimizer, fl_cfg.lr)
     loss_fn = wl.make_loss(ds)
     eval_batch = wl.eval_set(ds, eval_n_per_class)
@@ -615,10 +590,6 @@ def make_async_trial_fn(fl_cfg, ds=None, *, strategy: str,
 # ---------------------------------------------------------------------------
 # Engine registry bodies (registered by repro.fl.experiment)
 # ---------------------------------------------------------------------------
-
-def _ones_avail(plan: np.ndarray) -> jnp.ndarray:
-    return jnp.ones(plan.shape[:2], jnp.float32)
-
 
 def _run_cells(spec, lowered, make_trial, out_width: int,
                engine_label: str = "population"):

@@ -1,4 +1,5 @@
-"""One FL round (paper Algorithm 1), fully jitted.
+"""The round math of one FL round (paper Algorithm 1): local training,
+reduction and server update of the selected clients.
 
 Flow per round T:
   1. every client reports its label histogram → σ²(L_i) scalars (cheap),
@@ -11,25 +12,26 @@ Flow per round T:
   4. masked weighted aggregation (FedAvg Eq. 1 / Algorithm-1 uniform mean),
   5. server interpolates and broadcasts.
 
-Budget invariant (asserted by the host loop per round): every mask-selected
-client sits inside the gathered window, so ``num_selected == mask.sum()``.
+Steps 1–2 and eval live in the round step of repro.fl.sim
+(``make_round_step``), which every single-device driver runs.  Budget
+invariant (asserted by the sim and host drivers after execution): every
+mask-selected client sits inside the gathered window, so
+``num_selected == mask.sum()``.
 
 ``aggregation='fedsgd'`` switches clients to single-gradient reporting with a
 server-side SGD step (the paper's FedSGD baseline).
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.core import (Aggregator, cluster_counts, get_aggregator,
-                        get_strategy, interpolate, kmeans_cluster,
-                        selection_budget)
+from repro.core import Aggregator, get_aggregator, interpolate
 from repro.kernels.dispatch import masked_weighted_mean
 from repro.obs import phase
-from repro.optim import apply_updates, get_optimizer
+from repro.optim import apply_updates
 from .client import local_train, local_gradient
 from .workloads import with_frozen
 
@@ -116,9 +118,8 @@ def client_update_step(global_params: PyTree, data_sel: Dict[str, Array],
                        map_clients: bool = False
                        ) -> Tuple[PyTree, Dict[str, Array]]:
     """Local training + masked aggregation + server update for the selected
-    client subset — the round math shared verbatim by the jitted host round
-    (below) and the compiled simulator (repro.fl.sim), so a change here
-    cannot desynchronize the two engines.
+    client subset — the round math of the shared round step
+    (repro.fl.sim.make_round_step) and of the hier engine's rounds.
 
     Workload-agnostic: ``loss_fn`` and the ``data_sel`` payload come from the
     workload registry (repro.fl.workloads); the only leaf this round math
@@ -266,8 +267,8 @@ def clustered_update_step(global_stack: PyTree, cluster_sel: Array,
                           data_sel: Dict[str, Array], live: Array,
                           loss_fn, opt, fl_cfg, agg: Aggregator
                           ) -> Tuple[PyTree, Dict[str, Array]]:
-    """The clustered round math: per-cluster global models, shared by the
-    compiled simulator and the jitted host round (the sharded round reaches
+    """The clustered round math: per-cluster global models, run by the
+    shared round step (repro.fl.sim.make_round_step; the sharded round reaches
     the same numbers through its delta-psum form — Σw(θ'−θ_c)/Σw = θ̄_c − θ_c
     because every cluster member trains from the same θ_c).
 
@@ -316,119 +317,3 @@ def clustered_update_step(global_stack: PyTree, cluster_sel: Array,
                 new, old),
             new_stack, global_stack)
     return new_stack, m
-
-
-def make_fl_round(loss_fn, fl_cfg, strategy_name: str | None = None,
-                  aggregation: str | None = None, *,
-                  poison_scale: float | None = None,
-                  with_stale: bool = False,
-                  want_client_norms: bool = False,
-                  counters: Tuple[str, ...] = (),
-                  map_clients: bool = False) -> Callable:
-    """Build the jitted round function.
-
-    Returned signature: fl_round(global_params, round_batches, hists, key)
-        round_batches: leaves (N, n_batches, batch_size, ...)
-        hists: (N, C)
-    → (new_global_params, info dict)
-
-    Clustered families (``Aggregator.n_clusters > 1``) take and return the
-    (n_clusters, *params) stacked pytree instead (``stack_global_params``
-    builds the initial one) and add ``info["cluster_assign"]`` — the (N,)
-    round k-means assignment — and ``info["cluster_weights"]`` — the (M,)
-    valid-client population per cluster, the caller's eval mixture weights.
-
-    Adversary statics (see :func:`client_update_step`): ``poison_scale``
-    and/or ``with_stale=True`` extend the signature with trailing
-    ``(..., adv, stale_params)`` arguments — ``adv`` the (N,) byzantine
-    mask, ``stale_params`` the τ-rounds-old global tree the host loop keeps
-    (pass the current params for ``poison``-only runs).  Clustered families
-    reject engine-level behaviors (per-cluster byzantine semantics are a
-    follow-up; the plan-level ``label_flip`` attack composes with them
-    already).  ``want_client_norms`` adds ``info["client_update_norms"]``
-    — per-CLIENT as-reported update ℓ₂ norms scattered to (N,), zero for
-    unselected clients.  All three default off, compiling the identical
-    pre-adversary program.
-
-    A workload with a frozen part passes its base as the trailing
-    ``frozen`` argument, an input of the jitted round (never a constant of
-    it); ``counters`` and ``map_clients`` as in :func:`client_update_step`,
-    whose per-slot counts come back as ``info["counters"]``.
-    """
-    strategy = get_strategy(strategy_name or fl_cfg.selection)
-    agg = resolve_aggregator(aggregation, fl_cfg)
-    attacked = poison_scale is not None or with_stale
-    if attacked and agg.clustered:
-        raise ValueError(
-            "engine-level adversary behaviors (poison/stale_update) are not "
-            "defined for clustered aggregation families; use the plan-level "
-            "label_flip transform or a single-global-model aggregator")
-    if with_stale and agg.base == "fedsgd":
-        raise ValueError(
-            "stale_update needs a stale TRAINING base; the fedsgd family "
-            "reports one gradient at the current global, so the behavior is "
-            "undefined for it")
-    n_sel = fl_cfg.clients_per_round
-    opt = get_optimizer(fl_cfg.optimizer, fl_cfg.lr)
-
-    @jax.jit
-    def fl_round(global_params: PyTree, round_batches: Dict[str, Array],
-                 hists: Array, key: Array, adv: Array | None = None,
-                 stale_params: PyTree | None = None,
-                 frozen: PyTree | None = None
-                 ) -> Tuple[PyTree, Dict[str, Array]]:
-        with phase("select"):
-            sel = strategy(key, hists, n_sel)
-            # The gather width is the STRATEGY's static budget, not
-            # clients_per_round: "full" gathers the whole population, a wide
-            # registered strategy gathers its declared slot count
-            # untruncated.
-            budget = selection_budget(sel, n_sel, hists.shape[0])
-            idx = sel.order[:budget]                  # clients asked to train
-            live = sel.mask[idx]                      # 0 where count < budget
-            data_sel = jax.tree_util.tree_map(lambda x: x[idx], round_batches)
-        extra = {}
-        if agg.clustered:
-            with phase("cluster"):
-                assign, cent = kmeans_cluster(hists, agg.n_clusters,
-                                              n_iters=agg.kmeans_iters)
-            new_params, m = clustered_update_step(
-                global_params, assign[idx], data_sel, live, loss_fn, opt,
-                fl_cfg, agg)
-            valid = (hists.sum(-1) > 0).astype(jnp.float32)
-            extra = {"cluster_assign": assign,
-                     "cluster_centroids": cent,
-                     "cluster_weights": cluster_counts(assign, agg.n_clusters,
-                                                       weights=valid)}
-        else:
-            new_params, m = client_update_step(
-                global_params, data_sel, live, loss_fn, opt, fl_cfg, agg,
-                adv=None if adv is None else adv[idx],
-                poison_scale=poison_scale,
-                stale_params=stale_params if with_stale else None,
-                want_client_norms=want_client_norms, frozen=frozen,
-                counters=counters, map_clients=map_clients)
-            if counters:
-                extra = {"counters": {k: m[k] * _slot_bcast(live, m[k])
-                                      for k in counters if k in m}}
-            if want_client_norms:
-                extra["client_update_norms"] = (
-                    jnp.zeros(hists.shape[0], jnp.float32)
-                    .at[idx].set(m["update_norm"] * live))
-
-        info = {
-            **extra,
-            "selected": idx,
-            "live": live,
-            "mask": sel.mask,
-            "num_selected": live.sum(),
-            # mask.sum() must equal num_selected — the budget window covers
-            # every mask-selected client; run_fl_host asserts it per round.
-            "mask_sum": sel.mask.sum(),
-            "budget": jnp.int32(budget),
-            "client_loss": (m["loss"] * live).sum() / jnp.maximum(live.sum(), 1),
-            "scores": sel.scores,
-        }
-        return new_params, info
-
-    return fl_round

@@ -41,11 +41,9 @@ Each round:
      the compute dispatch (fused Pallas weighted-agg kernel on TPU).
 
 ``mode="masked"`` keeps the legacy masked-psum round (every client trains,
-the mask zeroes unselected contributions) as the measured baseline —
-``benchmarks/sharded_round.py`` pins the gather-based round's win whenever
-B < N and records both exchanges' wall-clock and bytes.
+the mask zeroes unselected contributions) as the comparison baseline.
 
-Numerics match the host round / compiled simulator: identical histograms →
+Numerics match the shared round step (repro.fl.sim): identical histograms →
 identical registry selection (same tie-breaking), identical ``local_step``
 math, and the weighted delta mean equals fedavg-then-interpolate
 algebraically, so host/sim/sharded trajectories agree to float tolerance
@@ -67,13 +65,13 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.clustering import cluster_counts, kmeans_cluster
-from repro.core.selection import (SelectFn, get_strategy,
-                                  selection_budget, topn_mask)
+from repro.core.selection import SelectFn, get_strategy, selection_budget
 from repro.core.aggregation import (exchange_selected_shards,
                                     gather_client_shards, interpolate,
                                     psum_weighted_mean)
 from repro.kernels.dispatch import client_histograms, weighted_sum_tree
 from repro.obs import phase
+from .round import _slot_bcast
 
 Array = jax.Array
 PyTree = Any
@@ -84,17 +82,6 @@ def shard_map(f, mesh, in_specs, out_specs):
     # all_gather whose replication the static checker cannot infer.
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                          check_vma=False)
-
-
-def topn_mask_from_scores(scores: Array, n_select: int) -> Array:
-    """Deterministic top-n 0/1 mask over gathered scores (σ² ≠ 0 gate).
-
-    Back-compat wrapper over the registry building block
-    (``repro.core.selection.topn_mask``) — the round itself now dispatches
-    through the strategy registry, so sharded selection shares the other
-    engines' tie-breaking by construction instead of re-implementing it."""
-    mask, _ = topn_mask(scores, scores > 0, n_select)
-    return mask
 
 
 def _static_budget(select_fn: SelectFn, n_select: int, num_clients: int,
@@ -112,11 +99,6 @@ def _static_budget(select_fn: SelectFn, n_select: int, num_clients: int,
                    jax.ShapeDtypeStruct((num_clients, num_classes),
                                         jnp.float32))
     return box["budget"]
-
-
-def _slot_bcast(v: Array, leaf: Array) -> Array:
-    """Broadcast a (S,) per-slot vector against a (S, ...) stacked leaf."""
-    return v.reshape(v.shape + (1,) * (leaf.ndim - 1))
 
 
 def make_sharded_fl_round(mesh: Mesh, client_axis: str,
@@ -198,8 +180,8 @@ def make_sharded_fl_round(mesh: Mesh, client_axis: str,
     psum pair matches fedavg.  Requires ``mode="gather"`` and a non-clustered
     family.
 
-    Adversary statics (mirror of :func:`repro.fl.round.make_fl_round`, both
-    default-off → the identical pre-adversary program): ``poison_scale``
+    Adversary statics (mirror of :func:`repro.fl.round.client_update_step`,
+    both default-off → the identical pre-adversary program): ``poison_scale``
     and/or ``with_stale=True`` extend the signature with a replicated (N,)
     0/1 ``adv`` byzantine-mask argument (and, for ``with_stale``, a
     ``stale_params`` tree sharded like ``params``): byzantine slots train
@@ -332,7 +314,7 @@ def make_sharded_fl_round(mesh: Mesh, client_axis: str,
             if with_stale:
                 # Byzantine slots train from the τ-rounds-old global tree
                 # the caller carries; honest slots from the current one —
-                # the same per-slot base jnp.where the host round builds.
+                # the same per-slot base jnp.where client_update_step builds.
                 a_bool = adv[my_slots] > 0
                 base = jax.tree_util.tree_map(
                     lambda gp, st: jnp.where(
@@ -348,7 +330,7 @@ def make_sharded_fl_round(mesh: Mesh, client_axis: str,
             if poison_scale is not None:
                 # Byzantine slots report base + s·(θ' − base) — with the
                 # fedsgd local_step (θ − lr·∇) and base = θ this is exactly
-                # the host round's scaled-gradient report, so one statement
+                # client_update_step's scaled-gradient report, so one statement
                 # covers both families.
                 s = float(poison_scale)
                 a = adv[my_slots].astype(jnp.float32)
@@ -452,8 +434,7 @@ def exchange_bytes_per_device(batch: Dict[str, Array], num_clients: int,
     the a2a psum_scatter as int8 — also 1 byte, so the modes' per-client
     bytes agree).  On a ring, ``allgather`` receives the other groups'
     ``N − N/G`` client shards; ``a2a`` (reduce-scatter over the B_pad slot
-    routing) moves ``B_pad − B_pad/G`` shards — O(B) instead of O(N), the
-    ``benchmarks/sharded_round.py`` receipt."""
+    routing) moves ``B_pad − B_pad/G`` shards — O(B) instead of O(N)."""
     if exchange not in ("a2a", "allgather"):
         raise ValueError(f"exchange must be 'a2a' or 'allgather'; "
                          f"got {exchange!r}")

@@ -1,25 +1,23 @@
 """Compiled multi-trial FL simulation engine: the whole experiment is ONE
 XLA program.
 
-The legacy host loop (repro.fl.loop.run_fl_host) drives every round from
-Python — per-round host↔device transfers, a fresh jit per trial — so a
-Table-I grid (cases × strategies × seeds) scales linearly in wall-clock with
-grid size.  Here the round loop is a ``jax.lax.scan`` (device-resident label
-plans → synthetic materialization → selection → vmapped local training →
-aggregation → eval, all folded into the carried state), selection strategies
-become a traced stack+index dispatch (a batchable axis over the requested
-strategy set), and the whole thing is ``jax.vmap``-ed over seeds ×
-strategies × cases.  One compile, zero host
-round-trips, the full grid in a single device launch:
+One FL round is defined once, here: :func:`make_round_step` builds the
+round step (materialize → select → budget gather → local training,
+reduction and server update → eval → metric state) and the carry it
+threads.  :func:`make_trial_fn` scans that step (``jax.lax.scan``) over the
+rounds of one trial, and the grid ``jax.vmap``-s the trial over seeds ×
+strategies × cases; selection strategies become a traced stack+index
+dispatch (a batchable axis over the requested strategy set).  One compile,
+zero host round-trips, the full grid in a single device launch:
 
     plans = stack_case_plans(CASES, cfg, seed0=0)          # (K, T, N, n)
     res = run_grid(plans, cfg, strategies=("random", "labelwise"),
                    seeds=range(5))                         # one compiled call
     res.accuracy            # (K, S, R, rounds) f32
 
-Per-trial key derivation, round math, and evaluation are bit-compatible with
-the host loop (same fold_in tree, same ops), so trajectories match within
-float tolerance — tests/test_fl_sim.py pins this parity.
+The host engine (repro.fl.loop.run_fl_host) compiles the same step and
+calls it once per round from Python, so the two engines differ only in who
+drives the rounds; tests/test_fl_sim.py pins that they agree.
 
 Scenario transforms compose: plans may carry −1 padding from
 ``quantity_skew`` / ``apply_availability`` (repro.core.noniid), and
@@ -43,7 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Sequence
+from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -146,6 +144,236 @@ def _select(sid: Array, key: Array, hists: Array, n_sel: int,
     return masks[sid], scores[sid], orders[sid], budget
 
 
+class RoundStep(NamedTuple):
+    """One FL round as a pure function of device arrays, built once from the
+    trace-time statics (:func:`make_round_step`).
+
+    ``init(key, plan_shape)`` builds the round's carry: the model (the
+    ``(n_clusters, *params)`` stack for a clustered family), with last
+    round's centroids beside it where a metric reads them, or the
+    stale-update ring where the adversary asks for one.  ``step(carry, t,
+    key, plan, sid, avail, adv, frozen) -> (carry, ys)`` runs round ``t``;
+    ``ys`` is the trajectory tuple ``(acc, loss, nsel, msum)`` (seven
+    entries for a clustered family, see :func:`make_trial_fn`), paired with
+    the ``{name: value}`` metric dict when telemetry resolved metrics.
+
+    The sim engine scans ``step`` inside one compiled trial; the host engine
+    (repro.fl.loop.run_fl_host) compiles it once and calls it per round."""
+    init: Callable
+    step: Callable
+    attacked: bool
+    workload: Workload
+
+
+def make_round_step(fl_cfg, ds=None, *,
+                    aggregation: Optional[str] = None,
+                    eval_n_per_class: int = 50,
+                    strategies: Optional[Sequence[str]] = None,
+                    workload: "str | Workload" = "cnn",
+                    telemetry: Sequence[str] = (),
+                    adversary: Optional[dict] = None) -> RoundStep:
+    """The one FL round every single-device driver runs: materialize (with
+    the availability weighting) → select (the strategy universe's traced
+    dispatch and the validity gate) → gather the strategy's budget → local
+    training, reduction and server update (``client_update_step``, or
+    ``clustered_update_step`` after the round's k-means) → eval → the
+    metric state.  Arguments as :func:`make_trial_fn`'s, which scans it."""
+    wl = get_workload(workload)
+    ds = wl.dataset(ds)
+    has_frozen = wl.frozen is not None
+    universe = (tuple(strategies) if strategies is not None
+                else registered_strategies())
+    for name in universe:
+        strategy_id(name)  # validate early: unknown names raise here
+    agg = resolve_aggregator(aggregation, fl_cfg)
+    poison_scale, tau = resolve_adversary(adversary)
+    attacked = poison_scale is not None or tau > 0
+    if attacked and agg.clustered:
+        raise ValueError(
+            "engine-level adversary behaviors (poison/stale_update) are not "
+            "defined for clustered aggregation families; use the plan-level "
+            "label_flip transform or a single-global-model aggregator")
+    if tau > 0 and agg.base == "fedsgd":
+        raise ValueError(
+            "stale_update needs a stale TRAINING base; the fedsgd family "
+            "reports one gradient at the current global, so the behavior is "
+            "undefined for it")
+    if has_frozen and agg.clustered:
+        raise ValueError(
+            f"workload {wl.name!r} has a frozen part; clustered aggregation "
+            "families train whole per-cluster models and do not run it")
+    n_sel = fl_cfg.clients_per_round
+    opt = get_optimizer(fl_cfg.optimizer, fl_cfg.lr)
+    loss_fn = wl.make_loss(ds)
+    eval_batch = wl.eval_set(ds, eval_n_per_class)
+    eval_fn = wl.make_eval(ds)
+    avail_keys = ["hists", "mask", "num_classes", "params_old", "params_new"]
+    if agg.clustered:
+        avail_keys += ["assign", "n_clusters", "centroids", "prev_centroids"]
+    else:
+        avail_keys += ["client_update_norms"] + list(wl.counters)
+    metrics = resolve_metrics(resolve_telemetry_request(telemetry), avail_keys)
+    # Only clustered centroid-drift needs last round's centroids in the
+    # carry; everything else observes the current round alone.
+    needs_prev = agg.clustered and any(
+        "prev_centroids" in m.requires for m in metrics)
+    # Per-client update norms are computed only when a resolved metric asks
+    # (the delta_outlier z-scores) — same gating rule as needs_prev, so
+    # telemetry off keeps the round bit-identical.
+    needs_norms = not agg.clustered and any(
+        "client_update_norms" in m.requires for m in metrics)
+
+    def init(key: Array, plan_shape: Sequence[int]):
+        params = wl.init(jax.random.fold_in(key, 1), ds)
+        if agg.clustered:
+            params = stack_global_params(params, agg.n_clusters)
+        if needs_prev:
+            # (M, C) zeros for round 0 — C via a shape-only materialize probe
+            # (trace-time, no FLOPs).
+            probe = jax.eval_shape(
+                lambda p: wl.materialize(ds, p, jax.random.PRNGKey(0)),
+                jax.ShapeDtypeStruct(tuple(plan_shape[1:]), jnp.int32))
+            return (params, jnp.zeros(
+                (agg.n_clusters, probe["hists"].shape[1]), jnp.float32))
+        if tau:
+            # stale_update ring: slot j holds the newest θ_{t'} with
+            # t' ≡ j (mod τ+1); every slot starts at θ₀ so reads before
+            # round τ see the init (a client can never be staler than the
+            # run is old).
+            return (params, jax.tree_util.tree_map(
+                lambda p: jnp.broadcast_to(p[None], (tau + 1,) + p.shape),
+                params))
+        return params
+
+    def step(carry, t: Array, key: Array, plan: Array, sid: Array,
+             avail: Array, adv: Optional[Array] = None,
+             frozen: Optional[PyTree] = None):
+        t_static = plan.shape[0]
+        prev_cent = ring = None
+        if needs_prev:
+            params, prev_cent = carry
+        elif tau:
+            params, ring = carry
+        else:
+            params = carry
+        with phase("materialize"):
+            kt = jax.random.fold_in(key, 1000 + t)
+            plan_t = jax.lax.dynamic_index_in_dim(plan, t % t_static, 0,
+                                                  keepdims=False)
+            avail_t = jax.lax.dynamic_index_in_dim(
+                avail, t % avail.shape[0], 0, keepdims=False)
+            data = wl.materialize(ds, plan_t, jax.random.fold_in(kt, 0))
+            # Availability is applied ONCE, here: a dark client reports an
+            # empty histogram, so every registry strategy's validity gate
+            # excludes it.
+            hists = data["hists"] * avail_t[:, None]
+            batches = client_batches(data, fl_cfg.batch_size, wl.batch_keys)
+        with phase("select"):
+            mask, scores, order, budget = _select(
+                sid, jax.random.fold_in(kt, 1), hists, n_sel, universe)
+            # Enforce the registry validity contract engine-side: a client
+            # with an empty (possibly availability-zeroed) histogram is
+            # never live, even under a strategy whose own gate forgot it —
+            # here the plan may be intact (mask-mode avail), so the dark
+            # client's data is real and training it would silently leak
+            # influence.
+            mask = mask * (hists.sum(-1) > 0)
+            idx = order[:budget]      # the strategy's static gather width
+            live = mask[idx]
+            data_sel = jax.tree_util.tree_map(lambda x: x[idx], batches)
+
+        def emit(new_params, main, cent=None, assign=None, norms=None,
+                 counts=None):
+            # Metric collection is additive: the trajectory tuple is
+            # untouched, the series ride alongside as a second ys leaf.
+            if needs_prev:
+                new_carry = (new_params, cent)
+            elif tau:
+                new_carry = (new_params, ring)
+            else:
+                new_carry = new_params
+            if not metrics:
+                return new_carry, main
+            state = {"hists": hists, "mask": mask,
+                     "num_classes": hists.shape[1],
+                     "params_old": params, "params_new": new_params}
+            if agg.clustered:
+                state.update(assign=assign, n_clusters=agg.n_clusters,
+                             centroids=cent, prev_centroids=prev_cent)
+            if needs_norms:
+                state["client_update_norms"] = norms
+            state.update(counts or {})
+            return new_carry, (main, collect_metrics(metrics, state))
+
+        if agg.clustered:
+            with phase("cluster"):
+                assign, cent = kmeans_cluster(hists, agg.n_clusters,
+                                              n_iters=agg.kmeans_iters)
+            new_params, m = clustered_update_step(
+                params, assign[idx], data_sel, live, loss_fn, opt, fl_cfg,
+                agg)
+            with phase("eval"):
+                loss_c, ev_m = jax.vmap(
+                    lambda p: eval_fn(p, eval_batch))(new_params)
+                acc_c = ev_m["accuracy"]
+                # The scalar trajectory is the mixture over per-cluster
+                # models, weighted by each cluster's VALID population (every
+                # client the round could have trained, not just the selected
+                # ones) — a single comparable number against the one-model
+                # baseline.
+                valid = (hists.sum(-1) > 0).astype(jnp.float32)
+                w = cluster_counts(assign, agg.n_clusters, weights=valid)
+                tot = jnp.maximum(w.sum(), 1.0)
+            return emit(new_params,
+                        ((acc_c * w).sum() / tot, (loss_c * w).sum() / tot,
+                         live.sum(), mask.sum(), acc_c, loss_c, assign),
+                        cent=cent, assign=assign)
+        stale = None
+        if tau:
+            # Write θ_t into its ring slot FIRST (so τ=0 degenerates to
+            # reading the current params), then read θ_{t−τ} (θ₀ before
+            # round τ — every unwritten slot still holds the init).
+            ring = jax.tree_util.tree_map(
+                lambda r, p: jax.lax.dynamic_update_index_in_dim(
+                    r, p, t % (tau + 1), 0), ring, params)
+            stale = jax.tree_util.tree_map(
+                lambda r: jax.lax.dynamic_index_in_dim(
+                    r, jnp.mod(t - tau, tau + 1), 0, keepdims=False),
+                ring)
+        new_params, m = client_update_step(
+            params, data_sel, live, loss_fn, opt, fl_cfg, agg,
+            adv=adv[idx] if attacked else None,
+            poison_scale=poison_scale, stale_params=stale,
+            want_client_norms=needs_norms, frozen=frozen,
+            counters=wl.counters, map_clients=wl.map_clients)
+        norms = None
+        if needs_norms:
+            norms = (jnp.zeros(hists.shape[0], jnp.float32)
+                     .at[idx].set(m["update_norm"] * live))
+        counts = {c: _slot_counts(m, c, live, params, data_sel, frozen)
+                  for c in wl.counters}
+
+        with phase("eval"):
+            ev_loss, ev_m = eval_fn(new_params, with_frozen(eval_batch, frozen))
+        return emit(new_params, (ev_m["accuracy"], ev_loss, live.sum(),
+                                 mask.sum()), norms=norms, counts=counts)
+
+    def _slot_counts(m, name, live, params, data_sel, frozen):
+        """Counter ``name`` per selected slot, zero for a dead slot.  The
+        round step reports every counter of the workload; a step put in its
+        place that reports none (a test's stand-in that leaves the state
+        unchanged) reads zeros."""
+        if name in m:
+            return m[name] * live.reshape((-1,) + (1,) * (m[name].ndim - 1))
+        batch = jax.tree_util.tree_map(lambda x: x[0, 0], data_sel)
+        one = jax.eval_shape(
+            lambda p, b: loss_fn(p, with_frozen(b, frozen))[1][name],
+            params, batch)
+        return jnp.zeros(live.shape + one.shape, jnp.float32)
+
+    return RoundStep(init, step, attacked, wl)
+
+
 def make_trial_fn(fl_cfg, ds=None, *,
                   aggregation: Optional[str] = None,
                   rounds: Optional[int] = None,
@@ -203,218 +431,29 @@ def make_trial_fn(fl_cfg, ds=None, *,
     ``counters`` become round state of the same names, (budget, …) per
     selected slot, for the telemetry metrics that read them.
     """
-    wl = get_workload(workload)
-    ds = wl.dataset(ds)
-    has_frozen = wl.frozen is not None
-    universe = (tuple(strategies) if strategies is not None
-                else registered_strategies())
-    for name in universe:
-        strategy_id(name)  # validate early: unknown names raise here
-    agg = resolve_aggregator(aggregation, fl_cfg)
-    poison_scale, tau = resolve_adversary(adversary)
-    attacked = poison_scale is not None or tau > 0
-    if attacked and agg.clustered:
-        raise ValueError(
-            "engine-level adversary behaviors (poison/stale_update) are not "
-            "defined for clustered aggregation families; use the plan-level "
-            "label_flip transform or a single-global-model aggregator")
-    if tau > 0 and agg.base == "fedsgd":
-        raise ValueError(
-            "stale_update needs a stale TRAINING base; the fedsgd family "
-            "reports one gradient at the current global, so the behavior is "
-            "undefined for it")
-    if has_frozen and agg.clustered:
-        raise ValueError(
-            f"workload {wl.name!r} has a frozen part; clustered aggregation "
-            "families train whole per-cluster models and do not run it")
-    n_sel = fl_cfg.clients_per_round
+    rs = make_round_step(fl_cfg, ds, aggregation=aggregation,
+                         eval_n_per_class=eval_n_per_class,
+                         strategies=strategies, workload=workload,
+                         telemetry=telemetry, adversary=adversary)
     # `is None`, not falsy-or: rounds=0 is a legitimate zero-round dry-run
     # (empty trajectories), not a request for the full schedule.
     num_rounds = fl_cfg.global_epochs if rounds is None else rounds
-    opt = get_optimizer(fl_cfg.optimizer, fl_cfg.lr)
-    loss_fn = wl.make_loss(ds)
-    eval_batch = wl.eval_set(ds, eval_n_per_class)
-    eval_fn = wl.make_eval(ds)
-    avail_keys = ["hists", "mask", "num_classes", "params_old", "params_new"]
-    if agg.clustered:
-        avail_keys += ["assign", "n_clusters", "centroids", "prev_centroids"]
-    else:
-        avail_keys += ["client_update_norms"] + list(wl.counters)
-    metrics = resolve_metrics(resolve_telemetry_request(telemetry), avail_keys)
-    # Only clustered centroid-drift needs last round's centroids in the scan
-    # carry; everything else observes the current round alone.
-    needs_prev = agg.clustered and any(
-        "prev_centroids" in m.requires for m in metrics)
-    # Per-client update norms are computed only when a resolved metric asks
-    # (the delta_outlier z-scores) — same gating rule as needs_prev, so
-    # telemetry off keeps the scan body bit-identical.
-    needs_norms = not agg.clustered and any(
-        "client_update_norms" in m.requires for m in metrics)
 
     def trial(plan: Array, sid: Array, seed: Array, avail: Array,
               adv: Optional[Array] = None, frozen: Optional[PyTree] = None):
-        if attacked and adv is None:
+        if rs.attacked and adv is None:
             raise ValueError("adversary behaviors requested at trial build "
                              "time need the (N,) adv mask as a 5th argument")
-        if has_frozen and frozen is None:
-            raise ValueError(f"workload {wl.name!r} has a frozen part: the "
-                             "trial needs its base as the 6th argument")
-        t_static = plan.shape[0]
+        if rs.workload.frozen is not None and frozen is None:
+            raise ValueError(f"workload {rs.workload.name!r} has a frozen "
+                             "part: the trial needs its base as the 6th "
+                             "argument")
         key = jax.random.PRNGKey(seed)
-        params = wl.init(jax.random.fold_in(key, 1), ds)
-        if agg.clustered:
-            params = stack_global_params(params, agg.n_clusters)
-        if needs_prev:
-            # (M, C) zeros for round 0 — C via a shape-only materialize probe
-            # (trace-time, no FLOPs).
-            probe = jax.eval_shape(
-                lambda p: wl.materialize(ds, p, jax.random.PRNGKey(0)),
-                jax.ShapeDtypeStruct(plan.shape[1:], jnp.int32))
-            carry0 = (params, jnp.zeros(
-                (agg.n_clusters, probe["hists"].shape[1]), jnp.float32))
-        elif tau:
-            # stale_update ring: slot j holds the newest θ_{t'} with
-            # t' ≡ j (mod τ+1); every slot starts at θ₀ so reads before
-            # round τ see the init (a client can never be staler than the
-            # run is old).
-            carry0 = (params, jax.tree_util.tree_map(
-                lambda p: jnp.broadcast_to(p[None], (tau + 1,) + p.shape),
-                params))
-        else:
-            carry0 = params
-
-        def round_body(carry, t):
-            prev_cent = ring = None
-            if needs_prev:
-                params, prev_cent = carry
-            elif tau:
-                params, ring = carry
-            else:
-                params = carry
-            with phase("materialize"):
-                # Same fold_in tree as the host loop — parity is bit-for-bit
-                # in the randomness, so trajectories differ only by op
-                # reordering.
-                kt = jax.random.fold_in(key, 1000 + t)
-                plan_t = jax.lax.dynamic_index_in_dim(plan, t % t_static, 0,
-                                                      keepdims=False)
-                avail_t = jax.lax.dynamic_index_in_dim(
-                    avail, t % avail.shape[0], 0, keepdims=False)
-                data = wl.materialize(ds, plan_t, jax.random.fold_in(kt, 0))
-                # Availability is applied ONCE, here: a dark client reports
-                # an empty histogram, so every registry strategy's validity
-                # gate excludes it.
-                hists = data["hists"] * avail_t[:, None]
-                batches = client_batches(data, fl_cfg.batch_size,
-                                         wl.batch_keys)
-            with phase("select"):
-                mask, scores, order, budget = _select(
-                    sid, jax.random.fold_in(kt, 1), hists, n_sel, universe)
-                # Enforce the registry validity contract engine-side: a
-                # client with an empty (possibly availability-zeroed)
-                # histogram is never live, even under a strategy whose own
-                # gate forgot it — here the plan may be intact (mask-mode
-                # avail), so the dark client's data is real and training it
-                # would silently leak influence.
-                mask = mask * (hists.sum(-1) > 0)
-                idx = order[:budget]      # the strategy's static gather width
-                live = mask[idx]
-                data_sel = jax.tree_util.tree_map(lambda x: x[idx], batches)
-
-            def emit(new_params, main, cent=None, assign=None, norms=None,
-                     counts=None):
-                # Metric collection is additive: the trajectory tuple is
-                # untouched, the series ride alongside as a second ys leaf.
-                if needs_prev:
-                    new_carry = (new_params, cent)
-                elif tau:
-                    new_carry = (new_params, ring)
-                else:
-                    new_carry = new_params
-                if not metrics:
-                    return new_carry, main
-                state = {"hists": hists, "mask": mask,
-                         "num_classes": hists.shape[1],
-                         "params_old": params, "params_new": new_params}
-                if agg.clustered:
-                    state.update(assign=assign, n_clusters=agg.n_clusters,
-                                 centroids=cent, prev_centroids=prev_cent)
-                if needs_norms:
-                    state["client_update_norms"] = norms
-                state.update(counts or {})
-                return new_carry, (main, collect_metrics(metrics, state))
-
-            if agg.clustered:
-                with phase("cluster"):
-                    assign, cent = kmeans_cluster(hists, agg.n_clusters,
-                                                  n_iters=agg.kmeans_iters)
-                new_params, m = clustered_update_step(
-                    params, assign[idx], data_sel, live, loss_fn, opt,
-                    fl_cfg, agg)
-                with phase("eval"):
-                    loss_c, ev_m = jax.vmap(
-                        lambda p: eval_fn(p, eval_batch))(new_params)
-                    acc_c = ev_m["accuracy"]
-                    # The scalar trajectory is the mixture over per-cluster
-                    # models, weighted by each cluster's VALID population
-                    # (every client the round could have trained, not just
-                    # the selected ones) — a single comparable number
-                    # against the one-model baseline.
-                    valid = (hists.sum(-1) > 0).astype(jnp.float32)
-                    w = cluster_counts(assign, agg.n_clusters, weights=valid)
-                    tot = jnp.maximum(w.sum(), 1.0)
-                return emit(new_params,
-                            ((acc_c * w).sum() / tot,
-                             (loss_c * w).sum() / tot,
-                             live.sum(), mask.sum(),
-                             acc_c, loss_c, assign),
-                            cent=cent, assign=assign)
-            stale = None
-            if tau:
-                # Write θ_t into its ring slot FIRST (so τ=0 degenerates to
-                # reading the current params), then read θ_{t−τ} (θ₀ before
-                # round τ — every unwritten slot still holds the init).
-                ring = jax.tree_util.tree_map(
-                    lambda r, p: jax.lax.dynamic_update_index_in_dim(
-                        r, p, t % (tau + 1), 0), ring, params)
-                stale = jax.tree_util.tree_map(
-                    lambda r: jax.lax.dynamic_index_in_dim(
-                        r, jnp.mod(t - tau, tau + 1), 0, keepdims=False),
-                    ring)
-            new_params, m = client_update_step(
-                params, data_sel, live, loss_fn, opt, fl_cfg, agg,
-                adv=adv[idx] if attacked else None,
-                poison_scale=poison_scale, stale_params=stale,
-                want_client_norms=needs_norms, frozen=frozen,
-                counters=wl.counters, map_clients=wl.map_clients)
-            norms = None
-            if needs_norms:
-                norms = (jnp.zeros(hists.shape[0], jnp.float32)
-                         .at[idx].set(m["update_norm"] * live))
-            counts = {c: _slot_counts(m, c, live, params, data_sel, frozen)
-                      for c in wl.counters}
-
-            with phase("eval"):
-                ev_loss, ev_m = eval_fn(new_params,
-                                        with_frozen(eval_batch, frozen))
-            return emit(new_params, (ev_m["accuracy"], ev_loss, live.sum(),
-                                     mask.sum()), norms=norms, counts=counts)
-
-        _, traj = jax.lax.scan(round_body, carry0, jnp.arange(num_rounds))
+        _, traj = jax.lax.scan(
+            lambda carry, t: rs.step(carry, t, key, plan, sid, avail, adv,
+                                     frozen),
+            rs.init(key, plan.shape), jnp.arange(num_rounds))
         return traj
-
-    def _slot_counts(m, name, live, params, data_sel, frozen):
-        """Counter ``name`` per selected slot, zero for a dead slot.  The
-        round step reports every counter of the workload; a step put in its
-        place that reports none (a test's stand-in that leaves the state
-        unchanged) reads zeros."""
-        if name in m:
-            return m[name] * live.reshape((-1,) + (1,) * (m[name].ndim - 1))
-        batch = jax.tree_util.tree_map(lambda x: x[0, 0], data_sel)
-        one = jax.eval_shape(
-            lambda p, b: loss_fn(p, with_frozen(b, frozen))[1][name],
-            params, batch)
-        return jnp.zeros(live.shape + one.shape, jnp.float32)
 
     @functools.wraps(trial)
     def traced_trial(*args, **kwargs):

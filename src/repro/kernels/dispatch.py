@@ -6,7 +6,7 @@ on) and the masked weighted mean of local models (FedAvg Eq. 1) — and the
 repo carries validated Pallas kernels for both (kernels/label_hist,
 kernels/weighted_agg).  This module is the trace-time switch that decides,
 per call, whether those ops lower to the Pallas kernels or to the pure-XLA
-references, so every engine (compiled sim grid, host parity oracle, sharded
+references, so every engine (compiled sim grid, host engine, sharded
 SPMD round) compiles the right implementation for the platform it runs on:
 
 * ``tpu`` — the Pallas kernels (``label_hist_kernel``,

@@ -15,7 +15,8 @@ pytestmark = pytest.mark.slow  # multi-minute tier; see tests/conftest.py
 from repro.configs.paper_cnn import FLConfig
 from repro.core import case_label_plan, bias_mix_plan
 from repro.data import ImageDataset
-from repro.fl import run_fl, make_sharded_fl_round, topn_mask_from_scores
+from repro.core.selection import topn_mask
+from repro.fl import run_fl, make_sharded_fl_round
 from repro.ckpt import save_checkpoint, load_checkpoint, latest_checkpoint
 
 SMALL = FLConfig(num_clients=16, clients_per_round=6, global_epochs=4,
@@ -67,7 +68,7 @@ class TestFLLoop:
 class TestShardedRound:
     def test_topn_mask(self):
         scores = jnp.array([0.5, 0.0, 2.0, 1.0])
-        mask = topn_mask_from_scores(scores, 2)
+        mask, _ = topn_mask(scores, scores > 0, 2)
         np.testing.assert_array_equal(np.asarray(mask), [0, 0, 1, 1])
 
     def test_sharded_round_matches_masked_mean(self):

@@ -1,9 +1,9 @@
 """Compiled-engine tests: host-loop parity, grid structure, scenario
 transforms threading.
 
-The parity test is the regression anchor for repro/fl/sim.py: the engine's
-lax.scan round loop must reproduce the legacy host loop's trajectories (same
-fold_in key tree, same round math) within float tolerance.
+The parity tests pin the two drivers of repro/fl/sim.py's round step: the
+engine's lax.scan round loop and the host engine's per-round calls must
+return the same trajectories within float tolerance.
 """
 import numpy as np
 import pytest
@@ -35,6 +35,26 @@ class TestEngineParity:
         np.testing.assert_allclose(sim.loss, host.loss, rtol=2e-4, atol=2e-5)
         np.testing.assert_allclose(sim.accuracy, host.accuracy, atol=5e-3)
         np.testing.assert_array_equal(sim.num_selected, host.num_selected)
+
+    def test_host_and_sim_run_one_round_step(self):
+        """Both engines look the update up in ``repro.fl.sim``: with it
+        patched to hand back the params it was given (``bench/faults.py``'s
+        ``state_unchanged``), neither engine trains, and both return the
+        untrained model's accuracy in every round."""
+        import repro.fl.sim as sim_mod
+        from unittest import mock
+        from repro.fl import ExperimentSpec, ScenarioSpec, run
+        scenario = ScenarioSpec.from_plan("micro", micro_plan())
+        out = {}
+        with mock.patch.object(sim_mod, "client_update_step",
+                               lambda params, *a, **k: (params, {})):
+            for engine in ("sim", "host"):
+                out[engine] = run(ExperimentSpec(
+                    scenarios=(scenario,), strategies=("labelwise",),
+                    seeds=(0,), fl=MICRO, eval_n_per_class=10,
+                    engine=engine)).accuracy[0, 0, 0]
+        assert (out["sim"] == out["sim"][0]).all(), out["sim"]
+        np.testing.assert_array_equal(out["host"], out["sim"])
 
     @pytest.mark.slow
     def test_run_fl_wrapper_delegates(self):

@@ -3,7 +3,7 @@ parity, budget invariants, and the engines' workload-agnosticism.
 
 The fast tier pins the acceptance contract for the registry subsystem: the
 ``lm`` workload (micro transformer over domain-skewed TokenDataset streams)
-runs through the compiled engine AND the host parity oracle with matching
+runs through the compiled engine AND the host engine with matching
 trajectories, and ``repro.fl.sim`` imports no model/dataset code — every
 workload reaches the engines through the registry alone.
 """
@@ -97,7 +97,7 @@ class TestEnginesAreWorkloadAgnostic:
 class TestLMEngineParity:
     def test_lm_host_sim_trajectory_parity(self):
         """Acceptance pin: workload='lm' through the compiled lax.scan engine
-        reproduces the host parity oracle's trajectories (same fold_in tree,
+        reproduces the host engine's trajectories (same fold_in tree,
         same transformer round math)."""
         plan = micro_plan("iid")
         host = run_fl_host(plan, MICRO, strategy="labelwise", workload="lm",
