@@ -387,6 +387,7 @@ def _metric_state(num_clients: int, num_classes: int, n_clusters: int,
                                                     jnp.float32),
         # (slots, layers, held experts + 1): a workload counter
         "expert_load": jax.ShapeDtypeStruct((4, 2, 3), jnp.float32),
+        "expert_rows_run": jax.ShapeDtypeStruct((4, 2), jnp.float32),
     }
     return dyn
 

@@ -479,7 +479,8 @@ def granite_lora_workload(cfg: ModelConfig, *, base_seed: int,
     cross-entropy over the valid sequences (the router is frozen and its
     load-balance term weighs 0), and reports ``expert_load``, the tokens
     routed to each held expert of each layer and the held assignments
-    dropped, as ``moe_held_apply`` counts them.  Eval is loss and top-1
+    dropped, and ``expert_rows_run``, the rows each layer's dispatch ran, as
+    ``moe_held_apply`` counts them.  Eval is loss and top-1
     next-token accuracy on ``n_per_class`` held-out sequences per domain,
     one sequence at a time.  The loss, its gradient and the eval run every
     matmul in full float32 (on a TPU the default rounds the operands to
@@ -509,7 +510,7 @@ def granite_lora_workload(cfg: ModelConfig, *, base_seed: int,
                     adapters, batch[FROZEN_KEY], toks)
                 with scope(cfg, "loss"):
                     ce, m = token_ce(logits, _lm_targets(toks, batch["valid"]))
-            return ce, {"ce": ce, "ntok": m["ntok"], "expert_load": loads}
+            return ce, {"ce": ce, "ntok": m["ntok"], **loads}
         return loss
 
     def make_eval(ds: TokenDataset) -> EvalFn:
@@ -544,7 +545,7 @@ def granite_lora_workload(cfg: ModelConfig, *, base_seed: int,
         batch_keys=("tokens", "labels", "valid"),
         num_classes=lambda ds: ds.num_domains,
         frozen=lambda ds: granite_base(cfg, base_seed, embed_std),
-        counters=("expert_load",),
+        counters=("expert_load", "expert_rows_run"),
         # Four silos side by side need 21.18 GB of a chip's 15.75 GiB at the
         # granite cut's widths (compile rehearsal for a TPU v5e).
         map_clients=True,

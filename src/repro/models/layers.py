@@ -496,9 +496,10 @@ def grouped_matmul(x: Array, w: Array, sizes: Array) -> Array:
     """Rows of x (M, K) sorted by group, ``sizes[g]`` rows of group g, each
     times its group's w[g] (G, K, N) → (M, N); rows past the groups are 0,
     and so are their rows of the input gradient.  The work scales with the
-    rows in the groups.  The backward runs at the matmul precision in force
-    where this is called (``jax.default_matmul_precision``), as the forward
-    does."""
+    rows in the groups.  The held experts' dispatch (:func:`moe_held_apply`)
+    runs it on one block of rows at a time, with the groups clipped to the
+    block.  The backward runs at the matmul precision in force where this is
+    called (``jax.default_matmul_precision``), as the forward does."""
     return _grouped(x, w, sizes, jax.config.jax_default_matmul_precision)
 
 
@@ -534,31 +535,140 @@ def _grouped_bwd(precision, res, dy):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+def dispatch_block_rows(tokens: int, k: int, experts_held: int,
+                        num_experts: int) -> int:
+    """Rows one block of the held experts' dispatch runs: the largest
+    divisor of the most a call can route to the held experts (tokens ×
+    min(k, held)) that is at most an eighth of what a call routes there on
+    average (tokens · k · held / experts).  The blocks tile that most, so a
+    call never runs more rows than it; and a call runs ceil(routed / block)
+    blocks, fewer than one block's rows past the routed."""
+    most = tokens * min(k, experts_held)
+    eighth = max(1, tokens * k * experts_held // (8 * num_experts))
+    return max(r for r in range(1, min(eighth, most) + 1) if most % r == 0)
+
+
+def _block_groups(counts: Array, lo: Array, rb: int) -> Array:
+    """The rows of each group (``counts[g]`` rows of group g, in order) that
+    fall in the block of rows [lo, lo + rb)."""
+    ends = jnp.cumsum(counts)
+    inside = jnp.minimum(ends, lo + rb) - jnp.maximum(ends - counts, lo)
+    return jnp.maximum(inside, 0)
+
+
+def _held_dispatch(cfg: ModelConfig, rb: int):
+    """``dispatch(xt, ws, tok, gate, counts) → y``: into each token's row of
+    y (t, d), the gate-weighted outputs of the held experts ``ws`` =
+    (w_gate, w_up, w2) on the rows routed to them.  Row r of the sorted
+    assignments is token ``tok[r]`` with gate ``gate[r]``; the first
+    ``counts[0]`` rows are expert 0's, the next ``counts[1]`` expert 1's, and
+    so on.  The rows run in blocks of ``rb``, and only the ceil(Σ counts /
+    rb) blocks that hold routed rows run: each gathers its rows' tokens, runs
+    the experts as :func:`grouped_matmul` with the groups clipped to the
+    block, and adds its gate-weighted rows to y.  A loop of a data-dependent
+    trip count has no reverse-mode derivative, so the backward is the same
+    loop, at the matmul precision of the forward.  A vmapped batch runs its
+    members one after another: batched, each member's loop would run the
+    batch's longest trip under selects over the whole of y."""
+    act = jax.nn.silu if cfg.activation != "gelu_glu" else jax.nn.gelu
+    precision = jax.config.jax_default_matmul_precision
+
+    def rows(xs, gb, ws, sizes):
+        wg, wu, w2 = ws
+        with scope(cfg, "moe.experts"):
+            h = act(grouped_matmul(xs, wg, sizes)) * grouped_matmul(xs, wu, sizes)
+            return grouped_matmul(h, w2, sizes) * gb[:, None].astype(h.dtype)
+
+    def over_blocks(tok, gate, counts, body, init):
+        """``body(lo, tb, gb, sizes, carry)`` over the blocks that hold
+        routed rows, ``lo`` a block's first row, ``tb``/``gb`` its rows'
+        tokens and gates, ``sizes`` the groups clipped to it."""
+        def step(j, carry):
+            lo = j * rb
+            tb = jax.lax.dynamic_slice_in_dim(tok, lo, rb)
+            gb = jax.lax.dynamic_slice_in_dim(gate, lo, rb)
+            return body(lo, tb, gb, _block_groups(counts, lo, rb), carry)
+        return jax.lax.fori_loop(0, -(-counts.sum() // rb), step, init)
+
+    @jax.custom_batching.sequential_vmap
+    def forward(xt, ws, tok, gate, counts):
+        def body(lo, tb, gb, sizes, y):
+            with scope(cfg, "moe.route"):
+                xs = xt[tb]
+            ys = rows(xs, gb, ws, sizes)
+            with scope(cfg, "moe.route"):
+                return y.at[tb].add(ys)
+        return over_blocks(tok, gate, counts, body, jnp.zeros_like(xt))
+
+    @jax.custom_batching.sequential_vmap
+    def input_grads(xt, ws, tok, gate, counts, dy):
+        def body(lo, tb, gb, sizes, carry):
+            dx, dgate = carry
+            with scope(cfg, "moe.route"):
+                xs, dys = xt[tb], dy[tb]
+            _, vjp = jax.vjp(lambda xs, gb: rows(xs, gb, ws, sizes), xs, gb)
+            dxs, dgb = vjp(dys)
+            with scope(cfg, "moe.route"):
+                return (dx.at[tb].add(dxs),
+                        jax.lax.dynamic_update_slice_in_dim(dgate, dgb, lo, 0))
+        return over_blocks(tok, gate, counts, body,
+                           (jnp.zeros_like(xt), jnp.zeros_like(gate)))
+
+    # A loop of its own, so that where nothing reads the experts' gradient
+    # (the frozen base) XLA removes the whole loop.
+    @jax.custom_batching.sequential_vmap
+    def weight_grads(xt, ws, tok, gate, counts, dy):
+        def body(lo, tb, gb, sizes, dws):
+            _, vjp = jax.vjp(lambda ws: rows(xt[tb], gb, ws, sizes), ws)
+            return jax.tree_util.tree_map(jnp.add, dws, vjp(dy[tb])[0])
+        return over_blocks(tok, gate, counts, body,
+                           jax.tree_util.tree_map(jnp.zeros_like, ws))
+
+    @jax.custom_vjp
+    def dispatch(xt, ws, tok, gate, counts):
+        return forward(xt, ws, tok, gate, counts)
+
+    def fwd(xt, ws, tok, gate, counts):
+        return forward(xt, ws, tok, gate, counts), (xt, ws, tok, gate, counts)
+
+    def bwd(res, dy):
+        with _at_precision(precision):
+            dx, dgate = input_grads(*res, dy)
+            dws = weight_grads(*res, dy)
+        return dx, dws, None, dgate, None
+
+    dispatch.defvjp(fwd, bwd)
+    return dispatch
+
+
 def moe_held_apply(p: PyTree, x: Array, cfg: ModelConfig
-                   ) -> Tuple[Array, Array, Array]:
+                   ) -> Tuple[Array, Array, Dict[str, Array]]:
     """This chip's share of a top-k MoE, dropless.  x: (B, S, d) →
-    (y, aux_loss, load).
+    (y, aux_loss, counts).
 
     The router scores all ``num_experts`` and keeps the top
     ``experts_per_token`` (softmax, top-k, renormalised: the same weights as
     a softmax over the top-k logits).  ``p``'s expert weights are the
     ``experts_held`` experts from ``expert_offset`` on.  The assignments that
-    fall on them are sorted by expert and run as one grouped matmul over
-    their rows (:func:`grouped_matmul`), so the expert work scales with the
-    tokens routed here; the gate-weighted rows are added back to their
-    tokens.  An assignment to an expert not held contributes nothing here.
+    fall on them are sorted by expert, and the experts run on their rows in
+    blocks of :func:`dispatch_block_rows` rows, only the blocks that hold
+    routed rows, as grouped matmuls (:func:`grouped_matmul`); the
+    gate-weighted rows are added back to their tokens.  So the expert work
+    and the rows gathered and scattered scale with the assignments routed
+    here, and no held assignment is ever dropped, however the router routes.
+    An assignment to an expert not held contributes nothing here.
 
-    The row buffer holds ``tokens × min(k, experts_held)`` rows, the most a
-    call can route to the held experts, so no held assignment is ever
-    dropped.  ``load`` is (experts_held + 1,) f32: the assignments routed to
-    each held expert, then the held assignments dropped (0)."""
+    ``counts["expert_load"]`` is (experts_held + 1,) f32: the assignments
+    routed to each held expert, then the held assignments dropped (0: the
+    blocks run cover every routed row).
+    ``counts["expert_rows_run"]`` is the rows the dispatch ran (f32 scalar):
+    the blocks run × the rows of a block."""
     b, s, d = x.shape
     e, k, eh, off = (cfg.num_experts, cfg.experts_per_token, cfg.experts_held,
                      cfg.expert_offset)
     t = b * s
-    rows = t * min(k, eh)
+    rb = dispatch_block_rows(t, k, eh, e)
     xt = x.reshape(t, d)
-    act = jax.nn.silu if cfg.activation != "gelu_glu" else jax.nn.gelu
     with scope(cfg, "moe.route"):
         logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"])
         probs = jax.nn.softmax(logits, axis=-1)
@@ -571,22 +681,18 @@ def moe_held_apply(p: PyTree, x: Array, cfg: ModelConfig
         held = (local >= 0) & (local < eh)
         # Held assignments first, by expert; the rest sort past them (key eh).
         key = jnp.where(held, local, eh).astype(jnp.int32)
-        order = jnp.argsort(key, stable=True)[:rows]
-        se = key[order]
-        live = se < eh
-        st = order // k                                           # token of each row
-        sg = jnp.where(live, gate_vals.reshape(-1)[order], 0.0)
+        order = jnp.argsort(key, stable=True)
         counts = jnp.zeros((eh + 1,), jnp.int32).at[key].add(1)[:eh]
-        xs = xt[st]
+        tok = order // k                                          # token of each row
+        gate = gate_vals.reshape(-1)[order]
     with scope(cfg, "moe.experts"):
-        g = grouped_matmul(xs, p["w_gate"].astype(xs.dtype), counts)
-        u = grouped_matmul(xs, p["w_up"].astype(xs.dtype), counts)
-        ys = grouped_matmul(act(g) * u, p["w2"].astype(xs.dtype), counts)
-    with scope(cfg, "moe.route"):
-        y = jnp.zeros((t, d), ys.dtype).at[st].add(ys * sg[:, None].astype(ys.dtype))
-        dropped = held.sum() - live.sum()
-        load = jnp.concatenate([counts, dropped[None]]).astype(jnp.float32)
-    return y.reshape(b, s, d).astype(x.dtype), aux, load
+        ws = tuple(p[w].astype(xt.dtype) for w in ("w_gate", "w_up", "w2"))
+        y = _held_dispatch(cfg, rb)(xt, ws, tok, gate, counts)
+    # The blocks run cover every routed row: no held assignment is dropped.
+    load = jnp.concatenate([counts, jnp.zeros((1,), counts.dtype)])
+    return y.reshape(b, s, d).astype(x.dtype), aux, {
+        "expert_load": load.astype(jnp.float32),
+        "expert_rows_run": (-(-counts.sum() // rb) * rb).astype(jnp.float32)}
 
 
 # ---------------------------------------------------------------------------

@@ -69,12 +69,12 @@ def block_apply(p: PyTree, x: Array, cfg: ModelConfig, kind: Tuple[str, str], *,
                 enc_kv: Optional[Tuple[Array, Array]] = None,
                 window: int = 0, pos_offset: Array | int = 0,
                 bidirectional: bool = False):
-    """Returns (x, new_cache, aux_loss, load): ``load`` is the block's
-    expert-load counts (``moe_held_apply``'s; an empty array where the block
-    holds no share of experts)."""
+    """Returns (x, new_cache, aux_loss, counts): ``counts`` is the block's
+    expert counters (``moe_held_apply``'s; empty where the block holds no
+    share of experts)."""
     mixer, ffn = kind
     aux = jnp.zeros((), jnp.float32)
-    load = jnp.zeros((0,), jnp.float32)
+    counts = {}
     h = L.rmsnorm_apply(p["mixer_norm"], x, cfg.norm_eps)
     if mixer == "attn":
         if bidirectional:
@@ -101,7 +101,7 @@ def block_apply(p: PyTree, x: Array, cfg: ModelConfig, kind: Tuple[str, str], *,
         h2 = L.rmsnorm_apply(p["ffn_norm"], x, cfg.norm_eps)
         if ffn in ("moe", "moe+dense"):
             if cfg.experts_held:
-                mo, aux, load = L.moe_held_apply(p["moe"], h2, cfg)
+                mo, aux, counts = L.moe_held_apply(p["moe"], h2, cfg)
             else:
                 mo, aux = L.moe_apply(p["moe"], h2, cfg)
             if ffn == "moe+dense":
@@ -111,7 +111,7 @@ def block_apply(p: PyTree, x: Array, cfg: ModelConfig, kind: Tuple[str, str], *,
         else:
             x = _residual(x, L.mlp_apply(p["mlp"], h2, cfg), cfg)
         x = sh.constrain(x, sh.BATCH, sh.SEQ, None)
-    return x, new_cache, aux, load
+    return x, new_cache, aux, counts
 
 
 def block_cache_init(cfg: ModelConfig, kind: Tuple[str, str], batch: int,
@@ -213,11 +213,12 @@ def _constrain_sliced_blocks(blocks: PyTree, cfg: ModelConfig) -> PyTree:
 
 def stack_apply_train(params: PyTree, x: Array, cfg: ModelConfig,
                       window: int = 0
-                      ) -> Tuple[Array, Array, Optional[Array]]:
+                      ) -> Tuple[Array, Array, Optional[Dict[str, Array]]]:
     """Training/scoring forward through all blocks.  Returns (x, aux_total,
-    loads): ``loads`` (num_layers, experts_held + 1) stacks each block's
-    expert-load counts (``block_apply``'s), None where the model holds no
-    share of experts.  Under ``cfg.remat`` a scanned period is one
+    loads): ``loads`` stacks each block's expert counters (``block_apply``'s)
+    on a leading layer axis: ``expert_load`` (num_layers, experts_held + 1),
+    ``expert_rows_run`` (num_layers,); None where the model holds no share
+    of experts.  Under ``cfg.remat`` a scanned period is one
     checkpoint, and unstacked blocks are one checkpoint each."""
     period_kinds, p, reps = stack_plan(cfg)
 
@@ -234,7 +235,8 @@ def stack_apply_train(params: PyTree, x: Array, cfg: ModelConfig,
             aux = aux + a
             loads.append(load)
         x = sh.constrain(x, sh.BATCH, sh.RESIDUAL_SEQ, None)
-        return x, aux, (jnp.stack(loads) if cfg.experts_held else None)
+        return x, aux, (jax.tree_util.tree_map(lambda *c: jnp.stack(c), *loads)
+                        if cfg.experts_held else None)
 
     def block(bp, x, kind):
         x, _, a, load = block_apply(bp, x, cfg, kind, mode="train", window=window)
@@ -265,8 +267,8 @@ def stack_apply_train(params: PyTree, x: Array, cfg: ModelConfig,
 
     (x, aux), loads = jax.lax.scan(scan_fn, (x, jnp.zeros((), jnp.float32)),
                                    params["blocks"])
-    if loads is not None:
-        loads = loads.reshape((-1,) + loads.shape[2:])
+    loads = jax.tree_util.tree_map(
+        lambda c: c.reshape((-1,) + c.shape[2:]), loads)
     return x, aux, loads
 
 
@@ -401,9 +403,9 @@ def _unembed(params: PyTree, cfg: ModelConfig, x: Array) -> Array:
 
 
 def forward_loads(params: PyTree, cfg: ModelConfig, batch: Dict[str, Array]
-                  ) -> Tuple[Array, Array, Optional[Array]]:
-    """Full-sequence logits (training/scoring) with the blocks' expert-load
-    counts.  Returns (logits, aux, loads), ``loads`` as
+                  ) -> Tuple[Array, Array, Optional[Dict[str, Array]]]:
+    """Full-sequence logits (training/scoring) with the blocks' expert
+    counters.  Returns (logits, aux, loads), ``loads`` as
     :func:`stack_apply_train` gives them (None for an encoder-decoder)."""
     x = _embed_inputs(params, cfg, batch)
     loads = None

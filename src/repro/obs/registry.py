@@ -36,6 +36,9 @@ int (shapes):
                     tokens its local training routed to each held expert
                     of each layer, then the held assignments dropped (a
                     workload's ``counters``; zero for a dead slot)
+``expert_rows_run`` (budget, layers) f32: per selected slot, the rows each
+                    layer's expert dispatch ran over its local training
+                    (a workload's ``counters``; zero for a dead slot)
 ==================  =======================================================
 
 A metric declares ``requires`` — the state keys it reads; an engine collects
@@ -273,6 +276,13 @@ def _expert_load(state: Mapping[str, Any]) -> Array:
     return state["expert_load"]
 
 
+def _expert_rows_run(state: Mapping[str, Any]) -> Array:
+    """(budget, layers): the rows each layer's expert dispatch ran over the
+    round's local training, per client, as the workload's loss counted
+    them."""
+    return state["expert_rows_run"]
+
+
 register_metric("selection_entropy", _selection_entropy,
                 requires=("hists", "mask"))
 register_metric("selected_label_hist", _selected_label_hist,
@@ -289,3 +299,5 @@ register_metric("delta_outlier", _delta_outlier,
                 requires=("client_update_norms", "mask"), axes=("client",))
 register_metric("expert_load", _expert_load, requires=("expert_load",),
                 axes=("slot", "layer", "expert"))
+register_metric("expert_rows_run", _expert_rows_run,
+                requires=("expert_rows_run",), axes=("slot", "layer"))

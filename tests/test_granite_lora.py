@@ -72,7 +72,7 @@ def test_a_share_counts_its_assignments_and_drops_none(offset):
     cfg = dataclasses.replace(TINY, expert_offset=offset)
     p, _ = L.moe_init(jax.random.PRNGKey(1), cfg)
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 32, cfg.d_model))
-    _, _, load = L.moe_held_apply(p, x, cfg)
+    load = L.moe_held_apply(p, x, cfg)[2]["expert_load"]
     logits = x.reshape(-1, cfg.d_model) @ p["router"]
     _, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), cfg.experts_per_token)
     want = [(np.asarray(idx) == offset + e).sum() for e in range(2)]
@@ -100,6 +100,151 @@ def test_the_shares_add_up_to_the_uncut_layer():
             got = got + L.moe_held_apply(share, x, cfg)[0]
     # float32 sums in another order: a few ulps of outputs of size ~1.
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def full_buffer_dispatch(p, x, cfg):
+    """The held experts' layer output as one pass over a row buffer sized
+    for the most a call can route to them, tokens × min(k, held) rows: the
+    reference the blocked dispatch is held to."""
+    b, s, d = x.shape
+    e, k, eh, off = (cfg.num_experts, cfg.experts_per_token, cfg.experts_held,
+                     cfg.expert_offset)
+    t = b * s
+    xt = x.reshape(t, d)
+    probs = jax.nn.softmax(xt @ p["router"], axis=-1)
+    gate_vals, gate_idx = jax.lax.top_k(probs, k)
+    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    local = gate_idx.reshape(-1) - off
+    held = (local >= 0) & (local < eh)
+    key = jnp.where(held, local, eh).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True)[:t * min(k, eh)]
+    st = order // k
+    sg = jnp.where(key[order] < eh, gate_vals.reshape(-1)[order], 0.0)
+    counts = jnp.zeros((eh + 1,), jnp.int32).at[key].add(1)[:eh]
+    xs = xt[st]
+    g = L.grouped_matmul(xs, p["w_gate"], counts)
+    u = L.grouped_matmul(xs, p["w_up"], counts)
+    ys = L.grouped_matmul(jax.nn.silu(g) * u, p["w2"], counts)
+    return jnp.zeros((t, d)).at[st].add(ys * sg[:, None]).reshape(b, s, d)
+
+
+# 1,024 tokens a call, 2 of 8 experts held from 2 on, top-3: blocks of 64
+# rows, up to 32 of them.
+DISPATCH = dataclasses.replace(TINY, expert_offset=2, dtype="float32")
+
+
+def routed(routing):
+    """Two calls' inputs (2, 1, 1024, d) and the layer's weights, with the
+    router biased through two constant features of x to route
+    ``routing``: "random" as drawn; "all_held", every token's top-k on both
+    held experts (the most a call can route there, every block run);
+    "none_held", nothing to them (no block run); "straddling", held expert
+    0 takes the first 1.5 blocks' tokens and no others, so its group ends
+    inside a block and expert 1's starts there."""
+    cfg, held = DISPATCH, slice(2, 4)
+    p, _ = L.moe_init(jax.random.PRNGKey(1), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 1, 1024, cfg.d_model))
+    if routing == "random":
+        return p, x
+    x = x.at[..., 0].set(1.0).at[..., 1].set(0.0)
+    r = p["router"]
+    if routing == "all_held":
+        r = r.at[0, held].add(100.0)
+    elif routing == "none_held":
+        r = r.at[0, held].add(-100.0)
+    else:
+        rb = L.dispatch_block_rows(1024, cfg.experts_per_token, 2, 8)
+        x = x.at[..., :rb + rb // 2, 1].set(1.0)
+        r = r.at[0, 2].add(-100.0).at[1, 2].add(200.0)
+    return dict(p, router=r), x
+
+
+ROUTINGS = ("random", "all_held", "none_held", "straddling")
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_the_blocked_dispatch_is_the_full_buffer_dispatch(routing):
+    """Forward, input gradient and the experts' gradient of the blocked
+    dispatch against one pass over the static worst-case row buffer, under
+    vmap and remat, at the highest precision."""
+    cfg = DISPATCH
+    p, x = routed(routing)
+    c = jax.random.normal(jax.random.PRNGKey(3), x.shape[1:])
+
+    def grads(layer):
+        def loss(x, p):
+            return (jax.checkpoint(layer)(p, x) * c).sum()
+        return jax.jit(jax.vmap(jax.value_and_grad(loss, argnums=(0, 1)),
+                                in_axes=(0, None)))
+    with jax.default_matmul_precision("highest"):
+        y = jax.vmap(lambda x: L.moe_held_apply(p, x, cfg)[0])(x)
+        want_y = jax.vmap(lambda x: full_buffer_dispatch(p, x, cfg))(x)
+        got = grads(lambda p, x: L.moe_held_apply(p, x, cfg)[0])(x, p)
+        want = grads(lambda p, x: full_buffer_dispatch(p, x, cfg))(x, p)
+    if routing == "none_held":
+        np.testing.assert_array_equal(y, 0)
+    # The same rows and float32 matmuls; the loop adds each block's rows
+    # into y, the input gradient and the experts' gradient, where one pass
+    # adds all rows at once: sums in another order, a few float32 ulps of
+    # the largest entry.
+    eps = np.finfo(np.float32).eps
+    for a, b in zip(jax.tree_util.tree_leaves((y, got)),
+                    jax.tree_util.tree_leaves((want_y, want))):
+        np.testing.assert_allclose(a, b, rtol=0,
+                                   atol=8 * eps * float(np.abs(b).max()))
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_the_dispatch_runs_whole_blocks_over_the_routed_rows(routing):
+    """``expert_rows_run`` is the block's rows × ceil(routed / block): never
+    fewer than the rows routed to the held experts, never more than the
+    most a call can route there."""
+    cfg = DISPATCH
+    p, x = routed(routing)
+    rb = L.dispatch_block_rows(1024, cfg.experts_per_token, 2, 8)
+    most = 1024 * min(cfg.experts_per_token, 2)
+    assert most % rb == 0 and rb < most
+    for xi in x:
+        counts = L.moe_held_apply(p, xi, cfg)[2]
+        routed_rows = int(counts["expert_load"][:-1].sum())
+        run = int(counts["expert_rows_run"])
+        assert run == rb * -(-routed_rows // rb)
+        assert routed_rows <= run <= most
+        assert counts["expert_load"][-1] == 0
+        want = {"all_held": most, "none_held": 0,
+                "straddling": None, "random": None}[routing]
+        assert want is None or routed_rows == want
+        if routing == "straddling":
+            assert counts["expert_load"][0] == rb + rb // 2
+
+
+def test_the_dispatch_holds_no_row_buffer_of_the_static_bound():
+    """The expert layer at the cell's widths (4,096 tokens, width 4,096,
+    expert width 768, top-10 of 72, 9 held), forward and input gradient,
+    compiles to no array of tokens × min(k, held) = 36,864 rows: the
+    gathered rows, their matmuls and the combine run on blocks of
+    ``dispatch_block_rows`` rows.  The one shape exempt, (36,864, 768), is
+    the CPU's ragged dot flattening the stacked weights (9, 4,096, 768):
+    at these widths held × width is that many."""
+    import re
+    cfg = dataclasses.replace(GRANITE, experts_held=9, expert_offset=0)
+    t, d, k = 4096, cfg.d_model, cfg.experts_per_token
+    rows = t * min(k, 9)
+    p = jax.eval_shape(lambda: L.moe_init(jax.random.PRNGKey(0), cfg)[0])
+    x = jax.ShapeDtypeStruct((1, t, d), jnp.float32)
+
+    def fwd(p, x):
+        return L.moe_held_apply(p, x, cfg)[0]
+
+    def grad(p, x):
+        return jax.grad(lambda x: (fwd(p, x) ** 2).sum())(x)
+    weights = f"f32[{9 * d},{cfg.moe_d_ff}]"
+    assert rows == 9 * d and L.dispatch_block_rows(t, k, 9, 72) < rows
+    with jax.default_matmul_precision("float32"):
+        for f in (fwd, grad):
+            text = jax.jit(f).lower(p, x).compile().as_text()
+            shapes = set(re.findall(rf"\w+\[{rows}(?:,\d+)*\]", text))
+            assert shapes <= {weights}, (f.__name__, shapes)
 
 
 def test_the_grouped_matmul_is_ragged_dot_under_vmap_and_grad():
@@ -202,8 +347,10 @@ def test_a_scanned_stack_counts_the_same_loads_as_unstacked_blocks():
     toks = {"tokens": jnp.arange(32)[None] % cfg.vocab_size}
     logits, _, loads = forward_loads(params, scanned, toks)
     want_logits, _, want = forward_loads(flat, cfg, toks)
-    assert loads.shape == (8, cfg.experts_held + 1)
-    np.testing.assert_array_equal(loads, want)
+    assert loads["expert_load"].shape == (8, cfg.experts_held + 1)
+    assert loads["expert_rows_run"].shape == (8,)
+    for name in ("expert_load", "expert_rows_run"):
+        np.testing.assert_array_equal(loads[name], want[name])
     # The same float32 ops; the scan only reorders how XLA fuses them.
     np.testing.assert_allclose(logits, want_logits, rtol=1e-5, atol=1e-5)
 
@@ -262,10 +409,16 @@ def test_sim_and_host_engines_agree_through_the_shared_step():
         spec = ExperimentSpec(scenarios=(sc,), strategies=("labelwise",),
                               seeds=(3,), fl=FL, eval_n_per_class=1,
                               workload=wl, engine=engine,
-                              telemetry=("expert_load",))
+                              telemetry=("expert_load", "expert_rows_run"))
         out[engine] = run(spec)
     a, b = out["sim"], out["host"]
     np.testing.assert_array_equal(a.num_selected, b.num_selected)
+    ta, tb = a.telemetry(), b.telemetry()
+    for name in ("expert_load", "expert_rows_run"):
+        np.testing.assert_array_equal(ta[name], tb[name])
+    # Whole blocks over the rows routed, summed over the local steps.
+    routed = ta["expert_load"][..., :-1].sum(-1)
+    assert (ta["expert_rows_run"] >= routed).all() and routed.sum() > 0
     # One program against a per-round jit of the same ops: op order only.
     np.testing.assert_allclose(a.loss, b.loss, rtol=1e-6)
     assert a.loss[0, 0, 0, 1] != a.loss[0, 0, 0, 0]        # training moved it
