@@ -535,17 +535,22 @@ def _grouped_bwd(precision, res, dy):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+# The TPU compiler runs ``lax.ragged_dot`` as a kernel that walks the rows in
+# tiles of up to 512 and streams a group's whole weight matrix for each tile;
+# a row count that is not a multiple of 512 gets smaller tiles and more weight
+# traffic per row.
+_ROW_TILE = 512
+
+
 def dispatch_block_rows(tokens: int, k: int, experts_held: int,
                         num_experts: int) -> int:
-    """Rows one block of the held experts' dispatch runs: the largest
-    divisor of the most a call can route to the held experts (tokens ×
-    min(k, held)) that is at most an eighth of what a call routes there on
-    average (tokens · k · held / experts).  The blocks tile that most, so a
-    call never runs more rows than it; and a call runs ceil(routed / block)
-    blocks, fewer than one block's rows past the routed."""
-    most = tokens * min(k, experts_held)
-    eighth = max(1, tokens * k * experts_held // (8 * num_experts))
-    return max(r for r in range(1, min(eighth, most) + 1) if most % r == 0)
+    """Rows one block of the held experts' dispatch runs: a fifth of what a
+    call routes to the held experts on average (tokens · k · held /
+    experts), rounded up to whole row tiles of the grouped matmul.  A call
+    runs ceil(routed / block) blocks, fewer than one block's rows past the
+    routed."""
+    fifth = -(-tokens * k * experts_held // (5 * num_experts))
+    return -(-fifth // _ROW_TILE) * _ROW_TILE
 
 
 def _block_groups(counts: Array, lo: Array, rb: int) -> Array:
@@ -567,9 +572,10 @@ def _held_dispatch(cfg: ModelConfig, rb: int):
     the experts as :func:`grouped_matmul` with the groups clipped to the
     block, and adds its gate-weighted rows to y.  A loop of a data-dependent
     trip count has no reverse-mode derivative, so the backward is the same
-    loop, at the matmul precision of the forward.  A vmapped batch runs its
-    members one after another: batched, each member's loop would run the
-    batch's longest trip under selects over the whole of y."""
+    loop, at the matmul precision of the forward, with the weights
+    transposed once before it.  A vmapped batch runs its members one after
+    another: batched, each member's loop would run the batch's longest trip
+    under selects over the whole of y."""
     act = jax.nn.silu if cfg.activation != "gelu_glu" else jax.nn.gelu
     precision = jax.config.jax_default_matmul_precision
 
@@ -582,13 +588,16 @@ def _held_dispatch(cfg: ModelConfig, rb: int):
     def over_blocks(tok, gate, counts, body, init):
         """``body(lo, tb, gb, sizes, carry)`` over the blocks that hold
         routed rows, ``lo`` a block's first row, ``tb``/``gb`` its rows'
-        tokens and gates, ``sizes`` the groups clipped to it."""
+        tokens and gates, ``sizes`` the groups clipped to it.  ``tok`` and
+        ``gate`` run one block past the last row, so no block's slice is
+        cut short."""
         def step(j, carry):
             lo = j * rb
             tb = jax.lax.dynamic_slice_in_dim(tok, lo, rb)
             gb = jax.lax.dynamic_slice_in_dim(gate, lo, rb)
             return body(lo, tb, gb, _block_groups(counts, lo, rb), carry)
-        return jax.lax.fori_loop(0, -(-counts.sum() // rb), step, init)
+        with scope(cfg, "moe.route"):
+            return jax.lax.fori_loop(0, -(-counts.sum() // rb), step, init)
 
     @jax.custom_batching.sequential_vmap
     def forward(xt, ws, tok, gate, counts):
@@ -602,12 +611,24 @@ def _held_dispatch(cfg: ModelConfig, rb: int):
 
     @jax.custom_batching.sequential_vmap
     def input_grads(xt, ws, tok, gate, counts, dy):
+        with scope(cfg, "moe.experts"):
+            wg, wu, w2 = ws
+            wgt, wut, w2t = (jnp.swapaxes(w, 1, 2) for w in ws)
+
         def body(lo, tb, gb, sizes, carry):
             dx, dgate = carry
             with scope(cfg, "moe.route"):
                 xs, dys = xt[tb], dy[tb]
-            _, vjp = jax.vjp(lambda xs, gb: rows(xs, gb, ws, sizes), xs, gb)
-            dxs, dgb = vjp(dys)
+            with scope(cfg, "moe.experts"):
+                g = grouped_matmul(xs, wg, sizes)
+                u = grouped_matmul(xs, wu, sizes)
+                a, act_vjp = jax.vjp(act, g)
+                o = grouped_matmul(a * u, w2, sizes)
+                dgb = (o * dys).sum(-1).astype(gate.dtype)
+                dh = grouped_matmul(dys * gb[:, None].astype(dys.dtype), w2t,
+                                    sizes)
+                dxs = (grouped_matmul(act_vjp(dh * u)[0], wgt, sizes)
+                       + grouped_matmul(dh * a, wut, sizes))
             with scope(cfg, "moe.route"):
                 return (dx.at[tb].add(dxs),
                         jax.lax.dynamic_update_slice_in_dim(dgate, dgb, lo, 0))
@@ -624,18 +645,25 @@ def _held_dispatch(cfg: ModelConfig, rb: int):
         return over_blocks(tok, gate, counts, body,
                            jax.tree_util.tree_map(jnp.zeros_like, ws))
 
+    def padded(tok, gate):
+        return (jnp.concatenate([tok, jnp.zeros((rb,), tok.dtype)]),
+                jnp.concatenate([gate, jnp.zeros((rb,), gate.dtype)]))
+
     @jax.custom_vjp
     def dispatch(xt, ws, tok, gate, counts):
-        return forward(xt, ws, tok, gate, counts)
+        return forward(xt, ws, *padded(tok, gate), counts)
 
     def fwd(xt, ws, tok, gate, counts):
-        return forward(xt, ws, tok, gate, counts), (xt, ws, tok, gate, counts)
+        y = forward(xt, ws, *padded(tok, gate), counts)
+        return y, (xt, ws, tok, gate, counts)
 
     def bwd(res, dy):
+        xt, ws, tok, gate, counts = res
+        res = (xt, ws, *padded(tok, gate), counts)
         with _at_precision(precision):
             dx, dgate = input_grads(*res, dy)
             dws = weight_grads(*res, dy)
-        return dx, dws, None, dgate, None
+        return dx, dws, None, dgate[:gate.shape[0]], None
 
     dispatch.defvjp(fwd, bwd)
     return dispatch

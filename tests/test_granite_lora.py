@@ -128,8 +128,8 @@ def full_buffer_dispatch(p, x, cfg):
     return jnp.zeros((t, d)).at[st].add(ys * sg[:, None]).reshape(b, s, d)
 
 
-# 1,024 tokens a call, 2 of 8 experts held from 2 on, top-3: blocks of 64
-# rows, up to 32 of them.
+# 1,024 tokens a call, 2 of 8 experts held from 2 on, top-3: blocks of 512
+# rows, up to 4 of them.
 DISPATCH = dataclasses.replace(TINY, expert_offset=2, dtype="float32")
 
 
@@ -203,7 +203,7 @@ def test_the_dispatch_runs_whole_blocks_over_the_routed_rows(routing):
     p, x = routed(routing)
     rb = L.dispatch_block_rows(1024, cfg.experts_per_token, 2, 8)
     most = 1024 * min(cfg.experts_per_token, 2)
-    assert most % rb == 0 and rb < most
+    assert rb < most
     for xi in x:
         counts = L.moe_held_apply(p, xi, cfg)[2]
         routed_rows = int(counts["expert_load"][:-1].sum())
@@ -219,32 +219,32 @@ def test_the_dispatch_runs_whole_blocks_over_the_routed_rows(routing):
 
 
 def test_the_dispatch_holds_no_row_buffer_of_the_static_bound():
-    """The expert layer at the cell's widths (4,096 tokens, width 4,096,
-    expert width 768, top-10 of 72, 9 held), forward and input gradient,
-    compiles to no array of tokens × min(k, held) = 36,864 rows: the
-    gathered rows, their matmuls and the combine run on blocks of
-    ``dispatch_block_rows`` rows.  The one shape exempt, (36,864, 768), is
-    the CPU's ragged dot flattening the stacked weights (9, 4,096, 768):
-    at these widths held × width is that many."""
+    """The expert layer at the cell's widths (width 4,096, expert width 768,
+    top-10 of 72, 9 held) on two of the cell's calls, 8,192 tokens, forward
+    and input gradient, compiles to no array of tokens × min(k, held) =
+    73,728 rows: the gathered rows, their matmuls and the combine run on
+    blocks of ``dispatch_block_rows`` rows.  (At one call's 4,096 tokens
+    the bound, 36,864, would also be the rows of the stacked weights
+    flattened, held × width.)"""
     import re
     cfg = dataclasses.replace(GRANITE, experts_held=9, expert_offset=0)
-    t, d, k = 4096, cfg.d_model, cfg.experts_per_token
+    t, d, k = 8192, cfg.d_model, cfg.experts_per_token
     rows = t * min(k, 9)
     p = jax.eval_shape(lambda: L.moe_init(jax.random.PRNGKey(0), cfg)[0])
-    x = jax.ShapeDtypeStruct((1, t, d), jnp.float32)
+    x = jax.ShapeDtypeStruct((2, t // 2, d), jnp.float32)
 
     def fwd(p, x):
         return L.moe_held_apply(p, x, cfg)[0]
 
     def grad(p, x):
         return jax.grad(lambda x: (fwd(p, x) ** 2).sum())(x)
-    weights = f"f32[{9 * d},{cfg.moe_d_ff}]"
-    assert rows == 9 * d and L.dispatch_block_rows(t, k, 9, 72) < rows
+    assert rows not in (9 * d, 9 * cfg.moe_d_ff)
+    assert L.dispatch_block_rows(t, k, 9, 72) < rows
     with jax.default_matmul_precision("float32"):
         for f in (fwd, grad):
             text = jax.jit(f).lower(p, x).compile().as_text()
             shapes = set(re.findall(rf"\w+\[{rows}(?:,\d+)*\]", text))
-            assert shapes <= {weights}, (f.__name__, shapes)
+            assert not shapes, (f.__name__, shapes)
 
 
 def test_the_grouped_matmul_is_ragged_dot_under_vmap_and_grad():
