@@ -544,13 +544,15 @@ _ROW_TILE = 512
 
 def dispatch_block_rows(tokens: int, k: int, experts_held: int,
                         num_experts: int) -> int:
-    """Rows one block of the held experts' dispatch runs: a fifth of what a
+    """Rows one block of the held experts' dispatch runs: half of what a
     call routes to the held experts on average (tokens · k · held /
     experts), rounded up to whole row tiles of the grouped matmul.  A call
     runs ceil(routed / block) blocks, fewer than one block's rows past the
-    routed."""
-    fifth = -(-tokens * k * experts_held // (5 * num_experts))
-    return -(-fifth // _ROW_TILE) * _ROW_TILE
+    routed.  Each block costs a loop step and the weights of the groups it
+    cuts; on a TPU v5e at the granite cell, half ran a round 0.6% faster
+    than a fifth."""
+    half = -(-tokens * k * experts_held // (2 * num_experts))
+    return -(-half // _ROW_TILE) * _ROW_TILE
 
 
 def _block_groups(counts: Array, lo: Array, rb: int) -> Array:
